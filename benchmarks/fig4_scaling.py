@@ -26,8 +26,8 @@ from repro.data import dp_stick_breaking_data, bp_stick_breaking_data
 P = {P}
 algo = "{algo}"
 n, pb = {n}, {pb}
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((P,), ("data",))
+mesh = jax.make_mesh((P,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 if algo == "bpmeans":
     x, _, _ = bp_stick_breaking_data(n, seed=0)
 else:
@@ -54,6 +54,9 @@ def run(n: int = 16384, pb: int = 2048, ps=(1, 2, 4, 8), quiet: bool = False):
         base_model = None
         for p in ps:
             env = dict(os.environ)
+            # Each child emulates P hosts as P CPU devices; it must not
+            # reach for a chip the parent process may already hold.
+            env["JAX_PLATFORMS"] = "cpu"
             env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
             env["PYTHONPATH"] = os.path.join(repo, "src")
             code = _WORKER.format(P=p, algo=algo, n=n, pb=pb)

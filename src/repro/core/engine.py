@@ -222,10 +222,12 @@ def _finish_epoch(txn, pool, send, payload, aux, safe, valid_e, validate_cap,
 
 
 def _epoch_body(txn, pool, x_e, valid_e, state_e, validate_cap, scan_mode,
-                replicate=None):
+                replicate=None, propose=None):
     """One bulk-synchronous OCC epoch (any width, incl. the width-1 epochs
-    of the serial bootstrap prefix) — always on the precomputed validator."""
-    send, payload, aux, safe = txn.propose(pool, x_e, state_e)
+    of the serial bootstrap prefix) — always on the precomputed validator.
+    `propose` replaces `txn.propose` (the mesh path's per-shard propose)."""
+    propose = txn.propose if propose is None else propose
+    send, payload, aux, safe = propose(pool, x_e, state_e)
     return _finish_epoch(txn, pool, send, payload, aux, safe, valid_e,
                          validate_cap, scan_mode, replicate)
 
@@ -246,18 +248,22 @@ def _engine_pass(txn, pool, x, state, *, pb, cap_warm, cap_rest, n_warm,
     n, d = x.shape
     nb = n_bootstrap
 
-    replicate = None
+    replicate = propose = None
     if mesh is not None:
         # The validator is the replicated master: pin its compacted (cap, …)
         # buffers to the replicated spec so GSPMD gathers once at compaction
         # instead of resharding mid-scan (shardings.occ_validate_sharding).
-        from repro.distributed.shardings import occ_validate_sharding
+        from repro.distributed.shardings import (
+            occ_propose_shard_map, occ_validate_sharding,
+        )
         replicate = lambda a: jax.lax.with_sharding_constraint(
             a, occ_validate_sharding(mesh, a.ndim))
+        propose = occ_propose_shard_map(txn.propose, mesh, data_axis, pb)
 
-    def epoch_at(cap):
+    def epoch_at(cap, propose=None):
         def epoch(pool, inp):
-            return _epoch_body(txn, pool, *inp, cap, scan_mode, replicate)
+            return _epoch_body(txn, pool, *inp, cap, scan_mode, replicate,
+                               propose)
         return epoch
 
     # Serial bootstrap prefix (paper §4.2): width-1 epochs are exactly the
@@ -302,7 +308,7 @@ def _engine_pass(txn, pool, x, state, *, pb, cap_warm, cap_rest, n_warm,
             continue
         cut = lambda a: a[lo:hi]
         pool, part = jax.lax.scan(
-            epoch_at(cap), pool,
+            epoch_at(cap, propose), pool,
             (cut(xs), cut(valid), jax.tree.map(cut, ss)))
         seg_parts.append(part)
     am, sm, n_sent, n_acc, caps = jax.tree.map(

@@ -7,6 +7,8 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
+from repro.kernels.ref import MATMUL_PRECISION
+
 __all__ = ["sq_dists", "dp_means_objective", "bp_means_objective"]
 
 
@@ -15,13 +17,15 @@ def sq_dists(x: jnp.ndarray, centers: jnp.ndarray) -> jnp.ndarray:
 
     Uses the expanded form ||x||^2 + ||mu||^2 - 2 x mu^T so the inner term is
     a single matmul (MXU-friendly; the Pallas kernel tiles the same algebra).
-    Clamped at zero against fp cancellation.
+    Clamped at zero against fp cancellation.  The matmul runs at
+    `MATMUL_PRECISION` (full f32) so that the validator, which measures
+    payload pairs through here, sees the distances the propose kernel sees.
     """
     x = jnp.asarray(x)
     centers = jnp.asarray(centers)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)          # (N, 1)
     c2 = jnp.sum(centers * centers, axis=-1)[None, :]    # (1, K)
-    cross = x @ centers.T                                # (N, K)
+    cross = jnp.matmul(x, centers.T, precision=MATMUL_PRECISION)  # (N, K)
     return jnp.maximum(x2 + c2 - 2.0 * cross, 0.0)
 
 

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from repro.core.objective import sq_dists
 from repro.kernels import ops as _kops
+from repro.kernels.ref import MATMUL_PRECISION
 
 __all__ = [
     "CenterPool", "make_pool", "pool_append_serial", "block_epochs",
@@ -464,7 +465,7 @@ def precomputed_validate_gram(
         def fit(m, st):
             a, u, rn2, z = st
             c_m = coef[m]
-            dot = jnp.dot(u, c_m)
+            dot = jnp.dot(u, c_m, precision=MATMUL_PRECISION)
             z_m = 2.0 * dot > fnorm2[m]
             a = jnp.where(z_m, a - c_m, a)
             u = jnp.where(z_m, u - gcoef[m], u)
@@ -501,7 +502,8 @@ def precomputed_validate_gram(
     new_slots = count0 + jnp.arange(cap)
     z_c = jnp.zeros((cap, k_max), bool).at[:, new_slots].set(
         z_mat, mode="drop")
-    feats = coef @ payload_c                    # ONE MXU materialisation
+    # ONE MXU materialisation
+    feats = jnp.matmul(coef, payload_c, precision=MATMUL_PRECISION)
     widx = jnp.where(jnp.arange(cap) < nacc, new_slots, k_max)
     centers = pool.centers.at[widx].set(
         feats.astype(pool.centers.dtype), mode="drop")

@@ -25,17 +25,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 __all__ = ["ShardCtx", "shard_ctx", "current_ctx", "constrain", "batch_spec",
            "param_specs", "input_shardings", "axes_that_divide",
            "occ_epoch_sharding", "occ_validate_sharding",
-           "serve_snapshot_sharding", "serve_query_sharding",
-           "compat_shard_map"]
-
-
-def compat_shard_map(f, **kw):
-    """`jax.shard_map` across jax versions (older releases only have
-    `jax.experimental.shard_map.shard_map`)."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-    return sm(f, **kw)
+           "occ_propose_shard_map", "serve_snapshot_sharding",
+           "serve_query_sharding"]
 
 
 @dataclass
@@ -144,6 +135,21 @@ def occ_epoch_sharding(mesh: Mesh, data_axis: str, pb: int,
     ctx = ShardCtx(mesh=mesh, data_axes=(data_axis,))
     elem = _norm_elem(pb, data_axis, ctx)
     return NamedSharding(mesh, P(None, elem, *([None] * (rank - 2))))
+
+
+def occ_propose_shard_map(propose, mesh: Mesh, data_axis: str, pb: int):
+    """The optimistic phase as one program per device (paper Fig. 4): each
+    device runs `propose(pool, x_e, state_e)` on its own pb/|data_axis|
+    points against the replicated pool, and every output keeps the point
+    axis sharded.  Under plain GSPMD the Pallas propose kernel cannot be
+    partitioned at all (XLA refuses a Mosaic call it would have to split).
+    Returns None when the axis does not divide pb — the epoch then stays
+    replicated, as `occ_epoch_sharding` falls back to."""
+    if pb % mesh.shape[data_axis]:
+        return None
+    return jax.shard_map(propose, mesh=mesh,
+                         in_specs=(P(), P(data_axis), P(data_axis)),
+                         out_specs=P(data_axis), check_vma=False)
 
 
 def occ_validate_sharding(mesh: Mesh, rank: int) -> NamedSharding:

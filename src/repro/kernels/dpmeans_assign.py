@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.ref import MATMUL_PRECISION
+
 __all__ = ["dpmeans_assign", "dpmeans_assign_emulate"]
 
 
@@ -62,17 +64,19 @@ def _assign_kernel(k_active_ref, x_ref, c_ref, mask_ref, d2_ref, idx_ref, *,
     def _work():
         x = x_ref[...].astype(jnp.float32)            # (bn, D)
         c = c_ref[...].astype(jnp.float32)            # (bk, D)
-        m = mask_ref[...]                             # (bk,)
+        m = mask_ref[...]                             # (1, bk)
 
         x2 = jnp.sum(x * x, axis=-1, keepdims=True)   # (bn, 1)
         c2 = jnp.sum(c * c, axis=-1)[None, :]         # (1, bk)
         # MXU: the only O(bn*bk*D) term is a single matmul.
         d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32), 0.0)
-        d2 = jnp.where(m[None, :], d2, jnp.inf)       # masked-out centers
+            x, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+            preferred_element_type=jnp.float32), 0.0)
+        d2 = jnp.where(m != 0, d2, jnp.inf)           # masked-out centers
 
-        loc_min = jnp.min(d2, axis=-1)                # (bn,)
-        loc_idx = jnp.argmin(d2, axis=-1).astype(jnp.int32) + kb * bk
+        loc_min = jnp.min(d2, axis=-1, keepdims=True)               # (bn, 1)
+        loc_idx = (jnp.argmin(d2, axis=-1, keepdims=True).astype(jnp.int32)
+                   + kb * bk)
 
         run_min = d2_ref[...]
         run_idx = idx_ref[...]
@@ -119,7 +123,7 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
         return jnp.minimum(j, last), 0
 
     def _mask_tile(i, j, k_ref):
-        return _center_tile(i, j, k_ref)[0]
+        return 0, _center_tile(i, j, k_ref)[0]
 
     grid = (np_ // bn, kp // bk)
     d2, idx = pl.pallas_call(
@@ -130,20 +134,20 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((bn, d), lambda i, j, k_ref: (i, 0)),
                 pl.BlockSpec((bk, d), _center_tile),
-                pl.BlockSpec((bk,), _mask_tile),
+                pl.BlockSpec((1, bk), _mask_tile),
             ],
             out_specs=[
-                pl.BlockSpec((bn,), lambda i, j, k_ref: (i,)),
-                pl.BlockSpec((bn,), lambda i, j, k_ref: (i,)),
+                pl.BlockSpec((bn, 1), lambda i, j, k_ref: (i, 0)),
+                pl.BlockSpec((bn, 1), lambda i, j, k_ref: (i, 0)),
             ],
         ),
         out_shape=[
-            jax.ShapeDtypeStruct((np_,), jnp.float32),
-            jax.ShapeDtypeStruct((np_,), jnp.int32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.float32),
+            jax.ShapeDtypeStruct((np_, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(k_active, x, centers, mask)
-    return d2[:n], idx[:n]
+    )(k_active, x, centers, mask.astype(jnp.int32)[None, :])
+    return d2[:n, 0], idx[:n, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_k"))
@@ -191,7 +195,7 @@ def dpmeans_assign_emulate(x: jnp.ndarray, centers: jnp.ndarray,
             cf = c.astype(jnp.float32)
             c2 = jnp.sum(cf * cf, axis=-1)[None, :]
             d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-                xf, cf, (((1,), (1,)), ((), ())),
+                xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
                 preferred_element_type=jnp.float32), 0.0)
             d2 = jnp.where(m[None, :], d2, jnp.inf)
             loc_min = jnp.min(d2, axis=-1)

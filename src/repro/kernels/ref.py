@@ -2,20 +2,73 @@
 
 Each `<name>_ref` is the semantic spec; kernel sweep tests assert_allclose
 against these across shapes and dtypes.
+
+The distance contract every backend is held to, here and on the chip:
+candidate ids are EXACT and squared distances agree to `D2_RTOL` /
+`D2_ATOL`.  Two computations of one distance may differ in the last ulps
+(matmul tiling, accumulation order, a different compiler), so distances
+are never compared bitwise across backends or shapes.  The one exception
+to exact ids is a near-tie: candidates whose reference distances lie
+within the tolerance of each other have no defined order, so their ids
+may trade places (`topk_disagreements`).
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
 import jax
+import numpy as np
 
 __all__ = ["assign_ref", "pairwise_argmin_ref", "topk_ref",
            "topk_merge_ref", "topk_multiprobe_ref", "TOPK_SENTINEL",
+           "MATMUL_PRECISION", "D2_RTOL", "D2_ATOL", "topk_disagreements",
            "flash_attention_ref", "rmsnorm_ref", "swiglu_ref"]
+
+# Every distance matmul of the OCC path -- the Pallas kernels, their
+# emulations, these oracles and the validator's precompute -- contracts at
+# full f32 precision.  On TPU an f32 matmul at the default precision is one
+# bf16 pass, so the propose and the validator would see different
+# distances for the same pair, which breaks the serial equivalence of the
+# OCC pass (paper Thm 3.1).  The CPU computes f32 either way.
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
+D2_RTOL = 1e-5
+D2_ATOL = 1e-5
 
 # Invalid-candidate id inside the top-k selection: larger than any real
 # center index, so the lexicographic (d2, id) order pushes exhausted slots
 # last deterministically.  Callers map it to -1 wherever d2 is non-finite.
 TOPK_SENTINEL = 2**31 - 1
+
+
+def _matmul_t(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a @ b.T at `MATMUL_PRECISION`."""
+    return jnp.matmul(a, b.T, precision=MATMUL_PRECISION)
+
+
+def topk_disagreements(d2, idx, d2_ref, idx_ref) -> int:
+    """How many (row, rank) slots of a top-k answer break the distance
+    contract against a reference top-k of the same queries.
+
+    A slot breaks it when its distance misses the reference distance at
+    that rank by more than D2_ATOL + D2_RTOL * |ref| (exhausted slots must
+    both be inf), or when its id differs from the reference id without a
+    near-tie: the id must sit in the reference row at a rank whose
+    distance is within that tolerance of this rank's.  `d2_ref`/`idx_ref`
+    may hold more columns than `d2`/`idx` (the next ranks), so a near-tie
+    across the k-th rank counts as a tie too.
+    """
+    d2, idx = np.asarray(d2), np.asarray(idx)
+    d2_ref, idx_ref = np.asarray(d2_ref), np.asarray(idx_ref)
+    k = d2.shape[1]
+    tol = D2_ATOL + D2_RTOL * np.abs(np.where(np.isfinite(d2_ref), d2_ref, 0))
+    dr = d2_ref[:, :k]
+    both_inf = np.isinf(d2) & np.isinf(dr)
+    bad = ~(both_inf | (np.abs(d2 - dr) <= tol[:, :k]))
+    for r, j in zip(*np.nonzero(idx != idx_ref[:, :k])):
+        at = np.nonzero(idx_ref[r] == idx[r, j])[0]
+        if at.size == 0 or abs(d2_ref[r, at[0]] - dr[r, j]) > tol[r, j]:
+            bad[r, j] = True
+    return int(bad.sum())
 
 
 def assign_ref(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray):
@@ -25,7 +78,7 @@ def assign_ref(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray):
     it preserves the propose phase's dtype/precision contract exactly."""
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(centers * centers, axis=-1)[None, :]
-    d2 = jnp.maximum(x2 + c2 - 2.0 * (x @ centers.T), 0.0)
+    d2 = jnp.maximum(x2 + c2 - 2.0 * _matmul_t(x, centers), 0.0)
     d2 = jnp.where(mask[None, :], d2, jnp.inf)
     d2min = jnp.min(d2, axis=-1)
     idx = jnp.where(jnp.isfinite(d2min),
@@ -41,7 +94,7 @@ def pairwise_argmin_ref(x: jnp.ndarray, centers: jnp.ndarray,
     cf = centers.astype(jnp.float32)
     x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
     c2 = jnp.sum(cf * cf, axis=-1)[None, :]
-    d2 = jnp.maximum(x2 + c2 - 2.0 * (xf @ cf.T), 0.0)
+    d2 = jnp.maximum(x2 + c2 - 2.0 * _matmul_t(xf, cf), 0.0)
     if mask is not None:
         d2 = jnp.where(mask[None, :], d2, jnp.inf)
     return jnp.min(d2, axis=-1), jnp.argmin(d2, axis=-1).astype(jnp.int32)
@@ -68,7 +121,7 @@ def topk_ref(x: jnp.ndarray, centers: jnp.ndarray, k: int,
         centers = jnp.where(mask[:, None], centers, 0)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     c2 = jnp.sum(centers * centers, axis=-1)[None, :]
-    d2 = jnp.maximum(x2 + c2 - 2.0 * (x @ centers.T), 0.0)
+    d2 = jnp.maximum(x2 + c2 - 2.0 * _matmul_t(x, centers), 0.0)
     if mask is not None:
         d2 = jnp.where(mask[None, :], d2, jnp.inf)
     neg, idx = jax.lax.top_k(-d2, k)
@@ -144,7 +197,7 @@ def topk_multiprobe_ref(x: jnp.ndarray, fine: jnp.ndarray,
     g = jnp.where(gmask[:, None], g, 0)
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)
     g2 = jnp.sum(g * g, axis=-1)[None, :]
-    d2 = jnp.maximum(x2 + g2 - 2.0 * (x @ g.T), 0.0)
+    d2 = jnp.maximum(x2 + g2 - 2.0 * _matmul_t(x, g), 0.0)
     ok = gmask[None, :] & jnp.repeat(member, s, axis=1)
     d2 = jnp.where(ok, d2, jnp.inf)
     init_d = jnp.full((x.shape[0], k), jnp.inf, d2.dtype)

@@ -51,7 +51,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.ref import TOPK_SENTINEL, topk_merge_ref
+from repro.kernels.ref import MATMUL_PRECISION, TOPK_SENTINEL, topk_merge_ref
 
 __all__ = ["topk_stream", "topk_stream_emulate", "topk_multiprobe_stream",
            "topk_multiprobe_emulate", "topk_tile_loads"]
@@ -102,15 +102,15 @@ def _topk_kernel(k_active_ref, x_ref, c_ref, mask_ref, d2_ref, idx_ref, *,
     def _work():
         x = x_ref[...].astype(jnp.float32)            # (bn, D)
         c = c_ref[...].astype(jnp.float32)            # (bk, D)
-        m = mask_ref[...]                             # (bk,)
+        m = mask_ref[...]                             # (1, bk)
         bn = x.shape[0]
 
         x2 = jnp.sum(x * x, axis=-1, keepdims=True)
         c2 = jnp.sum(c * c, axis=-1)[None, :]
         d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())),
+            x, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
             preferred_element_type=jnp.float32), 0.0)
-        d2 = jnp.where(m[None, :], d2, jnp.inf)
+        d2 = jnp.where(m != 0, d2, jnp.inf)
         ids = (jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1) + kb * bk)
 
         nd, ni = topk_merge_ref(d2_ref[...], idx_ref[...], d2, ids, kk)
@@ -152,7 +152,7 @@ def topk_stream(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
         return jnp.minimum(j, last), 0
 
     def _mask_tile(i, j, k_ref):
-        return _center_tile(i, j, k_ref)[0]
+        return 0, _center_tile(i, j, k_ref)[0]
 
     grid = (np_ // bn, kp // bk)
     d2, idx = pl.pallas_call(
@@ -163,7 +163,7 @@ def topk_stream(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((bn, d), lambda i, j, k_ref: (i, 0)),
                 pl.BlockSpec((bk, d), _center_tile),
-                pl.BlockSpec((bk,), _mask_tile),
+                pl.BlockSpec((1, bk), _mask_tile),
             ],
             out_specs=[
                 pl.BlockSpec((bn, k), lambda i, j, k_ref: (i, 0)),
@@ -175,7 +175,7 @@ def topk_stream(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
             jax.ShapeDtypeStruct((np_, k), jnp.int32),
         ],
         interpret=interpret,
-    )(k_active, x, centers, mask)
+    )(k_active, x, centers, mask.astype(jnp.int32)[None, :])
     d2, idx = _finalize(d2, idx)
     return d2[:n], idx[:n]
 
@@ -227,7 +227,7 @@ def topk_stream_emulate(x: jnp.ndarray, centers: jnp.ndarray,
             cf = c.astype(jnp.float32)
             c2 = jnp.sum(cf * cf, axis=-1)[None, :]
             d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-                xf, cf, (((1,), (1,)), ((), ())),
+                xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
                 preferred_element_type=jnp.float32), 0.0)
             d2 = jnp.where(m[None, :], d2, jnp.inf)
             ids = (jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
@@ -273,20 +273,24 @@ def _mp_kernel(u_count_ref, cells_ref, x_ref, f_ref, ids_ref, fmask_ref,
     def _work():
         x = x_ref[...].astype(jnp.float32)            # (bn, D)
         c = f_ref[0].astype(jnp.float32)              # (S, D) — one shard
-        ids = ids_ref[0]                              # (S,)
-        fm = fmask_ref[0]                             # (S,)
-        mem = member_ref[...][:, 0]                   # (bn,)
+        ids = ids_ref[0]                              # (1, S)
+        fm = fmask_ref[0]                             # (1, S)
+        # Membership of union slot j: column j of the row block's (bn, U)
+        # membership, picked by a lane mask (U is small — at most n_cells).
+        mem_all = member_ref[...]                     # (bn, U)
+        col = jax.lax.broadcasted_iota(jnp.int32, mem_all.shape, 1) == j
+        mem = jnp.max(jnp.where(col, mem_all, 0), axis=1, keepdims=True)
 
         x2 = jnp.sum(x * x, axis=-1, keepdims=True)
         c2 = jnp.sum(c * c, axis=-1)[None, :]
         d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())),
+            x, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
             preferred_element_type=jnp.float32), 0.0)
-        d2 = jnp.where(fm[None, :] & mem[:, None], d2, jnp.inf)
+        d2 = jnp.where((fm != 0) & (mem != 0), d2, jnp.inf)
 
         nd, ni = topk_merge_ref(
             d2_ref[...], idx_ref[...], d2,
-            jnp.broadcast_to(ids[None, :], d2.shape), kk)
+            jnp.broadcast_to(ids, d2.shape), kk)
         d2_ref[...] = nd
         idx_ref[...] = ni
 
@@ -325,12 +329,6 @@ def topk_multiprobe_stream(x: jnp.ndarray, fine: jnp.ndarray,
         jc = jnp.minimum(j, jnp.maximum(u_ref[0], 1) - 1)
         return cells_ref[jc], 0, 0
 
-    def _shard_vec(i, j, u_ref, cells_ref):
-        return _shard_tile(i, j, u_ref, cells_ref)[:2]
-
-    def _member_tile(i, j, u_ref, cells_ref):
-        return i, jnp.minimum(j, jnp.maximum(u_ref[0], 1) - 1)
-
     grid = (bp // bn, u)
     d2, idx = pl.pallas_call(
         functools.partial(_mp_kernel, kk=k),
@@ -340,9 +338,9 @@ def topk_multiprobe_stream(x: jnp.ndarray, fine: jnp.ndarray,
             in_specs=[
                 pl.BlockSpec((bn, d), lambda i, j, u_ref, cells_ref: (i, 0)),
                 pl.BlockSpec((1, s, d), _shard_tile),
-                pl.BlockSpec((1, s), _shard_vec),
-                pl.BlockSpec((1, s), _shard_vec),
-                pl.BlockSpec((bn, 1), _member_tile),
+                pl.BlockSpec((1, 1, s), _shard_tile),
+                pl.BlockSpec((1, 1, s), _shard_tile),
+                pl.BlockSpec((bn, u), lambda i, j, u_ref, cells_ref: (i, 0)),
             ],
             out_specs=[
                 pl.BlockSpec((bn, k), lambda i, j, u_ref, cells_ref: (i, 0)),
@@ -354,7 +352,8 @@ def topk_multiprobe_stream(x: jnp.ndarray, fine: jnp.ndarray,
             jax.ShapeDtypeStruct((bp, k), jnp.int32),
         ],
         interpret=interpret,
-    )(u_active, cells_cl, x, fine, fine_ids, fine_mask, member)
+    )(u_active, cells_cl, x, fine, fine_ids[:, None, :],
+      fine_mask.astype(jnp.int32)[:, None, :], member.astype(jnp.int32))
     d2, idx = _finalize(d2, idx)
     return d2[:b], idx[:b]
 
@@ -400,7 +399,7 @@ def topk_multiprobe_emulate(x: jnp.ndarray, fine: jnp.ndarray,
             cf = fine[cell].astype(jnp.float32)
             c2 = jnp.sum(cf * cf, axis=-1)[None, :]
             d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-                xf, cf, (((1,), (1,)), ((), ())),
+                xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
                 preferred_element_type=jnp.float32), 0.0)
             d2 = jnp.where(fine_mask[cell][None, :] & mem[:, None],
                            d2, jnp.inf)

@@ -57,10 +57,10 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
                 arch.moe, capacity_factor=variant["capacity_factor"]))
 
     if "mesh_shape" in variant:   # §Perf lever: same chips, different split
-        from repro.launch.mesh import compat_mesh
         shp = tuple(variant["mesh_shape"])
         axes = ("data", "model") if len(shp) == 2 else ("pod", "data", "model")
-        mesh = compat_mesh(shp, axes)
+        mesh = jax.make_mesh(
+            shp, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     n_chips = mesh.size
@@ -80,7 +80,7 @@ def run_cell(arch_name: str, shape_name: str, multi_pod: bool,
             t_compile = time.time() - t0 - t_lower
 
             mem = compiled.memory_analysis()
-            cost = roofline.hlo_cost_analysis(compiled)
+            cost = compiled.cost_analysis()
             print(mem)    # proves it fits
             print({k: v for k, v in cost.items()
                    if k in ("flops", "bytes accessed", "optimal_seconds")})

@@ -37,6 +37,10 @@ in the driver process that speaks only CTRL frames:
     and every surviving follower's digest — the whole killed-and-promoted
     run must be BIT-IDENTICAL to a run where nothing ever failed.
 
+Like `launch/occ_cluster.py`, this is a loopback emulation of hosts on
+the CPU, not a chip path: every process pins JAX to the CPU and prints the
+platform it got.
+
   PYTHONPATH=src python -m repro.launch.ha_cluster --quick \
       --nodes 3 --workers 2 --kill-after 6 --out BENCH_ha.json
 """
@@ -139,7 +143,8 @@ def ha_node_main(cfg_kw: dict, node_id: int, coord_port: int) -> None:
     or `report` after an orderly FIN), promote (run the master phase), and
     exit.
     """
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.launch.occ_cluster import cpu_host
+    cpu_host(f"ha node {node_id}")
     from repro.distributed.protocol import hello_frame, write_frame
     from repro.distributed.transport import ReplicationClient, store_digest
     from repro.serving.snapshot import SnapshotStore
@@ -330,7 +335,8 @@ def ha_worker_main(cfg_kw: dict, worker_id: int, coord_port: int) -> None:
     master until FIN (pass complete → exit) or EOF (master died →
     re-discover).  After an EOF the worker insists on term strictly above
     the one it lost, so it can never reconnect to a zombie."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.launch.occ_cluster import cpu_host
+    cpu_host(f"ha worker {worker_id}")
     from repro.distributed.protocol import hello_frame, write_frame
     from repro.launch.occ_cluster import (ClusterConfig, _cluster_data,
                                           _cluster_txn, _padded_epochs,
@@ -512,7 +518,8 @@ class _Coordinator:
 
 
 def run_ha_cluster(cfg: HAConfig) -> dict:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    from repro.launch.occ_cluster import cpu_host
+    cpu_host("ha coordinator")
     from repro.core.engine import OCCEngine
     from repro.core.occ import block_epochs
     from repro.distributed.transport import store_digest

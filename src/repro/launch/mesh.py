@@ -7,21 +7,7 @@ from __future__ import annotations
 
 import jax
 
-__all__ = ["compat_mesh", "make_production_mesh", "make_test_mesh"]
-
-
-def compat_mesh(shape, axes):
-    """jax.make_mesh across jax versions.
-
-    Newer jax wants explicit ``axis_types=(AxisType.Auto, ...)`` to keep the
-    GSPMD auto-partitioning behaviour; older releases (<= 0.4.x) don't have
-    `jax.sharding.AxisType` at all and Auto is the only behaviour.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(tuple(shape), tuple(axes))
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         axis_types=(axis_type.Auto,) * len(axes))
+__all__ = ["make_production_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -30,9 +16,5 @@ def make_production_mesh(*, multi_pod: bool = False):
     cross-pod data parallelism (DCN/ICI-X gradient all-reduce)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_mesh(shape, axes)
-
-
-def make_test_mesh(shape=(2, 2), axes=("data", "model")):
-    """Small mesh for CPU tests (requires enough host devices)."""
-    return compat_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))

@@ -30,6 +30,11 @@ Failure semantics (chaos-tested in tests/test_occ_cluster.py):
     replacement follower bootstraps from a SNAPSHOT frame and tails to
     the same bit-identical store.
 
+This is a loopback emulation of hosts on the CPU, not a chip path: every
+process (master, workers, followers) pins JAX to the CPU and prints the
+platform it got — a spawned child that reached for the TPU would contend
+with its parent for the one chip.
+
   PYTHONPATH=src python -m repro.launch.occ_cluster [--quick] \
       --workers 2 --followers 1 --out BENCH_transport.json
 """
@@ -175,6 +180,17 @@ def _serve_master(sock: socket.socket, cfg: ClusterConfig, worker_id: int,
         sock.close()
 
 
+def cpu_host(role: str) -> None:
+    """Pin this emulated host's process to the CPU and print its platform.
+    Spawned children inherit the setting before they import JAX; the config
+    update covers a process that imported JAX before calling this."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+    jax.config.update("jax_platforms", "cpu")
+    print(f"[{role}] pid {os.getpid()}: JAX platform "
+          f"{jax.devices()[0].platform}", flush=True)
+
+
 def worker_main(cfg_kw: dict, worker_id: int, port: int) -> None:
     """One propose worker (spawned process): tail pool deltas, answer STEP
     frames with the jitted shard propose, exit on FIN.
@@ -185,7 +201,7 @@ def worker_main(cfg_kw: dict, worker_id: int, port: int) -> None:
     pool).  If cfg.die_epoch targets this worker it exits hard (os._exit)
     upon the STEP, before proposing — the chaos tests' mid-epoch death.
     """
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    cpu_host(f"occ worker {worker_id}")
     from repro.distributed.protocol import hello_frame, write_frame
 
     cfg = ClusterConfig(**cfg_kw)
@@ -402,7 +418,7 @@ def _masked_reference(cfg: ClusterConfig, engine, dead_from: dict[int, int]):
 
 
 def run_cluster(cfg: ClusterConfig) -> dict:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    cpu_host("occ master")
     from repro.core.engine import OCCEngine
     from repro.distributed.transport import ReplicationServer, store_digest
     from repro.launch.occ_follower import follower_main

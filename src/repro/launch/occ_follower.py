@@ -17,6 +17,7 @@ import argparse
 import json
 
 from repro.distributed.transport import ReplicationClient, store_digest
+from repro.launch.occ_cluster import cpu_host
 
 __all__ = ["follower_main"]
 
@@ -33,6 +34,7 @@ def follower_main(host: str, port: int, model: str | None,
     backoff + jitter (§14) up to `max_retries` consecutive failures; the
     re-HELLO carries the follower's watermark, so a retry resumes with the
     missing suffix (or a SNAPSHOT resync) rather than the full history."""
+    cpu_host(f"follower {model}")
     client = ReplicationClient((host, port), model=model, capacity=capacity,
                                reconnect=reconnect, max_retries=max_retries,
                                backoff_s=backoff_s,

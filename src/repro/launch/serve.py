@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import get_arch, reduced
 from repro.distributed.shardings import shard_ctx
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import build_model
 from repro.serving.engine import Request, ServeEngine
@@ -34,6 +35,7 @@ def main(argv=None):
     ap.add_argument("--decode-mode", choices=["tp", "cp"], default="tp")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:

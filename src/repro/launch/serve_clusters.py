@@ -65,6 +65,7 @@ import numpy as np
 from repro.core import DPMeansTransaction, OCCEngine
 from repro.core.occ import nearest_center
 from repro.data import dp_stick_breaking_data
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import Obs, Tracer
 from repro.serving import (
     ClusterService, ModelRouter, Query, ServeConfig, SnapshotStore,
@@ -705,6 +706,7 @@ def main(argv=None):
     ap.add_argument("--trace-out", default=None,
                     help="write a Perfetto/Chrome trace JSON here")
     args = ap.parse_args(argv)
+    use_compile_cache()
     cfg = ServeDemoConfig(n=args.n, n_models=args.models, pb=args.pb,
                           train_batch=args.train_batch,
                           min_queries=args.queries, backend=args.backend,

@@ -22,6 +22,7 @@ from repro.configs import TrainConfig, get_arch, reduced
 from repro.data.tokens import TokenPipeline
 from repro.distributed.fault import StepWatchdog
 from repro.distributed.shardings import shard_ctx
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.models import build_model
 from repro.training.step import make_train_step, train_state_init
@@ -44,6 +45,7 @@ def main(argv=None):
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--log-every", type=int, default=5)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     arch = get_arch(args.arch)
     if args.reduced:

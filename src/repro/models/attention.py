@@ -243,8 +243,7 @@ def _cp_decode(q, k_new, v_new, cache, pos, cfg, scale):
         o = o.transpose(0, 3, 1, 2, 4).reshape(b, c, hkv * g, hd)
         return o, ck, cv
 
-    from repro.distributed.shardings import compat_shard_map
-    o, ck, cv = compat_shard_map(
+    o, ck, cv = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bs, None, None, None), P(bs, None, None, None),
                   P(bs, None, None, None), P(bs, ax, None, None),

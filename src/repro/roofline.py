@@ -25,17 +25,7 @@ import math
 import re
 from dataclasses import dataclass, field
 
-__all__ = ["HW", "hlo_cost_analysis", "parse_collectives", "roofline_terms",
-           "model_flops"]
-
-
-def hlo_cost_analysis(compiled) -> dict:
-    """`compiled.cost_analysis()` across jax versions: older releases return
-    a per-device list of dicts, newer ones a single dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        return cost[0] if cost else {}
-    return cost
+__all__ = ["HW", "parse_collectives", "roofline_terms", "model_flops"]
 
 PEAK_FLOPS = 197e12          # bf16 per chip
 HBM_BW = 819e9               # bytes/s per chip

@@ -393,7 +393,8 @@ def test_topk_duplicate_distance_tiebreak_determinism(rng):
         outs[backend] = (np.asarray(d2), np.asarray(idx))
     for b in ("emulate", "pallas"):
         np.testing.assert_array_equal(outs[b][1], outs["ref"][1])
-        np.testing.assert_array_equal(outs[b][0], outs["ref"][0])
+        np.testing.assert_allclose(outs[b][0], outs["ref"][0],
+                                   rtol=ref.D2_RTOL, atol=ref.D2_ATOL)
     d2, idx = outs["ref"]
     for r in range(11):
         for j in range(1, 6):
@@ -405,8 +406,9 @@ def test_topk_duplicate_distance_tiebreak_determinism(rng):
 
 def test_topk_multiprobe_full_union_bitwise_flat(rng):
     """p = all at the ops level: union covering every cell + all-true
-    membership is bit-identical to flat serve_topk on every backend —
-    garbage in padded shard slots included."""
+    membership equals flat serve_topk on every backend under the distance
+    contract (ids exact, distances to ref.D2_RTOL/D2_ATOL) — garbage in
+    padded shard slots included."""
     kc, d, count = 512, 64, 437
     cn = rng.normal(size=(kc, d)).astype(np.float32)
     cn[count:] = np.nan
@@ -422,8 +424,29 @@ def test_topk_multiprobe_full_union_bitwise_flat(rng):
         d2m, im = ops.serve_topk_multiprobe(
             x, h.fine, h.fine_ids, h.fine_mask, cells, member, 9,
             u_count=jnp.asarray(h.n_cells, jnp.int32), backend=backend)
-        np.testing.assert_array_equal(np.asarray(d2m), np.asarray(d2f))
+        np.testing.assert_allclose(np.asarray(d2m), np.asarray(d2f),
+                                   rtol=ref.D2_RTOL, atol=ref.D2_ATOL)
         np.testing.assert_array_equal(np.asarray(im), np.asarray(if_))
+
+
+_REF_D2 = np.array([[0.5, 1.0, 1.0 + 2e-6, 3.0, 4.0]], np.float32)
+_REF_ID = np.array([[7, 3, 9, 1, 2]], np.int32)
+
+
+@pytest.mark.parametrize("d2,idx,bad", [
+    ([[0.5, 1.0, 1.0, 3.0]], [[7, 3, 9, 1]], 0),           # equal
+    ([[0.5 + 1e-6, 1.0, 1.0, 3.0]], [[7, 3, 9, 1]], 0),    # last-ulp distance
+    ([[0.5, 1.0, 1.0, 3.0]], [[7, 9, 3, 1]], 0),           # near-tie swap
+    ([[0.5, 1.0, 1.0, 3.0]], [[3, 7, 9, 1]], 2),           # swap without tie
+    ([[0.5, 1.0, 1.0, 3.1]], [[7, 3, 9, 1]], 1),           # distance miss
+    ([[0.5, 1.0, 1.0, 3.0]], [[7, 3, 9, 5]], 1),           # id not in ref row
+])
+def test_topk_disagreements_distance_contract(d2, idx, bad):
+    """ids exact except where reference distances tie within tolerance;
+    distances to D2_RTOL/D2_ATOL."""
+    assert ref.topk_disagreements(np.array(d2, np.float32),
+                                  np.array(idx, np.int32),
+                                  _REF_D2, _REF_ID) == bad
 
 
 def test_topk_multiprobe_partial_union_matches_candidate_oracle(rng):

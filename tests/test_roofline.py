@@ -78,7 +78,7 @@ def test_analytic_flops_vs_hlo(name, kw):
     batch = {"tokens": jax.ShapeDtypeStruct((B, S), jnp.int32),
              "labels": jax.ShapeDtypeStruct((B, S), jnp.int32)}
     compiled = jax.jit(m.loss).lower(params, batch).compile()
-    hlo_flops = roofline.hlo_cost_analysis(compiled).get("flops", 0.0)
+    hlo_flops = compiled.cost_analysis().get("flops", 0.0)
     shape = ShapeConfig("v", S, B, "train")
     ana = roofline.analytic_flops(cfg, shape, segments_for(cfg))
     ratio = ana["fwd_total"] / hlo_flops

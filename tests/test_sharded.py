@@ -29,8 +29,9 @@ from repro.core import occ_dp_means
 from repro.data import dp_stick_breaking_data
 x, _, _ = dp_stick_breaking_data(512, seed=1)
 x = jnp.asarray(x)
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((8,), ("data",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(AxisType.Auto,))
 r_local = occ_dp_means(x, 4.0, pb=64, k_max=128, max_iters=2)
 r_dist = occ_dp_means(x, 4.0, pb=64, k_max=128, max_iters=2, mesh=mesh)
 assert int(r_local.pool.count) == int(r_dist.pool.count)
@@ -52,8 +53,9 @@ from repro.distributed.shardings import shard_ctx
 from repro.models import build_model
 cfg = reduced(ARCHS["granite-3-2b"]).replace(dtype="float32")
 m = build_model(cfg)
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 4), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 B, CL = 4, 32
 with shard_ctx(mesh), mesh:
@@ -89,8 +91,9 @@ batch = {k: jnp.asarray(v) for k, v in pipe.batch_at(0).items()}
 state0 = train_state_init(m.init(jax.random.key(0)), tcfg)
 s_ref, met_ref = make_train_step(m, tcfg)(state0, batch)
 
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((2, 2, 2), ("pod", "data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                     axis_types=(AxisType.Auto,) * 3)
 with shard_ctx(mesh), mesh:
     state1 = train_state_init(m.init(jax.random.key(0)), tcfg)
     s_sh, met_sh = jax.jit(make_train_step(m, tcfg))(state1, batch)
@@ -109,8 +112,9 @@ def test_compressed_psum_shard_map():
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.optim.compression import compressed_psum_with_feedback, ef_init
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((4,), ("pod",))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4,), ("pod",),
+                     axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 g_all = jnp.asarray(rng.normal(size=(4, 64)).astype(np.float32))
 def body(g):
@@ -118,9 +122,8 @@ def body(g):
     ef = ef_init(grads)
     out, ef2 = compressed_psum_with_feedback(grads, ef, "pod")
     return out["w"], ef2.residual["w"]
-from repro.distributed.shardings import compat_shard_map
-summed, resid = compat_shard_map(body, mesh=mesh, in_specs=P("pod"),
-                                 out_specs=(P(), P("pod")))(g_all)
+summed, resid = jax.shard_map(body, mesh=mesh, in_specs=P("pod"),
+                              out_specs=(P(), P("pod")))(g_all)
 true = np.asarray(g_all).sum(0)
 err = np.abs(np.asarray(summed) - true).max()
 amax = np.abs(np.asarray(g_all)).max()
@@ -137,8 +140,9 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.checkpoint import CheckpointManager
 from repro.distributed.elastic import plan_shrunk_mesh, build_mesh_from_plan
-from repro.launch.mesh import compat_mesh
-mesh = compat_mesh((4, 2), ("data", "model"))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(AxisType.Auto,) * 2)
 w = jnp.arange(32, dtype=jnp.float32).reshape(8, 4)
 sharded = jax.device_put(w, NamedSharding(mesh, P("data", "model")))
 mgr = CheckpointManager({str(tmp_path)!r})

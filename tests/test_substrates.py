@@ -125,8 +125,8 @@ def test_checkpoint_missing_leaf_raises(tmp_path):
 # ------------------------------------------------------------------ elastic
 
 def test_elastic_plan_shrinks_data_axis():
-    from repro.launch.mesh import compat_mesh
-    mesh = compat_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 
     class FakeMesh:
         shape = {"pod": 2, "data": 16, "model": 16}
@@ -191,3 +191,30 @@ def test_curation_downweights_duplicates():
     assert rep.n_clusters >= 1
     assert rep.keep_weight.min() < 1.0       # the duplicate cluster got capped
     assert rep.keep_weight.max() <= 1.0
+
+
+# ------------------------------------------------------ compilation cache
+
+@pytest.mark.parametrize("env_dir", [None, "/var/cache/jax-programs"])
+def test_compile_cache_directory(monkeypatch, env_dir):
+    """JAX_COMPILATION_CACHE_DIR wins untouched; otherwise the cache sits at
+    the fixed, git-ignored <repo>/.jax_cache."""
+    from repro.launch.compile_cache import REPO_CACHE_DIR, use_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    try:
+        got = use_compile_cache()
+        if env_dir is None:
+            repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+            assert got == os.path.join(repo, ".jax_cache") == str(REPO_CACHE_DIR)
+            assert jax.config.jax_compilation_cache_dir == got
+            with open(os.path.join(repo, ".gitignore")) as f:
+                assert ".jax_cache/" in f.read().split()
+        else:
+            assert got == env_dir
+            assert jax.config.jax_compilation_cache_dir == before
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
