@@ -208,7 +208,8 @@ def measure(inject_sleep_ms: float = 0.0) -> dict:
         # supplementary: what the instrumented components measured about
         # themselves during the same run (same registry, free to export)
         "component_metrics": {
-            "engine_pass_s": _hist_summary(obs, "engine_pass_s"),
+            "engine_pass_s": {w: _hist_summary(obs, "engine_pass_s", width=w)
+                              for w in ("full", "capped")},
             "serve_request_s": _hist_summary(obs, "serve_request_s",
                                              model=""),
             "serve_queue_wait_s": _hist_summary(obs, "serve_queue_wait_s",

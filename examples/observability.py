@@ -56,10 +56,13 @@ def main():
         if line.startswith(("engine_p", "engine_accepted", "wal_appends",
                             "wal_checkpoints", "engine_pass_s_")):
             print(f"  {line}")
-    h = obs.metrics.get_histogram("engine_pass_s")
-    print(f"engine passes: {h.count}, pass p50 {h.percentile(50) * 1e3:.1f}ms"
-          f" (K={int(engine.pool.count)}, "
-          f"conflict_rate={obs.metrics.value('engine_conflict_rate'):.3f})")
+    for width in ("full", "capped"):    # the pass's validator width
+        h = obs.metrics.get_histogram("engine_pass_s", width=width)
+        if h is not None:
+            print(f"engine passes at {width} width: {h.count}, pass p50 "
+                  f"{h.percentile(50) * 1e3:.1f}ms")
+    print(f"K={int(engine.pool.count)}, "
+          f"conflict_rate={obs.metrics.value('engine_conflict_rate'):.3f}")
 
     trace = load_trace(trace_path)
     assert validate_trace(trace) == []

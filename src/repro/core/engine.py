@@ -66,7 +66,6 @@ the trainer keeps streaming — trainer and service share no mutable state.
 """
 from __future__ import annotations
 
-from contextlib import nullcontext
 from typing import Any, Callable, NamedTuple, Protocol, runtime_checkable
 
 import jax
@@ -77,6 +76,7 @@ from repro.core.occ import (
     CenterPool, OCCStats, ValidatePre, block_epochs, effective_cap,
     next_pow2, precomputed_gather_validate,
 )
+from repro.obs import span
 from repro.obs.metrics import now as _obs_now
 
 __all__ = ["OCCTransaction", "OCCEngine", "OCCPassResult",
@@ -207,18 +207,25 @@ def _finish_epoch(txn, pool, send, payload, aux, safe, valid_e, validate_cap,
     validator, writeback, overflow fold, epoch stats.  Split out of
     `_epoch_body` so the proposal block can come from ANYWHERE (the fused
     scan below, or worker processes streaming proposals over sockets in
-    `launch/occ_cluster.py`) while validation stays one code path."""
+    `launch/occ_cluster.py`) while validation stays one code path.
+
+    Its ops carry the named scopes `occ.compact` (valid-masking here, then
+    the validator's compaction), `occ.precompute`, `occ.scan` and
+    `occ.commit` (the pool write and scatter-back, writeback, overflow fold
+    and epoch stats), so a profile splits the master's device time."""
     b = valid_e.shape[0]
-    send = jnp.logical_and(send, valid_e)
+    with jax.named_scope("occ.compact"):
+        send = jnp.logical_and(send, valid_e)
     pool, slots, outs, sent_ovf = precomputed_gather_validate(
         pool, send, payload, aux, txn.precompute_accept, txn.accept_pre,
         cap=validate_cap, replicate=replicate, scan_mode=scan_mode)
-    assign_e = txn.writeback(send, slots, outs, safe, valid_e)
-    pool = pool._replace(overflow=jnp.logical_or(pool.overflow, sent_ovf))
-    n_sent = jnp.sum(send.astype(jnp.int32))
-    n_acc = jnp.sum((slots >= 0).astype(jnp.int32))
-    return pool, (assign_e, send, n_sent, n_acc,
-                  jnp.asarray(effective_cap(validate_cap, b), jnp.int32))
+    with jax.named_scope("occ.commit"):
+        assign_e = txn.writeback(send, slots, outs, safe, valid_e)
+        pool = pool._replace(overflow=jnp.logical_or(pool.overflow, sent_ovf))
+        n_sent = jnp.sum(send.astype(jnp.int32))
+        n_acc = jnp.sum((slots >= 0).astype(jnp.int32))
+        cap = jnp.asarray(effective_cap(validate_cap, b), jnp.int32)
+    return pool, (assign_e, send, n_sent, n_acc, cap)
 
 
 def _epoch_body(txn, pool, x_e, valid_e, state_e, validate_cap, scan_mode,
@@ -227,7 +234,8 @@ def _epoch_body(txn, pool, x_e, valid_e, state_e, validate_cap, scan_mode,
     of the serial bootstrap prefix) — always on the precomputed validator.
     `propose` replaces `txn.propose` (the mesh path's per-shard propose)."""
     propose = txn.propose if propose is None else propose
-    send, payload, aux, safe = propose(pool, x_e, state_e)
+    with jax.named_scope("occ.propose"):
+        send, payload, aux, safe = propose(pool, x_e, state_e)
     return _finish_epoch(txn, pool, send, payload, aux, safe, valid_e,
                          validate_cap, scan_mode, replicate)
 
@@ -242,91 +250,105 @@ def _engine_pass(txn, pool, x, state, *, pb, cap_warm, cap_rest, n_warm,
     of a cold pool sends everything, Thm 3.3's burn-in) and the rest at
     `cap_rest` (the adaptive Thm-3.3 bound).  Non-adaptive runs pass
     cap_warm == cap_rest and get the single-segment scan unchanged.
+
+    Named scopes tag every op of the compiled pass for the profiler (op
+    metadata only: the program is the same without them): `occ.pass` holds
+    the input stack/pad, the epoch loops and the output unstack, and
+    inside it each epoch's `occ.propose`, `occ.compact`, `occ.precompute`,
+    `occ.scan` and `occ.commit` (see `_finish_epoch`).
     """
     global _PASS_TRACES
     _PASS_TRACES += 1
-    n, d = x.shape
-    nb = n_bootstrap
+    with jax.named_scope("occ.pass"):
+        n, d = x.shape
+        nb = n_bootstrap
 
-    replicate = propose = None
-    if mesh is not None:
-        # The validator is the replicated master: pin its compacted (cap, …)
-        # buffers to the replicated spec so GSPMD gathers once at compaction
-        # instead of resharding mid-scan (shardings.occ_validate_sharding).
-        from repro.distributed.shardings import (
-            occ_propose_shard_map, occ_validate_sharding,
-        )
-        replicate = lambda a: jax.lax.with_sharding_constraint(
-            a, occ_validate_sharding(mesh, a.ndim))
-        propose = occ_propose_shard_map(txn.propose, mesh, data_axis, pb)
+        replicate = propose = None
+        if mesh is not None:
+            # The validator is the replicated master: pin its compacted
+            # (cap, …) buffers to the replicated spec so GSPMD gathers once
+            # at compaction instead of resharding mid-scan
+            # (shardings.occ_validate_sharding).
+            from repro.distributed.shardings import (
+                occ_propose_shard_map, occ_validate_sharding,
+            )
+            replicate = lambda a: jax.lax.with_sharding_constraint(
+                a, occ_validate_sharding(mesh, a.ndim))
+            propose = occ_propose_shard_map(txn.propose, mesh, data_axis, pb)
 
-    def epoch_at(cap, propose=None):
-        def epoch(pool, inp):
-            return _epoch_body(txn, pool, *inp, cap, scan_mode, replicate,
-                               propose)
-        return epoch
+        def epoch_at(cap, propose=None):
+            def epoch(pool, inp):
+                return _epoch_body(txn, pool, *inp, cap, scan_mode, replicate,
+                                   propose)
+            return epoch
 
-    # Serial bootstrap prefix (paper §4.2): width-1 epochs are exactly the
-    # serial algorithm — each point proposes against the fully up-to-date
-    # pool, so this reproduces serial_*_pass on x[:nb].
-    assign_b = None
-    if nb:
-        xb = x[:nb][:, None, :]
-        vb = jnp.ones((nb, 1), bool)
-        sb = jax.tree.map(lambda s: s[:nb][:, None], state)
-        pool, (ab, _, _, _, _) = jax.lax.scan(epoch_at(cap_warm), pool,
-                                              (xb, vb, sb))
-        assign_b = jax.tree.map(lambda a: a.reshape((nb,) + a.shape[2:]), ab)
+        # Serial bootstrap prefix (paper §4.2): width-1 epochs are exactly
+        # the serial algorithm — each point proposes against the fully
+        # up-to-date pool, so this reproduces serial_*_pass on x[:nb].
+        assign_b = None
+        if nb:
+            xb = x[:nb][:, None, :]
+            vb = jnp.ones((nb, 1), bool)
+            sb = jax.tree.map(lambda s: s[:nb][:, None], state)
+            pool, (ab, _, _, _, _) = jax.lax.scan(epoch_at(cap_warm), pool,
+                                                  (xb, vb, sb))
+            assign_b = jax.tree.map(
+                lambda a: a.reshape((nb,) + a.shape[2:]), ab)
 
-    # Main epochs: pad to T*pb, reshape to (T, pb, ...), scan per segment.
-    n_rest = n - nb
-    t_epochs = block_epochs(n_rest, pb)
-    pad = t_epochs * pb - n_rest
+        # Main epochs: pad to T*pb, reshape to (T, pb, ...), scan per
+        # segment.
+        n_rest = n - nb
+        t_epochs = block_epochs(n_rest, pb)
+        pad = t_epochs * pb - n_rest
 
-    def stack(a):
-        flat = jnp.concatenate(
-            [a[nb:], jnp.zeros((pad,) + a.shape[1:], a.dtype)], 0)
-        return flat.reshape((t_epochs, pb) + a.shape[1:])
+        def stack(a):
+            flat = jnp.concatenate(
+                [a[nb:], jnp.zeros((pad,) + a.shape[1:], a.dtype)], 0)
+            return flat.reshape((t_epochs, pb) + a.shape[1:])
 
-    xs = stack(x)
-    valid = stack(jnp.ones((n,), bool))
-    ss = jax.tree.map(stack, state)
-    if mesh is not None:
-        # Shard each epoch's points over the data axis: the optimistic phase
-        # parallelizes under GSPMD, the validation scan runs replicated
-        # (SPMD re-execution of the master).  See shardings.occ_epoch_spec.
-        from repro.distributed.shardings import occ_epoch_sharding
-        put = lambda a: jax.lax.with_sharding_constraint(
-            a, occ_epoch_sharding(mesh, data_axis, pb, a.ndim))
-        xs, valid = put(xs), put(valid)
-        ss = jax.tree.map(put, ss)
+        xs = stack(x)
+        valid = stack(jnp.ones((n,), bool))
+        ss = jax.tree.map(stack, state)
+        if mesh is not None:
+            # Shard each epoch's points over the data axis: the optimistic
+            # phase parallelizes under GSPMD, the validation scan runs
+            # replicated (SPMD re-execution of the master).  See
+            # shardings.occ_epoch_spec.
+            from repro.distributed.shardings import occ_epoch_sharding
+            put = lambda a: jax.lax.with_sharding_constraint(
+                a, occ_epoch_sharding(mesh, data_axis, pb, a.ndim))
+            xs, valid = put(xs), put(valid)
+            ss = jax.tree.map(put, ss)
 
-    t_warm = min(n_warm, t_epochs) if cap_warm != cap_rest else 0
-    seg_parts = []
-    for cap, lo, hi in ((cap_warm, 0, t_warm), (cap_rest, t_warm, t_epochs)):
-        if hi <= lo:
-            continue
-        cut = lambda a: a[lo:hi]
-        pool, part = jax.lax.scan(
-            epoch_at(cap, propose), pool,
-            (cut(xs), cut(valid), jax.tree.map(cut, ss)))
-        seg_parts.append(part)
-    am, sm, n_sent, n_acc, caps = jax.tree.map(
-        lambda *p: jnp.concatenate(p, 0), *seg_parts)
+        t_warm = min(n_warm, t_epochs) if cap_warm != cap_rest else 0
+        seg_parts = []
+        for cap, lo, hi in ((cap_warm, 0, t_warm),
+                            (cap_rest, t_warm, t_epochs)):
+            if hi <= lo:
+                continue
+            cut = lambda a: a[lo:hi]
+            pool, part = jax.lax.scan(
+                epoch_at(cap, propose), pool,
+                (cut(xs), cut(valid), jax.tree.map(cut, ss)))
+            seg_parts.append(part)
+        am, sm, n_sent, n_acc, caps = jax.tree.map(
+            lambda *p: jnp.concatenate(p, 0), *seg_parts)
 
-    unstack = lambda a: a.reshape((t_epochs * pb,) + a.shape[2:])[:n_rest]
-    assign = jax.tree.map(unstack, am)
-    send = unstack(sm)
-    if nb:
-        assign = jax.tree.map(lambda b, m: jnp.concatenate([b, m], 0),
-                              assign_b, assign)
-        # Bootstrapped points are processed by the master by construction.
-        send = jnp.concatenate([jnp.ones((nb,), bool), send], 0)
-    epoch_of = jnp.concatenate([
-        jnp.zeros((nb,), jnp.int32),
-        jnp.repeat(jnp.arange(t_epochs, dtype=jnp.int32), pb)[:n_rest]])
-    return OCCPassResult(pool, assign, send, epoch_of,
-                         OCCStats(proposed=n_sent, accepted=n_acc, cap=caps))
+        unstack = lambda a: a.reshape(
+            (t_epochs * pb,) + a.shape[2:])[:n_rest]
+        assign = jax.tree.map(unstack, am)
+        send = unstack(sm)
+        if nb:
+            assign = jax.tree.map(lambda b, m: jnp.concatenate([b, m], 0),
+                                  assign_b, assign)
+            # Bootstrapped points are processed by the master by construction.
+            send = jnp.concatenate([jnp.ones((nb,), bool), send], 0)
+        epoch_of = jnp.concatenate([
+            jnp.zeros((nb,), jnp.int32),
+            jnp.repeat(jnp.arange(t_epochs, dtype=jnp.int32), pb)[:n_rest]])
+        return OCCPassResult(
+            pool, assign, send, epoch_of,
+            OCCStats(proposed=n_sent, accepted=n_acc, cap=caps))
 
 
 _engine_pass_jit = jax.jit(
@@ -431,13 +453,12 @@ class OCCEngine:
         return (None, rest, 1) if cold else (rest, rest, 0)
 
     def _observe_stats(self, stats: OCCStats, cold: bool) -> None:
-        """Fold a committed pass's observed load into the Thm-3.3 estimate:
-        cap ≈ pow2(2 · (Pb·ε̂ + ΔK̂)) with ε̂, ΔK̂ the post-burn-in per-epoch
-        sent rate / pool growth."""
+        """Fold a committed pass's observed load (host arrays) into the
+        Thm-3.3 estimate: cap ≈ pow2(2 · (Pb·ε̂ + ΔK̂)) with ε̂, ΔK̂ the
+        post-burn-in per-epoch sent rate / pool growth."""
         if not self.adaptive:
             return
-        sent = np.asarray(stats.proposed)
-        acc = np.asarray(stats.accepted)
+        sent, acc = stats.proposed, stats.accepted
         if cold:                       # drop the burn-in epoch's full flood
             sent, acc = sent[1:], acc[1:]
         if sent.size == 0:
@@ -448,18 +469,17 @@ class OCCEngine:
             est = max(est, self._cap_est // 2)
         self._cap_est = None if est >= self.pb else est
 
-    def _export_pass(self, res: OCCPassResult, t0: float) -> None:
-        """Post-pass telemetry export (obs is set): fold the on-device
-        `OCCStats` into the registry and the trace WITHOUT adding dispatches
-        — the fused pass stays ONE compiled call; stats come back as arrays
-        from that call and are read on the host here.  Per-epoch spans are
-        synthesized by even subdivision of the measured pass interval
-        (flagged ``synthetic_timing`` — the fused scan has no per-epoch
-        host timestamps, by design)."""
+    def _export_pass(self, stats: OCCStats, t0: float, width: str) -> None:
+        """Post-pass telemetry export (obs is set): fold the pass's
+        `OCCStats`, already read to the host, into the registry and the
+        trace WITHOUT adding dispatches — the fused pass stays ONE compiled
+        call.  `engine_pass_s` times the pass from dispatch to its stats
+        read, labelled by validator `width`; `engine.pass` is the same
+        interval in the trace.  The fused scan has no per-epoch host spans:
+        per-epoch device time comes from the `occ.*` named scopes in a
+        profiler trace."""
         m = self.obs.metrics
-        prop = np.asarray(res.stats.proposed)    # blocks: pass is done
-        acc = np.asarray(res.stats.accepted)
-        cap = np.asarray(res.stats.cap)
+        prop, acc, cap = stats.proposed, stats.accepted, stats.cap
         t1 = _obs_now()
         n_epochs = int(prop.shape[0])
         n_prop, n_acc = int(prop.sum()), int(acc.sum())
@@ -473,52 +493,61 @@ class OCCEngine:
             m.gauge("engine_conflict_rate").set((n_prop - n_acc) / n_prop)
         if n_epochs:
             m.gauge("engine_cap").set(int(cap[-1]))
-        m.histogram("engine_pass_s").observe(t1 - t0)
-        tr = self.obs.tracer
-        if tr is not None:
-            ts0, dur = t0 * 1e6, (t1 - t0) * 1e6
-            tr.complete("engine.pass", ts0, dur, cat="engine",
-                        args=dict(epochs=n_epochs, proposed=n_prop,
-                                  accepted=n_acc,
-                                  dispatches=self.n_dispatches))
-            if n_epochs:
-                step = dur / n_epochs
-                for e in range(n_epochs):
-                    tr.complete(
-                        "engine.epoch", ts0 + e * step, step, cat="engine",
-                        args=dict(epoch=e, proposed=int(prop[e]),
-                                  accepted=int(acc[e]), cap=int(cap[e]),
-                                  synthetic_timing=True))
+        m.histogram("engine_pass_s", width=width).observe(t1 - t0)
+        if self.obs.tracer is not None:
+            self.obs.tracer.complete(
+                "engine.pass", t0 * 1e6, (t1 - t0) * 1e6, cat="engine",
+                args=dict(epochs=n_epochs, proposed=n_prop, accepted=n_acc,
+                          dispatches=self.n_dispatches, width=width))
+
+    def _launch(self, pool, x, state, cap_warm, cap_rest, n_warm,
+                n_bootstrap, mesh) -> OCCPassResult:
+        res = _engine_pass_jit(
+            self.txn, pool, x, state, pb=self.pb, cap_warm=cap_warm,
+            cap_rest=cap_rest, n_warm=n_warm, n_bootstrap=n_bootstrap,
+            mesh=mesh, data_axis=self.data_axis, scan_mode=self.scan_mode)
+        self.n_dispatches += 1
+        return res
 
     def _dispatch(self, pool, x, state, *, n_bootstrap: int, cold: bool,
                   mesh) -> OCCPassResult:
         """One compiled pass, with the adaptive overflow retry: a pass whose
         observed sends exceed its window is re-dispatched at full width
         (deterministic — same inputs), so committed adaptive results are
-        always bit-identical to full-cap results."""
-        t0 = _obs_now() if self.obs is not None else 0.0
+        always bit-identical to full-cap results.
+
+        The stats are read to the host only where they are used (adaptive
+        caps, telemetry); that read is where the host waits on the device.
+        A pass counts as `full` width when any main epoch ran its validator
+        at full width (a cold adaptive pool, no estimate yet, an unbounded
+        master) or it was retried — the overflowed capped attempt is the
+        cost of the full-width path — else `capped`."""
+        obs = self.obs
+        t0 = _obs_now() if obs is not None else 0.0
         cap_warm, cap_rest, n_warm = self._plan_caps(cold)
-        res = _engine_pass_jit(
-            self.txn, pool, x, state, pb=self.pb, cap_warm=cap_warm,
-            cap_rest=cap_rest, n_warm=n_warm, n_bootstrap=n_bootstrap,
-            mesh=mesh, data_axis=self.data_axis, scan_mode=self.scan_mode)
-        self.n_dispatches += 1
+        with span("engine.dispatch", obs, cat="engine"):
+            res = self._launch(pool, x, state, cap_warm, cap_rest, n_warm,
+                               n_bootstrap, mesh)
         self.cap_history.append(cap_rest)
-        if self.adaptive and cap_rest is not None:
-            if np.any(np.asarray(res.stats.proposed)
-                      > np.asarray(res.stats.cap)):
-                self.n_cap_retries += 1
-                self._cap_est = None       # estimate was wrong: reset wide
-                self.cap_history[-1] = None   # committed pass ran full-width
-                res = _engine_pass_jit(
-                    self.txn, pool, x, state, pb=self.pb, cap_warm=None,
-                    cap_rest=None, n_warm=0, n_bootstrap=n_bootstrap,
-                    mesh=mesh, data_axis=self.data_axis,
-                    scan_mode=self.scan_mode)
-                self.n_dispatches += 1
-        self._observe_stats(res.stats, cold)
-        if self.obs is not None:
-            self._export_pass(res, t0)
+        if not self.adaptive and obs is None:
+            return res
+        with span("engine.stats_wait", obs, cat="engine"):
+            stats = jax.device_get(res.stats)
+        full = self.pb in (effective_cap(cap_warm, self.pb),
+                           effective_cap(cap_rest, self.pb))
+        if self.adaptive and cap_rest is not None \
+                and np.any(stats.proposed > stats.cap):
+            self.n_cap_retries += 1
+            self._cap_est = None       # estimate was wrong: reset wide
+            self.cap_history[-1] = None   # committed pass ran full-width
+            full = True
+            with span("engine.retry", obs, cat="engine"):
+                res = self._launch(pool, x, state, None, None, 0,
+                                   n_bootstrap, mesh)
+                stats = jax.device_get(res.stats)
+        self._observe_stats(stats, cold)
+        if obs is not None:
+            self._export_pass(stats, t0, "full" if full else "capped")
         return res
 
     # ------------------------------------------------------------- batch
@@ -536,9 +565,10 @@ class OCCEngine:
                              n_bootstrap=min(int(n_bootstrap), x.shape[0]),
                              cold=cold, mesh=self.mesh)
         if self.publish is not None:
-            self.publish(res, n_seen=x.shape[0],
-                         epochs=res.stats.proposed.shape[0],
-                         cap_est=self._cap_est)
+            with span("engine.publish", self.obs, cat="engine"):
+                self.publish(res, n_seen=x.shape[0],
+                             epochs=res.stats.proposed.shape[0],
+                             cap_est=self._cap_est)
         return res
 
     def refine(self, pool: CenterPool, x: jnp.ndarray, assign: Any) -> CenterPool:
@@ -624,8 +654,6 @@ class OCCEngine:
             state = self.txn.make_state(x, 0)
 
         obs = self.obs
-        _span = obs.span if obs is not None else (
-            lambda *a, **k: nullcontext())
 
         # Serial bootstrap prefix: width-1 epochs, stats discarded and send
         # forced True — exactly the fused pass's bootstrap scan.
@@ -659,11 +687,11 @@ class OCCEngine:
             ge = epoch_base + e          # global epoch index (§14 resume)
             t0e = _obs_now() if obs is not None else 0.0
             cut = slice(e * self.pb, (e + 1) * self.pb)
-            with _span("engine.propose", cat="engine", epoch=ge):
+            with span("engine.propose", obs, cat="engine", epoch=ge):
                 s_, p_, a_, sf_, ve = propose_fn(
                     pool, xs[cut], jax.tree.map(lambda s: s[cut], ss),
                     valid[cut], epoch=ge, offset=nb + e * self.pb)
-            with _span("engine.validate", cat="engine", epoch=ge):
+            with span("engine.validate", obs, cat="engine", epoch=ge):
                 pool, (ae, sde, ns, na, ce) = _finish_epoch_jit(
                     self.txn, pool, s_, p_, a_, sf_, ve,
                     validate_cap=cap, scan_mode=sm)
@@ -674,8 +702,9 @@ class OCCEngine:
             acc_l.append(na)
             cap_l.append(ce)
             if obs is not None:
-                # Host-driven loop: REAL per-epoch telemetry (unlike the
-                # fused pass's synthesized post-pass spans).
+                # Host-driven loop: REAL per-epoch telemetry (the fused
+                # pass has none on the host; its scopes split the device
+                # time).
                 nsi, nai, cei = int(ns), int(na), int(ce)
                 m = obs.metrics
                 m.counter("engine_epochs").inc()
@@ -715,8 +744,9 @@ class OCCEngine:
                                      accepted=jnp.stack(acc_l),
                                      cap=jnp.stack(cap_l)))
         if self.publish is not None:
-            self.publish(res, n_seen=n, epochs=t_epochs,
-                         cap_est=self._cap_est)
+            with span("engine.publish", obs, cat="engine"):
+                self.publish(res, n_seen=n, epochs=t_epochs,
+                             cap_est=self._cap_est)
         return res
 
     # --------------------------------------------------------- streaming
@@ -852,8 +882,9 @@ class OCCEngine:
         res = res._replace(epoch_of=res.epoch_of + self._epoch_base)
         self._epoch_base += res.stats.proposed.shape[0]
         if self.publish is not None:
-            self.publish(res, n_seen=self.n_processed,
-                         epochs=self._epoch_base, cap_est=self._cap_est)
+            with span("engine.publish", self.obs, cat="engine"):
+                self.publish(res, n_seen=self.n_processed,
+                             epochs=self._epoch_base, cap_est=self._cap_est)
         return res
 
     def partial_fit(self, xb: jnp.ndarray, *, state: Any = None,
@@ -884,30 +915,36 @@ class OCCEngine:
         `init_mean`) are batching-independent.  `pool` (first call only)
         still seeds the stream with an explicit initial pool, e.g. a warm
         model restored from a snapshot.
+
+        The call is the host span `engine.partial_fit`; inside it
+        `engine.dispatch`, `engine.stats_wait`, `engine.retry` and
+        `engine.publish` (`_dispatch`, `_commit_stream_pass`) mark what the
+        host does while the device runs or idles.
         """
-        if pool is not None:
-            if self._pool is not None:
-                raise ValueError("pool= only seeds the FIRST partial_fit")
-            self._pool = pool
-        if state is None:
-            state = self.txn.make_state(xb, self._n_seen)
-        self._n_seen += xb.shape[0]
-        if self._carry_x is not None:
-            xb = jnp.concatenate([self._carry_x, xb], 0)
-            state = jax.tree.map(lambda c, s: jnp.concatenate([c, s], 0),
-                                 self._carry_state, state)
-        n = xb.shape[0]
-        n_full = (n // self.pb) * self.pb
-        if n_full < n:
-            self._carry_x = xb[n_full:]
-            self._carry_state = jax.tree.map(lambda s: s[n_full:], state)
-        else:
-            self._carry_x = self._carry_state = None
-        if n_full == 0:
-            return self._empty_stream_result(xb, state)
-        xb = xb[:n_full]
-        state = jax.tree.map(lambda s: s[:n_full], state)
-        return self._commit_stream_pass(xb, state)
+        with span("engine.partial_fit", self.obs, cat="engine"):
+            if pool is not None:
+                if self._pool is not None:
+                    raise ValueError("pool= only seeds the FIRST partial_fit")
+                self._pool = pool
+            if state is None:
+                state = self.txn.make_state(xb, self._n_seen)
+            self._n_seen += xb.shape[0]
+            if self._carry_x is not None:
+                xb = jnp.concatenate([self._carry_x, xb], 0)
+                state = jax.tree.map(lambda c, s: jnp.concatenate([c, s], 0),
+                                     self._carry_state, state)
+            n = xb.shape[0]
+            n_full = (n // self.pb) * self.pb
+            if n_full < n:
+                self._carry_x = xb[n_full:]
+                self._carry_state = jax.tree.map(lambda s: s[n_full:], state)
+            else:
+                self._carry_x = self._carry_state = None
+            if n_full == 0:
+                return self._empty_stream_result(xb, state)
+            xb = xb[:n_full]
+            state = jax.tree.map(lambda s: s[:n_full], state)
+            return self._commit_stream_pass(xb, state)
 
     def flush(self) -> OCCPassResult | None:
         """Commit the carried partial epoch as the stream's final short

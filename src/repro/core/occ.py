@@ -319,10 +319,11 @@ def precomputed_validate(
                      pre.pair_d2, aux))
 
     # One batched pool write: appended slots are unique by construction.
-    widx = jnp.where(slots_c >= 0, slots_c, k_max)   # out-of-range rows drop
-    centers = pool.centers.at[widx].set(
-        payload_c.astype(pool.centers.dtype), mode="drop")
-    mask = pool.mask.at[widx].set(True, mode="drop")
+    with jax.named_scope("occ.commit"):
+        widx = jnp.where(slots_c >= 0, slots_c, k_max)   # out-of-range drop
+        centers = pool.centers.at[widx].set(
+            payload_c.astype(pool.centers.dtype), mode="drop")
+        mask = pool.mask.at[widx].set(True, mode="drop")
     return CenterPool(centers, mask, count, overflow), slots_c, refs_c
 
 
@@ -398,10 +399,11 @@ def logdepth_validate(
         arg_new = jnp.argmin(d2_new, axis=0)
         use_new = best_new < pre.d2_start
         refs_c = jnp.where(use_new, slots_c[arg_new], pre.idx_start)
-        widx = jnp.where(slots_c >= 0, slots_c, k_max)
-        centers = pool.centers.at[widx].set(
-            payload_c.astype(pool.centers.dtype), mode="drop")
-        mask = pool.mask.at[widx].set(True, mode="drop")
+        with jax.named_scope("occ.commit"):
+            widx = jnp.where(slots_c >= 0, slots_c, k_max)
+            centers = pool.centers.at[widx].set(
+                payload_c.astype(pool.centers.dtype), mode="drop")
+            mask = pool.mask.at[widx].set(True, mode="drop")
         new_pool = CenterPool(centers, mask, count0 + rank[-1], pool.overflow)
         return new_pool, slots_c, refs_c
 
@@ -499,15 +501,16 @@ def precomputed_validate_gram(
 
     # Epoch-new features occupy contiguous slots [count0, count0 + nacc):
     # scatter the acceptance-ordered fit bits / residual rows to pool slots.
-    new_slots = count0 + jnp.arange(cap)
-    z_c = jnp.zeros((cap, k_max), bool).at[:, new_slots].set(
-        z_mat, mode="drop")
-    # ONE MXU materialisation
-    feats = jnp.matmul(coef, payload_c, precision=MATMUL_PRECISION)
-    widx = jnp.where(jnp.arange(cap) < nacc, new_slots, k_max)
-    centers = pool.centers.at[widx].set(
-        feats.astype(pool.centers.dtype), mode="drop")
-    mask = pool.mask.at[widx].set(True, mode="drop")
+    with jax.named_scope("occ.commit"):
+        new_slots = count0 + jnp.arange(cap)
+        z_c = jnp.zeros((cap, k_max), bool).at[:, new_slots].set(
+            z_mat, mode="drop")
+        # ONE MXU materialisation
+        feats = jnp.matmul(coef, payload_c, precision=MATMUL_PRECISION)
+        widx = jnp.where(jnp.arange(cap) < nacc, new_slots, k_max)
+        centers = pool.centers.at[widx].set(
+            feats.astype(pool.centers.dtype), mode="drop")
+        mask = pool.mask.at[widx].set(True, mode="drop")
     return CenterPool(centers, mask, count0 + nacc, overflow), slots_c, z_c
 
 
@@ -537,20 +540,27 @@ def precomputed_gather_validate(
     replicated sharding before the scan, so GSPMD gathers once at
     compaction instead of resharding mid-scan, at whatever cap the epoch
     runs with (see shardings.occ_validate_sharding).
+
+    The steps run under the named scopes `occ.compact`, `occ.precompute`,
+    `occ.scan` and `occ.commit` (the scans' batched pool write and the
+    scatter-back), which tag their ops in a profile.
     """
     b = send.shape[0]
     count0 = pool.count
     cap_c = effective_cap(cap, b)
-    order, sent_overflow = _compact_sent(send, cap_c)
-    send_c = send[order]
-    payload_c = payload[order]
-    aux_c = None if aux is None else jax.tree.map(lambda a: a[order], aux)
-    if replicate is not None:
-        send_c, payload_c = replicate(send_c), replicate(payload_c)
-        aux_c = None if aux_c is None else jax.tree.map(replicate, aux_c)
-    pre = precompute_fn(pool, payload_c, aux_c, count0)
-    if replicate is not None:
-        pre = jax.tree.map(replicate, pre)
+    with jax.named_scope("occ.compact"):
+        order, sent_overflow = _compact_sent(send, cap_c)
+        send_c = send[order]
+        payload_c = payload[order]
+        aux_c = None if aux is None else jax.tree.map(lambda a: a[order],
+                                                      aux)
+        if replicate is not None:
+            send_c, payload_c = replicate(send_c), replicate(payload_c)
+            aux_c = None if aux_c is None else jax.tree.map(replicate, aux_c)
+    with jax.named_scope("occ.precompute"):
+        pre = precompute_fn(pool, payload_c, aux_c, count0)
+        if replicate is not None:
+            pre = jax.tree.map(replicate, pre)
     if pre.gram is not None:
         validate = precomputed_validate_gram
     elif scan_mode == "logdepth":
@@ -559,6 +569,9 @@ def precomputed_gather_validate(
         validate = precomputed_validate
     else:
         raise ValueError(f"unknown scan_mode {scan_mode!r}")
-    pool, slots_c, refs_c = validate(pool, send_c, payload_c, pre, decide_fn)
-    slots, outs = _scatter_back(order, b, slots_c, refs_c)
+    with jax.named_scope("occ.scan"):
+        pool, slots_c, refs_c = validate(pool, send_c, payload_c, pre,
+                                         decide_fn)
+    with jax.named_scope("occ.commit"):
+        slots, outs = _scatter_back(order, b, slots_c, refs_c)
     return pool, slots, outs, sent_overflow
