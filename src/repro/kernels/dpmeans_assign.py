@@ -7,10 +7,23 @@ carried across center tiles — the same streaming-reduction structure as
 flash attention's running softmax.
 
 Grid: (n_blocks, k_blocks); the k axis is the sequential ("arbitrary")
-dimension so output tiles are revisited and accumulated in place.
-VMEM working set per step: bn*D (points) + bk*D (centers) + bn*bk (distances)
-— block defaults keep this well under a v5e core's ~16 MiB VMEM budget with
-D up to 8192.
+dimension so output tiles are revisited and accumulated in place.  The
+tiles come from the shape (`assign_tiles`): one row block for up to 1024
+rows — so an epoch's centers stream from HBM once — and the widest
+power-of-two multiple of 128 centers whose working set, bn*D (points) +
+bk*D (centers) + bn*bk (distances), double-buffered, fits `VMEM_BUDGET`.
+Fixed per-step cost is then paid once per wide tile, not once per 128
+centers.  Inside a step a loop walks the tile in column groups of at most
+GROUP_ELEMS distances, which bounds the unrolled body the compiler emits.
+
+Per-row-block work that does not depend on the centers is done once, at
+the first center step: ||x||^2 goes to VMEM scratch.  The running min and
+argmin are lane-wide, (bn, 128) in VMEM scratch: each tile folds into them
+one 128-lane slice at a time with elementwise compare/select (strict `<`,
+slices in ascending column order, so each lane keeps its lowest index of
+its minimum).  The one cross-lane reduction runs at the last center step:
+d2 = min over lanes, idx = the least index among lanes holding d2 — the
+lowest index wins ties, exactly as a global first-occurrence argmin.
 
 Active-prefix restriction: the pool's valid slots are a prefix (centers are
 appended serially), so `k_active` — the pool count, a *traced* scalar passed
@@ -27,13 +40,13 @@ prefix twice over:
 
 The grid stays static (K_max tiles, JAX needs static shapes) but both the
 compute AND the HBM transfer per epoch track the *occupied* pool size
-rather than the K_max capacity.
+rather than the K_max capacity; `assign_tile_steps` counts both.
 
 `dpmeans_assign_emulate` is a vmapped jnp re-implementation of the exact
-kernel schedule (same tiles, same f32 accumulation, same running-argmin
-tie-breaking, same prefix skipping) — the fast stand-in for interpret mode,
-whose per-grid-step Python loop is too slow to parity-check production
-shapes (serving buckets) in CI.
+kernel schedule (same tiles, same f32 accumulation, same lane-wide running
+state and final reduction, same prefix skipping) — the fast stand-in for
+interpret mode, whose per-grid-step Python loop is too slow to parity-check
+production shapes (serving buckets) in CI.
 """
 from __future__ import annotations
 
@@ -46,50 +59,180 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import MATMUL_PRECISION
 
-__all__ = ["dpmeans_assign", "dpmeans_assign_emulate"]
+__all__ = ["dpmeans_assign", "dpmeans_assign_emulate", "assign_tiles",
+           "assign_tile_steps", "VMEM_BUDGET"]
+
+LANES = 128
+MAX_ROWS = 1024
+# Bytes of (bn*D + bk*D + bn*bk) f32, double-buffered, a tile may count:
+# 12 of the 16 MiB scoped-VMEM default of a v5e core, which leaves room
+# for the (bn, 128) running state and the outputs.
+VMEM_BUDGET = 12 << 20
+# Distances one step of the loop over a tile's column groups computes.
+# The compiler unrolls the loop body over its vregs, so code size and
+# compile time follow the group's area, not the tile's: a whole 256 x 4096
+# tile at D=96 took 7.0 s to compile for a v5e, in groups of 512 1.3 s.
+GROUP_ELEMS = 256 * 512
+_INT_MAX = jnp.iinfo(jnp.int32).max
 
 
-def _assign_kernel(k_active_ref, x_ref, c_ref, mask_ref, d2_ref, idx_ref, *,
-                   bk: int):
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _tile_bytes(bn: int, bk: int, d: int) -> int:
+    return (bn * d + bk * d + bn * bk) * 4 * 2
+
+
+def assign_tiles(n: int, d: int, k: int) -> tuple[int, int]:
+    """(bn, bk): the kernel's row block and center tile for (N, D, K).
+
+    bn is all N rows up to MAX_ROWS (more rows split into equal blocks of
+    at most MAX_ROWS, multiples of 8); bk the widest 128 * 2^j, at most K,
+    whose `_tile_bytes` fit VMEM_BUDGET — a power of two so it divides the
+    power-of-two pool capacities without padding.  Where even a 128-wide
+    tile does not fit (very wide D), the row block halves until it does.
+    K below one lane width takes the whole K in one tile.
+    """
+    blocks = _cdiv(n, MAX_ROWS)
+    bn = max(8, n) if blocks == 1 else 8 * _cdiv(_cdiv(n, blocks), 8)
+    while bn > 8 and _tile_bytes(bn, LANES, d) > VMEM_BUDGET:
+        bn = 8 * _cdiv(bn // 2, 8)
+    if k < LANES:
+        return bn, max(8, k)
+    bk = LANES
+    while 2 * bk <= k and _tile_bytes(bn, 2 * bk, d) <= VMEM_BUDGET:
+        bk *= 2
+    return bn, bk
+
+
+def assign_tile_steps(count: int, n: int, d: int, k: int
+                      ) -> tuple[int, int]:
+    """(grid steps, live steps) of one default-tiled kernel call.
+
+    Grid steps are every (row block, center tile) pair of the static grid;
+    live steps those whose body runs — center tiles that start below
+    `count` — ceil(count / bk) per row block.  The rest pay only the
+    pipeline's fixed cost (their DMA is elided by the index-map clamp).
+    """
+    bn, bk = assign_tiles(n, d, k)
+    row_blocks = _cdiv(n, bn)
+    return row_blocks * _cdiv(k, bk), row_blocks * _cdiv(count, bk)
+
+
+def _blocks(n, d, k, block_n, block_k):
+    bn, bk = assign_tiles(n, d, k)
+    if block_n is not None:
+        bn = min(block_n, max(8, n))
+    if block_k is not None:
+        bk = min(block_k, max(8, k))
+    return bn, bk
+
+
+def _pad(x, centers, mask, bn, bk):
+    n, d = x.shape
+    k = centers.shape[0]
+    n_pad = (-n) % bn
+    k_pad = (-k) % bk
+    if n_pad:
+        x = jnp.concatenate([x, jnp.zeros((n_pad, d), x.dtype)], 0)
+    if k_pad:
+        centers = jnp.concatenate(
+            [centers, jnp.zeros((k_pad, d), centers.dtype)], 0)
+        mask = jnp.concatenate([mask, jnp.zeros((k_pad,), bool)], 0)
+    return x, centers, mask
+
+
+def _fold(d2, run_min, run_idx, base):
+    """Fold a (bn, w) block of distances into the lane-wide running state,
+    one lane-width slice at a time, in ascending column order; `base` is
+    the block's first column index.  Strict `<`: a lane keeps its lowest
+    index of its minimum."""
+    lanes = run_min.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, run_idx.shape, 1) + base
+    for j in range(d2.shape[-1] // lanes):
+        s = d2[:, j * lanes:(j + 1) * lanes]
+        better = s < run_min
+        run_min = jnp.where(better, s, run_min)
+        run_idx = jnp.where(better, lane + j * lanes, run_idx)
+    return run_min, run_idx
+
+
+def _finish(run_min, run_idx):
+    """The one cross-lane reduction: min over lanes, and the least index
+    among the lanes that hold it (-1 where every lane is still empty)."""
+    d2 = jnp.min(run_min, axis=-1, keepdims=True)
+    idx = jnp.min(jnp.where(run_min == d2, run_idx, _INT_MAX), axis=-1,
+                  keepdims=True)
+    return d2, idx
+
+
+def _lanes(bk: int) -> int:
+    return LANES if bk % LANES == 0 else bk
+
+
+def _group(bn: int, bk: int) -> int:
+    """Centers per step of the kernel's loop over one tile: the widest
+    lane multiple dividing bk with bn * bg <= GROUP_ELEMS, and at least
+    one lane width."""
+    lanes = _lanes(bk)
+    bg = lanes
+    while bk % (2 * bg) == 0 and bn * 2 * bg <= GROUP_ELEMS:
+        bg *= 2
+    return bg
+
+
+def _assign_kernel(k_active_ref, x_ref, c_ref, mask_ref, d2_ref, idx_ref,
+                   x2_ref, run_min_ref, run_idx_ref, *, bk: int,
+                   bg: int):
     kb = pl.program_id(1)
 
     @pl.when(kb == 0)
     def _init():
-        d2_ref[...] = jnp.full_like(d2_ref, jnp.inf)
-        idx_ref[...] = jnp.full_like(idx_ref, -1)
+        x = x_ref[...].astype(jnp.float32)
+        x2_ref[...] = jnp.sum(x * x, axis=-1, keepdims=True)   # (bn, 1)
+        run_min_ref[...] = jnp.full_like(run_min_ref, jnp.inf)
+        run_idx_ref[...] = jnp.full_like(run_idx_ref, -1)
 
     # Skip whole center tiles beyond the active prefix: every slot in the
     # tile is masked out anyway, so the running min/argmin cannot change.
     @pl.when(kb * bk < k_active_ref[0])
     def _work():
         x = x_ref[...].astype(jnp.float32)            # (bn, D)
-        c = c_ref[...].astype(jnp.float32)            # (bk, D)
-        m = mask_ref[...]                             # (1, bk)
 
-        x2 = jnp.sum(x * x, axis=-1, keepdims=True)   # (bn, 1)
-        c2 = jnp.sum(c * c, axis=-1)[None, :]         # (1, bk)
-        # MXU: the only O(bn*bk*D) term is a single matmul.
-        d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-            x, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
-            preferred_element_type=jnp.float32), 0.0)
-        d2 = jnp.where(m != 0, d2, jnp.inf)           # masked-out centers
+        def group(g, carry):
+            off = pl.multiple_of(g * bg, bg)
+            c = c_ref[pl.ds(off, bg), :].astype(jnp.float32)    # (bg, D)
+            m = mask_ref[g]                                      # (1, bg)
+            c2 = jnp.sum(c * c, axis=-1)[None, :]                # (1, bg)
+            # MXU: the only O(bn*bg*D) term is a single matmul.
+            d2 = jnp.maximum(x2_ref[...] + c2 - 2.0 * jax.lax.dot_general(
+                x, c, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
+                preferred_element_type=jnp.float32), 0.0)
+            d2 = jnp.where(m != 0, d2, jnp.inf)       # masked-out centers
+            run_min, run_idx = _fold(d2, run_min_ref[...], run_idx_ref[...],
+                                     kb * bk + off)
+            run_min_ref[...] = run_min
+            run_idx_ref[...] = run_idx
+            return carry
 
-        loc_min = jnp.min(d2, axis=-1, keepdims=True)               # (bn, 1)
-        loc_idx = (jnp.argmin(d2, axis=-1, keepdims=True).astype(jnp.int32)
-                   + kb * bk)
+        if bk == bg:
+            group(0, None)
+        else:
+            jax.lax.fori_loop(0, bk // bg, group, None)
 
-        run_min = d2_ref[...]
-        run_idx = idx_ref[...]
-        better = loc_min < run_min
-        d2_ref[...] = jnp.where(better, loc_min, run_min)
-        idx_ref[...] = jnp.where(better, loc_idx, run_idx)
+    @pl.when(kb == pl.num_programs(1) - 1)
+    def _reduce():
+        d2, idx = _finish(run_min_ref[...], run_idx_ref[...])
+        d2_ref[...] = d2
+        idx_ref[...] = idx
 
 
 @functools.partial(jax.jit,
                    static_argnames=("block_n", "block_k", "interpret"))
 def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
                    count: jnp.ndarray | None = None,
-                   block_n: int = 256, block_k: int = 128,
+                   block_n: int | None = None, block_k: int | None = None,
                    interpret: bool = False):
     """Min squared distance and argmin over masked centers.
 
@@ -97,19 +240,14 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
     optional) bounds the valid prefix — center tiles at index >= count are
     skipped entirely (mask must already be False there; the pool invariant
     guarantees it).  Returns (d2min (N,) f32, idx (N,) int32, -1 where no
-    valid center).  N, K are padded to block multiples internally.
+    valid center; ties go to the lowest index).  Tiles default to
+    `assign_tiles(N, D, K)`; `block_n`/`block_k` override them.  N, K are
+    padded to block multiples internally.
     """
     n, d = x.shape
     k = centers.shape[0]
-    bn = min(block_n, max(8, n))
-    bk = min(block_k, max(8, k))
-    n_pad = (-n) % bn
-    k_pad = (-k) % bk
-    if n_pad:
-        x = jnp.concatenate([x, jnp.zeros((n_pad, d), x.dtype)], 0)
-    if k_pad:
-        centers = jnp.concatenate([centers, jnp.zeros((k_pad, d), centers.dtype)], 0)
-        mask = jnp.concatenate([mask, jnp.zeros((k_pad,), bool)], 0)
+    bn, bk = _blocks(n, d, k, block_n, block_k)
+    x, centers, mask = _pad(x, centers, mask, bn, bk)
     np_, kp = x.shape[0], centers.shape[0]
     k_active = jnp.full((1,), k if count is None else count, jnp.int32)
 
@@ -123,22 +261,28 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
         return jnp.minimum(j, last), 0
 
     def _mask_tile(i, j, k_ref):
-        return 0, _center_tile(i, j, k_ref)[0]
+        return _center_tile(i, j, k_ref)[0], 0, 0
 
-    grid = (np_ // bn, kp // bk)
+    lanes = _lanes(bk)
+    bg = _group(bn, bk)
     d2, idx = pl.pallas_call(
-        functools.partial(_assign_kernel, bk=bk),
+        functools.partial(_assign_kernel, bk=bk, bg=bg),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(np_ // bn, kp // bk),
             in_specs=[
                 pl.BlockSpec((bn, d), lambda i, j, k_ref: (i, 0)),
                 pl.BlockSpec((bk, d), _center_tile),
-                pl.BlockSpec((1, bk), _mask_tile),
+                pl.BlockSpec((bk // bg, 1, bg), _mask_tile),
             ],
             out_specs=[
                 pl.BlockSpec((bn, 1), lambda i, j, k_ref: (i, 0)),
                 pl.BlockSpec((bn, 1), lambda i, j, k_ref: (i, 0)),
+            ],
+            scratch_shapes=[
+                pltpu.VMEM((bn, 1), jnp.float32),       # ||x||^2
+                pltpu.VMEM((bn, lanes), jnp.float32),   # running min
+                pltpu.VMEM((bn, lanes), jnp.int32),     # running argmin
             ],
         ),
         out_shape=[
@@ -146,7 +290,7 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
             jax.ShapeDtypeStruct((np_, 1), jnp.int32),
         ],
         interpret=interpret,
-    )(k_active, x, centers, mask.astype(jnp.int32)[None, :])
+    )(k_active, x, centers, mask.astype(jnp.int32).reshape(-1, 1, bg))
     return d2[:n, 0], idx[:n, 0]
 
 
@@ -154,31 +298,28 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
 def dpmeans_assign_emulate(x: jnp.ndarray, centers: jnp.ndarray,
                            mask: jnp.ndarray,
                            count: jnp.ndarray | None = None,
-                           block_n: int = 256, block_k: int = 128):
+                           block_n: int | None = None,
+                           block_k: int | None = None):
     """Vmapped emulation of the Pallas kernel's exact schedule.
 
     Same contract as `dpmeans_assign`, computed as vmap-over-n-blocks of a
-    scan-over-k-tiles that mirrors the kernel body op for op: identical
-    padding/clamping, the same f32 `dot_general` per tile, per-tile argmin
-    + running strict-< merge (so cross-tile ties resolve to the lower tile
-    exactly as the kernel does), and count-based tile skipping.  Runs as
-    ONE compiled XLA computation — no per-grid-step Python — so production
-    shapes (serving buckets, large K_max) can be parity-checked in CI where
-    interpret mode would take minutes.
+    scan-over-k-tiles that mirrors the kernel body op for op: the same tile
+    choice, padding and clamping, ||x||^2 once per row block, the same f32
+    distance algebra, the same lane-wide running fold (`_fold`, in the same
+    column order) and final cross-lane reduction (`_finish`), and
+    count-based tile skipping.  It computes a tile's distances in one
+    `dot_general` where the kernel walks column groups: each distance is
+    one row-column contraction either way.
+    Runs as ONE compiled XLA computation — no per-grid-step Python — so
+    production shapes (serving buckets, large K_max) can be parity-checked
+    in CI where interpret mode would take minutes.
     """
     n, d = x.shape
     k = centers.shape[0]
-    bn = min(block_n, max(8, n))
-    bk = min(block_k, max(8, k))
-    n_pad = (-n) % bn
-    k_pad = (-k) % bk
-    if n_pad:
-        x = jnp.concatenate([x, jnp.zeros((n_pad, d), x.dtype)], 0)
-    if k_pad:
-        centers = jnp.concatenate(
-            [centers, jnp.zeros((k_pad, d), centers.dtype)], 0)
-        mask = jnp.concatenate([mask, jnp.zeros((k_pad,), bool)], 0)
+    bn, bk = _blocks(n, d, k, block_n, block_k)
+    x, centers, mask = _pad(x, centers, mask, bn, bk)
     k_active = jnp.asarray(k if count is None else count, jnp.int32)
+    lanes = _lanes(bk)
 
     xb = x.reshape(-1, bn, d)
     cb = centers.reshape(-1, bk, d)
@@ -190,7 +331,6 @@ def dpmeans_assign_emulate(x: jnp.ndarray, centers: jnp.ndarray,
         x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
 
         def tile(carry, inp):
-            run_min, run_idx = carry
             kb, c, m = inp
             cf = c.astype(jnp.float32)
             c2 = jnp.sum(cf * cf, axis=-1)[None, :]
@@ -198,16 +338,16 @@ def dpmeans_assign_emulate(x: jnp.ndarray, centers: jnp.ndarray,
                 xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
                 preferred_element_type=jnp.float32), 0.0)
             d2 = jnp.where(m[None, :], d2, jnp.inf)
-            loc_min = jnp.min(d2, axis=-1)
-            loc_idx = jnp.argmin(d2, axis=-1).astype(jnp.int32) + kb * bk
-            better = jnp.logical_and(loc_min < run_min, kb * bk < k_active)
-            return (jnp.where(better, loc_min, run_min),
-                    jnp.where(better, loc_idx, run_idx)), None
+            folded = _fold(d2, *carry, kb * bk)
+            live = kb * bk < k_active
+            return jax.tree.map(lambda new, old: jnp.where(live, new, old),
+                                folded, carry), None
 
-        init = (jnp.full((bn,), jnp.inf, jnp.float32),
-                jnp.full((bn,), -1, jnp.int32))
-        (d2m, idxm), _ = jax.lax.scan(tile, init, (kbs, cb, mb))
-        return d2m, idxm
+        init = (jnp.full((bn, lanes), jnp.inf, jnp.float32),
+                jnp.full((bn, lanes), -1, jnp.int32))
+        (run_min, run_idx), _ = jax.lax.scan(tile, init, (kbs, cb, mb))
+        d2m, idxm = _finish(run_min, run_idx)
+        return d2m[:, 0], idxm[:, 0]
 
     d2, idx = jax.vmap(one_block)(xb)
     return d2.reshape(-1)[:n], idx.reshape(-1)[:n]

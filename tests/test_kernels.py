@@ -70,6 +70,54 @@ def test_dpmeans_assign_count_prefix_parity(rng, count):
         assert np.all(np.asarray(ip) == -1)
 
 
+@pytest.mark.parametrize("n,d,k", [
+    (256, 96, 65536),      # deep96.train: pb=256 at DEEP width
+    (1024, 512, 65536),    # laion512.train: pb=1024 at CLIP width
+    (256, 512, 65536),     # laion512.train.4chip: 256 rows a chip
+    (8, 96, 32768),        # the smallest serving bucket
+    (4096, 96, 32768),     # the largest serving bucket
+    (1500, 96, 1000),      # rows split evenly, K not a power of two
+    (100, 8, 37),          # K below one lane width
+    (1024, 4096, 65536),   # too wide for 1024 rows: the block shrinks
+])
+def test_assign_tiles(n, d, k):
+    """The default tiles: one row block up to 1024 rows, the widest
+    power-of-two multiple of 128 centers (at most K) within the VMEM
+    budget, and live steps = ceil(count / bk) per row block."""
+    from repro.kernels.dpmeans_assign import (
+        VMEM_BUDGET, assign_tile_steps, assign_tiles)
+    bn, bk = assign_tiles(n, d, k)
+    blocks = -(-n // bn)
+    if n <= 1024 and d <= 512:
+        assert blocks == 1 and bn == max(n, 8)
+    assert bn % 8 == 0 or bn == n
+    assert (bn * d + bk * d + bn * bk) * 4 * 2 <= VMEM_BUDGET
+    if k < 128:
+        assert bk == max(8, k)
+    else:
+        assert bk % 128 == 0 and bk <= k
+        assert (bk // 128) & (bk // 128 - 1) == 0
+        wider = 2 * bk
+        assert (wider > k
+                or (bn * d + wider * d + bn * wider) * 4 * 2 > VMEM_BUDGET)
+    k_tiles = -(-k // bk)
+    for count in (0, 1, k // 3, k // 2 + 7, k):
+        assert assign_tile_steps(count, n, d, k) == (
+            blocks * k_tiles, blocks * -(-count // bk))
+
+
+def test_assign_tiles_at_the_cells():
+    """Tiles, grid steps and live steps of one propose epoch at the three
+    benchmark cells' shapes and pool sizes (PERF.md records them)."""
+    from repro.kernels.dpmeans_assign import assign_tile_steps, assign_tiles
+    assert assign_tiles(256, 96, 65536) == (256, 4096)
+    assert assign_tile_steps(33986, 256, 96, 65536) == (16, 9)
+    assert assign_tiles(1024, 512, 65536) == (1024, 512)
+    assert assign_tile_steps(52552, 1024, 512, 65536) == (128, 103)
+    assert assign_tiles(256, 512, 65536) == (256, 1024)
+    assert assign_tile_steps(52552, 256, 512, 65536) == (64, 52)
+
+
 def test_assign_ref_matches_legacy_nearest_center_semantics(rng):
     """ops.assign(ref) == masked sq_dists min/argmin with -1 on empty — the
     exact contract core.occ.nearest_center is built on."""
@@ -136,26 +184,59 @@ def test_backend_resolution():
 
 # ------------------------------------------------- emulation harness (CI)
 
-@pytest.mark.parametrize("n,k,d,count", [
-    (17, 5, 3, None), (33, 130, 8, 37), (20, 37, 6, 0), (20, 37, 6, 8),
-    (7, 130, 8, 100),
+_SMALL_BLOCKS = {"block_n": 16, "block_k": 8}
+
+
+@pytest.mark.parametrize("n,k,d,count,blocks", [
+    pytest.param(*case, _SMALL_BLOCKS, id="-".join(map(str, case)))
+    for case in [(17, 5, 3, None), (33, 130, 8, 37), (20, 37, 6, 0),
+                 (20, 37, 6, 8), (7, 130, 8, 100)]
+] + [
+    # 128-lane running state: two lane slices per 256-wide tile, three
+    # tiles, the third beyond the count
+    pytest.param(40, 600, 12, 300, {"block_n": 16, "block_k": 256},
+                 id="lane-slices"),
+    # the default tiles: one row block, 512-wide center tiles
+    pytest.param(24, 1000, 8, None, {}, id="default-tiles"),
+    # one 1024-wide tile computed in four 256-wide column groups
+    pytest.param(512, 1024, 8, 700, {}, id="column-groups"),
 ])
-def test_emulate_bitwise_matches_interpret(rng, n, k, d, count):
+def test_emulate_bitwise_matches_interpret(rng, n, k, d, count, blocks):
     """`dpmeans_assign_emulate` mirrors the kernel schedule op for op, so
     on shapes interpret mode CAN sweep the two are BIT-identical (same
-    tiles, same f32 dot_general, same running-argmin merges) — which is
-    what licenses the emulation as the large-shape parity oracle."""
+    tiles, same f32 dot_general, same lane-wide running fold and final
+    reduction) — which is what licenses the emulation as the large-shape
+    parity oracle."""
     x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
     m = (jnp.asarray(np.arange(k) < count) if count is not None
          else jnp.asarray(rng.uniform(size=k) > 0.25))
     cnt = None if count is None else jnp.asarray(count, jnp.int32)
-    d2p, ip = ops.assign(x, c, m, count=cnt, backend="pallas",
-                         block_n=16, block_k=8)
-    d2e, ie = ops.assign(x, c, m, count=cnt, backend="emulate",
-                         block_n=16, block_k=8)
+    d2p, ip = ops.assign(x, c, m, count=cnt, backend="pallas", **blocks)
+    d2e, ie = ops.assign(x, c, m, count=cnt, backend="emulate", **blocks)
     np.testing.assert_array_equal(np.asarray(d2p), np.asarray(d2e))
     np.testing.assert_array_equal(np.asarray(ip), np.asarray(ie))
+
+
+@pytest.mark.parametrize("backend", ["ref", "emulate", "pallas"])
+def test_assign_duplicate_centers_lowest_index_wins(rng, backend):
+    """Exact duplicate centers tie exactly; the lowest index wins on every
+    backend: a pair in two lanes of one tile (3, 70), a pair in one lane
+    of two lane slices (9, 137), and a pair across tiles (5, 300)."""
+    k, d = 512, 8
+    c = rng.normal(size=(k, d)).astype(np.float32)
+    pairs = [(3, 70), (9, 137), (5, 300)]
+    for lo, hi in pairs:
+        c[hi] = c[lo]
+    x = np.concatenate([c[[lo for lo, _ in pairs]],
+                        c[[lo for lo, _ in pairs]]
+                        + 1e-3 * rng.normal(size=(3, d)).astype(np.float32)])
+    kw = {} if backend == "ref" else {"block_n": 8, "block_k": 256}
+    d2, idx = ops.assign(jnp.asarray(x), jnp.asarray(c),
+                         count=jnp.asarray(400, jnp.int32), backend=backend,
+                         **kw)
+    lows = [lo for lo, _ in pairs]
+    np.testing.assert_array_equal(np.asarray(idx), lows + lows)
 
 
 def test_emulate_production_shape_parity(rng):

@@ -89,6 +89,22 @@ def test_dpmeans_assign_compiles(chip, rows):
         chip((), jnp.int32))
 
 
+@pytest.mark.parametrize("rows,d", [
+    (1024, 512),     # laion512.train: the epoch in one row block
+    (256, D),        # deep96.train
+    (256, 512),      # laion512.train.4chip: one chip's quarter
+])
+def test_dpmeans_assign_compiles_at_cell_shapes(chip, rows, d):
+    """The benchmark cells' propose shapes at their real K_max, with the
+    default tiles: a tile past the VMEM budget fails here, not on the
+    chip."""
+    k = 65536
+    _kernel_compiled(
+        lambda x, c, m, n: dpmeans_assign(x, c, m, count=n),
+        chip((rows, d)), chip((k, d)), chip((k,), jnp.bool_),
+        chip((), jnp.int32))
+
+
 @pytest.mark.parametrize("rows", SERVE_BUCKETS)
 def test_topk_stream_compiles(chip, rows):
     _kernel_compiled(
