@@ -1,5 +1,6 @@
-"""What every cell shares: the checkout's paths, the device check, the peaks
-table, the compile clock, the data generator and the result line.
+"""What every cell shares: the checkout's paths, the configuration and its
+algorithm file, the device check, the peaks table, the compile clock, the
+data generator and the result line.
 
 Nothing here imports the program under test (`repro`): the data and the
 yardstick belong to the benchmark, so a change to the program cannot move
@@ -8,6 +9,7 @@ them.
 from __future__ import annotations
 
 import glob
+import importlib.util
 import json
 import math
 import os
@@ -21,6 +23,8 @@ ROOT = os.path.dirname(HERE)
 # share it, and at a path that does not move, since the path is part of
 # the cache's key.  `repro.launch.compile_cache` names the same directory.
 CACHE_DIR = os.path.join(ROOT, ".jax_cache")
+# One file per OCC algorithm, named by a configuration's `algorithm` key.
+ALGORITHMS = os.path.join(HERE, "algorithms")
 
 
 class NoChip(Exception):
@@ -54,10 +58,50 @@ def find_cell(spec: dict, name: str) -> dict:
 
 
 def config_of(spec: dict, cell: dict) -> dict:
+    """The cell's configuration file, which has to name its algorithm."""
     for c in spec["configs"]:
         if c["name"] == cell["config"]:
-            return load_json(os.path.join(ROOT, c["file"]))
+            path = os.path.join(ROOT, c["file"])
+            cfg = load_json(path)
+            if "algorithm" not in cfg:
+                raise SystemExit(f"{path} names no algorithm: it needs an "
+                                 '"algorithm" key naming a file of '
+                                 f"{ALGORITHMS}")
+            return cfg
     raise SystemExit(f"no config named {cell['config']!r}")
+
+
+def load_module(path: str, name: str):
+    """The Python file at `path`, loaded by path as a module of its own."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def algorithm(cfg: dict, directory: str = ALGORITHMS):
+    """The configuration's OCC algorithm: `<directory>/<algorithm>.py`.
+
+    It gives the harness what differs between algorithms:
+      transaction(cfg, seed)  the program's transaction for one job;
+      check(x, answer, cfg, mix), control(x, answer, cfg, mix, dtype)
+                              the readings `correct` compares, keyed as in
+                              `limits/<cell>.json`, of the program's answer
+                              and of the control's; `answer` is (assign,
+                              send, pool): the job's `partial_fit` and
+                              `flush` outputs joined, and its last pool;
+      propose_work(k_start, accepted, pb, d)
+                              (flops, bytes) per epoch of the propose phase;
+      PROPOSE_KERNEL          the propose kernel's name in the device trace,
+                              or None where the propose is no Pallas call;
+      small(cfg), FAULTS      the CPU size and the planted faults of the
+                              tests (`tests/test_correct.py`).
+    There is no default: a missing file is an error that names its path."""
+    path = os.path.join(directory, cfg["algorithm"] + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"no algorithm file {path} for the configuration "
+                         f"{cfg.get('name')!r}")
+    return load_module(path, "algorithm_" + cfg["algorithm"])
 
 
 def mix_of(cell: dict) -> dict:

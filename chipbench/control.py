@@ -6,14 +6,16 @@ cell's own size: the program's, and the control's.
 
 The control is the reference put in the program's place and computed one
 step below the configuration's float32: in one bfloat16 pass.  For a
-training cell it recomputes every point's assignment and send decision in
-bfloat16 against the pool the program built; for a serving cell it answers
-every request of the cell's traffic in bfloat16.  Both are judged by the
-same float32 reference the benchmark's runs use (`reference.py`).  One
-process runs every seed; the program's training readings need no measured
-window (one job per seed), its serving readings a short window at the
-cell's own load.  Prints one JSON line per seed and a last line with the
-largest program reading and the smallest control reading of each number.
+training cell the configuration's algorithm file says what it recomputes
+(`control()`; DP-means: every point's assignment and send decision, against
+the pool the program built); for a serving cell it answers every request of
+the cell's traffic in bfloat16.  Both are judged by the same float32
+reference the benchmark's runs use (the algorithm's `check()`;
+`reference.py`).  One process runs every seed; the program's training
+readings need no measured window (one job per seed), its serving readings
+a short window at the cell's own load.  Prints one JSON line per seed and a
+last line with the largest program reading and the smallest control
+reading of each number.
 """
 from __future__ import annotations
 
@@ -33,18 +35,16 @@ import common  # noqa: E402
 
 def train_readings(cfg, mix, seed, devs, dtype):
     import jax.numpy as jnp
-    import reference
     import traffic
 
+    algo = common.algorithm(cfg)
     x = traffic.job_data(cfg, mix, seed)
     (assigns, sends, pool), _ = traffic._one_job(
-        x, cfg, dict(mix, _cell="control"), traffic._mesh(devs, mix))
-    a, s = jnp.concatenate(assigns), jnp.concatenate(sends)
-    kw = dict(pb=cfg["pb"], lam=cfg["lam"], block=mix.get("check_block", 1024))
-    prog = reference.check_job(x, pool.centers, pool.count, a, s, **kw)
-    ctrl = reference.check_job_control(x, pool.centers, pool.count, a, s,
-                                       dtype=dtype, **kw)
-    return prog, ctrl
+        x, cfg, dict(mix, _cell="control"), traffic._mesh(devs, mix),
+        algo.transaction(cfg, seed))
+    answer = (jnp.concatenate(assigns), jnp.concatenate(sends), pool)
+    return (algo.check(x, answer, cfg, mix),
+            algo.control(x, answer, cfg, mix, dtype))
 
 
 def serve_readings(cfg, mix, cell, seed, seconds, devs, limits, dtype):

@@ -13,10 +13,10 @@ F32 = 4
 
 
 def propose_epochs(accepted, pb: int, d: int):
-    """(flops, bytes) per epoch of the propose phase: each of the epoch's
-    pb points against the K_e centers live at the epoch's start, K_e the
-    sum of the earlier epochs' accepts.  Each center and each point is read
-    once."""
+    """(flops, bytes) per epoch of a nearest-center propose phase (DP-means,
+    OFL): each of the epoch's pb points against the K_e centers live at the
+    epoch's start, K_e the sum of the earlier epochs' accepts.  Each center
+    and each point is read once."""
     acc = np.asarray(accepted, np.float64)
     k_e = np.concatenate([[0.0], np.cumsum(acc)[:-1]])
     flops = 2.0 * pb * k_e * d
@@ -40,6 +40,6 @@ def min_seconds(flops, nbytes, peaks: dict) -> float:
 
 
 # Kernel names as the device trace shows them: a Pallas call's op is named
-# after the jitted function that makes it.
-PROPOSE_KERNEL = "dpmeans_assign"
+# after the jitted function that makes it.  The propose kernel's is its
+# algorithm's (`algorithms/<name>.py` PROPOSE_KERNEL).
 SERVE_KERNELS = ("dpmeans_assign", "topk_stream")

@@ -4,14 +4,16 @@
     python3 chipbench/run.py --workload <cell> --seed <n> --seconds <s> \
         --trace <0|1>
 
-From the root of a checkout.  Everything a cell is comes from data: its
-entry in BENCHMARK.json, its configuration (`chipbench/configs/`), its
-traffic mix (`chipbench/mixes/`), the limits `correct` is held to
+From the root of a checkout.  Everything a cell is comes from files found
+by name: its entry in BENCHMARK.json, its configuration
+(`chipbench/configs/`) and the OCC algorithm that names
+(`chipbench/algorithms/<algorithm>.py`), its traffic mix
+(`chipbench/mixes/`), the limits `correct` is held to
 (`chipbench/limits/<cell>.json`) and one reader per per-layer metric
 (`chipbench/layers/<metric>.py`).  One process: it finds the chips (and
 exits non-zero, printing no result, where JAX finds no TPU or too few),
 makes its data on the device from the seed, warms up, measures for
-`--seconds`, checks the answers against the plain reference
+`--seconds`, checks the answers against the algorithm's plain reference
 (`chipbench/reference.py`) and prints one JSON line as the last line of
 standard output.  With `--trace 1` it traces part of the window with the
 JAX profiler and prints the per-layer metrics in place of the end-to-end
@@ -24,7 +26,6 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -38,11 +39,8 @@ import common  # noqa: E402
 
 
 def layer_reader(name: str):
-    path = os.path.join(HERE, "layers", name + ".py")
-    spec = importlib.util.spec_from_file_location("layer_" + name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return common.load_module(os.path.join(HERE, "layers", name + ".py"),
+                              "layer_" + name).read
 
 
 def metrics_of(entries: list, cell: dict, reported: set | None = None):
@@ -62,12 +60,14 @@ def metrics_of(entries: list, cell: dict, reported: set | None = None):
 def run_cell(spec: dict, name: str, seed: int, seconds: float, trace: bool,
              *, devices=common.require_devices,
              config=None, mix=None, limits=None, peaks=None,
-             t_start=T_START) -> dict:
+             algorithms=common.ALGORITHMS, t_start=T_START) -> dict:
     """One run of one cell.  Returns the result line as a dict.  `devices`
     and the overrides exist for the tests, which drive a run on the CPU at
-    a small size, some with the timed path broken underneath."""
+    a small size, some with the timed path broken underneath, some with
+    algorithm files of their own (`algorithms`, a directory)."""
     cell = common.find_cell(spec, name)
     cfg = config if config is not None else common.config_of(spec, cell)
+    algo = common.algorithm(cfg, algorithms)
     mix = mix if mix is not None else common.mix_of(cell)
     if limits is None:
         limits = common.load_json(
@@ -78,7 +78,7 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, trace: bool,
     clock = common.CompileClock()
     import traffic
     run = traffic.SHAPES[mix["shape"]](cfg, mix, cell, seed, seconds, trace,
-                                        devs, clock, t_start, limits)
+                                        devs, clock, t_start, limits, algo)
     common.log("set-up: " + ", ".join(
         f"{k} {v:.4f}" for k, v in run.setup_parts.items())
         + f", setup_s {run.end_to_end['setup_s']:.4f}")
@@ -93,8 +93,8 @@ def run_cell(spec: dict, name: str, seed: int, seconds: float, trace: bool,
         red = tracing.reduce_dir(run.traced, len(devs))
         device.update(busy_s=red.busy_s, window_s=red.window_s)
         line["breakdown"] = red.breakdown()
-        ctx = {"cell": cell, "config": cfg, "mix": mix, "peaks": peaks,
-               "counters": run.counters, "trace": red}
+        ctx = {"cell": cell, "config": cfg, "algorithm": algo, "mix": mix,
+               "peaks": peaks, "counters": run.counters, "trace": red}
         metrics = {}
         for m in metrics_of(spec["per_layer"], cell,
                             {m["name"] for m in e2e}):

@@ -2,11 +2,12 @@
 devices, in a process of its own (`test_correct.py` starts it with
 XLA_FLAGS=--xla_force_host_platform_device_count=4).
 
-    python chipbench/tests/mesh_case.py [no_exchange]
+    python chipbench/tests/mesh_case.py [no_exchange | readings SEED]
 
 Prints the result line.  `no_exchange` leaves out the exchange between the
 chips: each chip's proposals stay on it, and the replicated validator sees
-only the first chip's."""
+only the first chip's.  `readings SEED` prints instead what
+`test_same_readings.py` compares, of a run on that seed."""
 from __future__ import annotations
 
 import json
@@ -31,6 +32,11 @@ def no_exchange():
 
 
 if __name__ == "__main__":
+    if sys.argv[1:2] == ["readings"]:
+        import test_same_readings
+        print(json.dumps(test_same_readings.readings(
+            "laion512.train.4chip", int(sys.argv[2]))))
+        sys.exit(0)
     if sys.argv[1:] == ["no_exchange"]:
         no_exchange()
     print(json.dumps(tc.run_small("laion512.train.4chip")))

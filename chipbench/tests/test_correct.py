@@ -5,7 +5,9 @@ run of the harness, turns `correct` false.
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest chipbench/tests
 
 The look for a chip is skipped (`devices=`); everything else is the run a
-cell makes, with the cell's own limits (`chipbench/limits/`).
+cell makes, with the cell's own limits (`chipbench/limits/`).  A training
+cell's small size and its faults come from its algorithm's file
+(`chipbench/algorithms/<algorithm>.py`: `small`, `FAULTS`).
 """
 from __future__ import annotations
 
@@ -25,6 +27,7 @@ sys.path.insert(1, os.path.join(os.path.dirname(BENCH), "src"))
 import common  # noqa: E402
 import control  # noqa: E402
 import run  # noqa: E402
+import traffic  # noqa: E402
 
 SPEC = common.benchmark_spec()
 PEAKS = {"flops_per_s": 1e12, "hbm_bytes_per_s": 1e11}
@@ -37,17 +40,18 @@ SERVE_CELLS = [c["name"] for c in SPEC["workloads"]
                if common.mix_of(c)["shape"] == "open_loop"]
 
 
+def algorithm_of(cell: str):
+    return common.algorithm(common.config_of(SPEC,
+                                             common.find_cell(SPEC, cell)))
+
+
 def small_config(cell: str) -> dict:
-    """The cell's configuration at a size the CPU runs in seconds: its
-    width, fewer components, points and slots, and λ = 1 with more noise,
-    so that components hold several centers and some decisions fall near
-    λ, as they do at full size."""
-    cfg = dict(common.config_of(SPEC, common.find_cell(SPEC, cell)))
-    cfg.update(n_components=256, n_points=16384, k_max=4096, noise=0.8,
-               lam=1.0,
-               pb=min(cfg["pb"], 256))
-    if common.find_cell(SPEC, cell)["chips"] > 1:
-        cfg["pb"] = 64 * common.find_cell(SPEC, cell)["chips"]
+    """The cell's configuration at the size its algorithm runs on the CPU
+    (`small`); on several chips, 64 points a chip to an epoch."""
+    c = common.find_cell(SPEC, cell)
+    cfg = algorithm_of(cell).small(common.config_of(SPEC, c))
+    if c["chips"] > 1:
+        cfg["pb"] = 64 * c["chips"]
     return cfg
 
 
@@ -71,6 +75,28 @@ def run_small(cell: str, seconds: float = 0.3, **mix_over) -> dict:
                         config=small_config(cell),
                         mix=small_mix(cell, **mix_over), peaks=PEAKS,
                         t_start=time.perf_counter())
+
+
+def run_counted(cell: str, seed: int, seconds: float = 0.0, **over):
+    """One small run of a training cell: its result line, and the counters
+    the jobs generator handed the readers (K, epochs).  A window of 0 s
+    holds one job.  `over` replaces `run_cell`'s keyword arguments."""
+    kw = dict(devices=devices, config=small_config(cell),
+              mix=small_mix(cell), peaks=PEAKS, t_start=time.perf_counter())
+    kw.update(over)
+    runs = []
+    jobs = traffic.SHAPES["jobs"]
+
+    def counted(*args):
+        runs.append(jobs(*args))
+        return runs[-1]
+
+    traffic.SHAPES["jobs"] = counted
+    try:
+        out = run.run_cell(SPEC, cell, seed, seconds, False, **kw)
+    finally:
+        traffic.SHAPES["jobs"] = jobs
+    return out, runs[0].counters
 
 
 def limits_of(cell: str) -> dict:
@@ -110,54 +136,9 @@ def test_serve_control_fails(cell):
 
 # ------------------------------------------------------ faults, training
 
-def _stale_state(monkeypatch):
-    """A pass that returns the pool it was given: no center is ever kept."""
-    from repro.core.engine import OCCEngine
-    orig = OCCEngine._commit_stream_pass
-
-    def commit(self, xb, state):
-        before = self._pool
-        if before is None:
-            before = self.txn.init_pool(xb[:self.pb])
-        res = orig(self, xb, state)
-        self._pool = before
-        return res._replace(pool=before)
-
-    monkeypatch.setattr(OCCEngine, "_commit_stream_pass", commit)
-
-
-def _half_batch(monkeypatch):
-    """Half of each call's points left out; their answers copied from the
-    half that ran."""
-    import jax.numpy as jnp
-    from repro.core.engine import OCCEngine
-    orig = OCCEngine.partial_fit
-
-    def partial_fit(self, xb, **kw):
-        res = orig(self, xb[:xb.shape[0] // 2], **kw)
-        return res._replace(assign=jnp.concatenate([res.assign] * 2),
-                            send=jnp.concatenate([res.send] * 2))
-
-    monkeypatch.setattr(OCCEngine, "partial_fit", partial_fit)
-
-
-def _altered_answer(monkeypatch):
-    """One point's assignment changed where the pass produces it."""
-    from repro.core.engine import OCCEngine
-    orig = OCCEngine.partial_fit
-
-    def partial_fit(self, xb, **kw):
-        res = orig(self, xb, **kw)
-        k = int(self.pool.count)
-        a = res.assign.at[7].set((res.assign[7] + 1) % k)
-        return res._replace(assign=a)
-
-    monkeypatch.setattr(OCCEngine, "partial_fit", partial_fit)
-
-
-@pytest.mark.parametrize("fault", [_stale_state, _half_batch,
-                                   _altered_answer])
-@pytest.mark.parametrize("cell", TRAIN_CELLS)
+@pytest.mark.parametrize("cell,fault", [
+    (cell, fault) for cell in TRAIN_CELLS
+    for fault in algorithm_of(cell).FAULTS])
 def test_train_fault_is_not_correct(cell, fault, monkeypatch):
     fault(monkeypatch)
     out = run_small(cell)

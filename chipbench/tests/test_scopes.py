@@ -24,7 +24,6 @@ BENCH = os.path.dirname(HERE)
 sys.path.insert(0, BENCH)
 
 import common  # noqa: E402
-import flops  # noqa: E402
 import run  # noqa: E402
 import scopes  # noqa: E402
 import tracing  # noqa: E402
@@ -33,6 +32,8 @@ DATA = os.path.join(HERE, "data")
 OLD = os.path.join(DATA, "small.xplane.pb")
 SCOPED = os.path.join(DATA, "small_scoped.xplane.pb")
 SPEC = common.benchmark_spec()
+# The recorded traces are of an OCC DP-means job (`record_trace.py`).
+DP_MEANS = common.algorithm({"algorithm": "dp_means"})
 NEW = ("validate.precompute_us_per_epoch", "validate.scan_us_per_epoch",
        "validate.glue_us_per_epoch", "engine.unscoped_share",
        "engine.host_gap_share")
@@ -210,7 +211,9 @@ def _ctx(cell, path, meta, tmp_path):
     tdir.mkdir()
     shutil.copy(path, tdir / "t.xplane.pb")
     red = tracing.reduce_file(path)
-    return {"cell": common.find_cell(SPEC, cell),
+    c = common.find_cell(SPEC, cell)
+    cfg = common.config_of(SPEC, c)
+    return {"cell": c, "config": cfg, "algorithm": common.algorithm(cfg),
             "peaks": common.peaks_for(meta["device_kind"]),
             "counters": {"compiles": 0, "cap_retries": 0, "pb": meta["pb"],
                          "dim": meta["dim"], "chips": 1,
@@ -262,7 +265,7 @@ def test_scoped_trace_books_the_pass_to_its_scopes():
         if tracing.op_name(name).startswith("while") and v > 0:
             assert scope is not None, name
     # the propose kernel is under occ.propose
-    assert any(scope == "occ.propose" and flops.PROPOSE_KERNEL in name
+    assert any(scope == "occ.propose" and DP_MEANS.PROPOSE_KERNEL in name
                for name, scope, _, _ in sc.chips[0]["ops"])
     assert sum(ns.values()) == pytest.approx(sc.chips[0]["busy"], rel=0.05)
 

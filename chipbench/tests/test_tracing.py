@@ -1,6 +1,8 @@
-"""The reduction from trace to metrics: on hand-made events, and on a small
-trace recorded on a TPU v5e (`data/small.xplane.pb`, written by
-`record_trace.py`, with the counts it saw in `data/small.json`).
+"""The reduction from trace to metrics: on hand-made events, and on small
+traces recorded on a TPU v5e (`data/small.xplane.pb`, and
+`data/small_scoped.xplane.pb` with the program's `occ.*` scopes and
+`engine.*` spans, written by `record_trace.py`, with the counts each saw in
+`data/<name>.json`).
 
     PYTHONPATH=src JAX_PLATFORMS=cpu python -m pytest chipbench/tests
 """
@@ -8,6 +10,7 @@ from __future__ import annotations
 
 import json
 import os
+import shutil
 import sys
 
 import numpy as np
@@ -24,6 +27,10 @@ import tracing  # noqa: E402
 
 DATA = os.path.join(HERE, "data")
 SPEC = common.benchmark_spec()
+# The recorded traces are of an OCC DP-means job.
+DP_MEANS = common.algorithm({"algorithm": "dp_means"})
+PROPOSE_READERS = ("propose_roofline", "propose.device_share",
+                   "validate.us_per_epoch", "train.mfu")
 
 # One window of 100 ns; two chips.  Chip 0: a loop holding the kernel and a
 # fusion, an all-gather, a lone fusion, and a copy that runs past the
@@ -86,6 +93,16 @@ def test_algorithmic_counts():
     assert flops.min_seconds([1000, 10], [50, 50], peaks) == 10 + 5
 
 
+def test_dp_means_propose_work_is_propose_epochs():
+    """DP-means' propose phase is the nearest-center count, epoch by epoch
+    of the traced call."""
+    for k0, acc, pb, d in ((5, np.array([2, 0, 9]), 4, 3),
+                           (33986, [3, 0, 1], 256, 96)):
+        f, b = DP_MEANS.propose_work(k0, acc, pb, d)
+        want_f, want_b = flops.propose_epochs([k0] + list(acc), pb, d)
+        assert list(f) == list(want_f[1:]) and list(b) == list(want_b[1:])
+
+
 def test_unknown_device_has_no_peaks():
     with pytest.raises(KeyError):
         common.peaks_for("no such chip")
@@ -98,9 +115,9 @@ def test_recorded_chip_trace():
     assert len(red.chips) == 1
     assert 0 < red.busy_s < red.window_s
     # one propose kernel per epoch, one assign and one top-k dispatch
-    assert red.kernel_count(flops.PROPOSE_KERNEL) == meta["epochs"] + 1
+    assert red.kernel_count(DP_MEANS.PROPOSE_KERNEL) == meta["epochs"] + 1
     assert red.kernel_count("topk_stream") == 1
-    t = red.kernel_seconds(flops.PROPOSE_KERNEL)
+    t = red.kernel_seconds(DP_MEANS.PROPOSE_KERNEL)
     assert 0 < t < red.busy_s
     f, b = flops.propose_epochs([meta["k_start"]] + meta["accepted"],
                                 meta["pb"], meta["dim"])
@@ -117,43 +134,73 @@ def test_recorded_chip_trace():
     assert np.isfinite(red.collective_s) and red.collective_s == 0
 
 
-def _recorded_counters(shape: str, meta: dict, red) -> dict:
-    """The counters a run of this mix shape hands the readers, filled from
-    what the recorded trace ran."""
-    if shape == "jobs":
-        return {"compiles": 0, "cap_retries": 0, "pb": meta["pb"],
-                "dim": meta["dim"], "chips": 1,
-                "traced_call": {"k_start": meta["k_start"],
-                                "accepted": meta["accepted"],
-                                "seconds": red.window_s}}
-    rows = meta["serve_rows"]
-    return {"compiles": 0, "bucket_fill": 0.5, "late_p95_ms": 0.5,
-            "group_rows": [rows] * meta["serve_dispatches"],
-            "n_centers": meta["k_start"], "dim": meta["dim"], "chips": 1}
+def _recorded_ctx(cell: dict, name: str, tmp_path) -> dict:
+    """The context a run of the cell hands its readers, filled from what
+    the recorded trace `data/<name>.xplane.pb` ran, with that trace as the
+    traced call's directory."""
+    meta = json.load(open(os.path.join(DATA, name + ".json")))
+    path = os.path.join(DATA, name + ".xplane.pb")
+    tdir = tmp_path / name
+    tdir.mkdir()
+    shutil.copy(path, tdir / "t.xplane.pb")
+    red = tracing.reduce_file(path)
+    if common.mix_of(cell)["shape"] == "jobs":
+        counters = {"compiles": 0, "cap_retries": 0, "pb": meta["pb"],
+                    "dim": meta["dim"], "chips": 1,
+                    "traced_call": {"dir": str(tdir),
+                                    "k_start": meta["k_start"],
+                                    "accepted": meta["accepted"],
+                                    "seconds": red.window_s}}
+    else:
+        rows = meta["serve_rows"]
+        counters = {"compiles": 0, "bucket_fill": 0.5, "late_p95_ms": 0.5,
+                    "group_rows": [rows] * meta["serve_dispatches"],
+                    "n_centers": meta["k_start"], "dim": meta["dim"],
+                    "chips": 1}
+    cfg = common.config_of(SPEC, cell)
+    return {"cell": cell, "config": cfg, "algorithm": common.algorithm(cfg),
+            "peaks": common.peaks_for(meta["device_kind"]),
+            "counters": counters, "trace": red}
 
 
 @pytest.mark.parametrize("cell", [c["name"] for c in SPEC["workloads"]
                                   if c["chips"] == 1])
-def test_every_reader_of_a_cell_reads_the_recorded_trace(cell):
+def test_every_reader_of_a_cell_reads_the_recorded_trace(cell, tmp_path):
     """Each per-layer metric a one-chip cell reports has its reader, and on
-    the recorded trace it reads a number in range; a share is never above
-    100%.  Only the collective share, a several-chip metric, reads
-    nothing on one chip."""
-    meta = json.load(open(os.path.join(DATA, "small.json")))
+    a recorded trace it reads a number in range; a share is never above
+    100%.  A reader of the program's scopes and engine spans (`scopes.py`)
+    reads the trace recorded with them, every other reader the trace
+    recorded before them.  Only the collective share, a several-chip
+    metric, reads nothing on one chip."""
     c = common.find_cell(SPEC, cell)
-    red = tracing.reduce_file(os.path.join(DATA, "small.xplane.pb"))
-    ctx = {"cell": c, "peaks": common.peaks_for(meta["device_kind"]),
-           "counters": _recorded_counters(common.mix_of(c)["shape"], meta,
-                                          red),
-           "trace": red}
+    old = _recorded_ctx(c, "small", tmp_path)
+    scoped = _recorded_ctx(c, "small_scoped", tmp_path)
     e2e = {m["name"] for m in run.metrics_of(SPEC["end_to_end"], c)}
     readers = run.metrics_of(SPEC["per_layer"], c, e2e)
     assert readers
     for m in readers:
-        value = run.layer_reader(m["name"])(ctx)
+        read = run.layer_reader(m["name"])
+        value = read(scoped if "scopes" in read.__globals__ else old)
         if m["name"] == "mesh.collective_share":
             assert value is None
             continue
         assert value is not None and np.isfinite(value), m["name"]
         if m["unit"] == "%":
             assert 0 <= value <= 100, (m["name"], value)
+
+
+def test_readers_take_the_work_and_kernel_from_the_algorithm(tmp_path):
+    """The propose readers read the cell's algorithm: one with no Pallas
+    propose (the test-only BP-means file) has no kernel roofline, share or
+    rest of the pass, and its own count of work in `train.mfu`."""
+    ctx = _recorded_ctx(common.find_cell(SPEC, "deep96.train"), "small",
+                        tmp_path)
+    dp = {n: run.layer_reader(n)(ctx) for n in PROPOSE_READERS}
+    assert all(v is not None and v > 0 for v in dp.values()), dp
+    ctx["algorithm"] = common.algorithm(
+        {"algorithm": "bp_means"}, os.path.join(HERE, "algorithms"))
+    bp = {n: run.layer_reader(n)(ctx) for n in PROPOSE_READERS}
+    assert bp == {"propose_roofline": None, "propose.device_share": None,
+                  "validate.us_per_epoch": None,
+                  "train.mfu": pytest.approx(2 * dp["train.mfu"],
+                                             rel=1e-12)}

@@ -2,10 +2,11 @@
 is data: its `shape` says which of the two generators below reads it, and
 the rest are that generator's parameters.
 
-  jobs       repeated clustering jobs through `OCCEngine.partial_fit` /
-             `flush`: the data is generated once (`job_data`), one whole
-             job runs in set-up (it compiles every shape the job uses), and
-             the window repeats the same job on a fresh engine, whole jobs
+  jobs       repeated OCC jobs through `OCCEngine.partial_fit` / `flush`,
+             with the transaction of the configuration's algorithm file:
+             the data is generated once (`job_data`), one whole job runs
+             in set-up (it compiles every shape the job uses), and the
+             window repeats the same job on a fresh engine, whole jobs
              only, starting none after `--seconds`.
   open_loop  independent requests through `ClusterService.submit`, each due
              at a time fixed in advance from the seed; sender threads wait
@@ -75,18 +76,18 @@ def _mesh(devs, mix):
                          axis_types=(jax.sharding.AxisType.Auto,))
 
 
-def _one_job(x, cfg, mix, mesh, trace_at=None):
-    """One clustering job: a fresh engine publishing into a fresh store,
-    `chunk_points` per `partial_fit`, then `flush`.  Returns the answer
-    (per-call assign / send lists, final pool) and its counters.  With
-    `trace_at` set, the profiler records that one `partial_fit` call."""
+def _one_job(x, cfg, mix, mesh, txn, trace_at=None):
+    """One OCC job of the transaction `txn`: a fresh engine publishing into
+    a fresh store, `chunk_points` per `partial_fit`, then `flush`.  Returns
+    the answer (per-call assign / send lists, final pool) and its counters.
+    With `trace_at` set, the profiler records that one `partial_fit`
+    call."""
     import jax
-    from repro.core import DPMeansTransaction, OCCEngine
+    from repro.core import OCCEngine
     from repro.serving import SnapshotStore
 
     store = SnapshotStore()
-    eng = OCCEngine(DPMeansTransaction(cfg["lam"], k_max=cfg["k_max"]),
-                    pb=cfg["pb"], validate_cap=cfg["validate_cap"],
+    eng = OCCEngine(txn, pb=cfg["pb"], validate_cap=cfg["validate_cap"],
                     mesh=mesh, publish=store.publish_pass)
     chunk = mix["chunk_points"]
     n_calls = -(-x.shape[0] // chunk)
@@ -135,16 +136,18 @@ def job_data(cfg, mix, seed: int):
 
 
 def _fit(a, n: int, fill):
-    """An answer array cut or padded to n entries: a missing answer reads
-    as `fill`, which the check counts as broken."""
+    """An answer array cut or padded to n rows along its leading axis, each
+    row of the array's own trailing shape: a missing answer reads as
+    `fill`, which the check counts as broken."""
     import jax.numpy as jnp
     if a.shape[0] >= n:
         return a[:n]
-    return jnp.concatenate([a, jnp.full((n - a.shape[0],), fill, a.dtype)])
+    pad = jnp.full((n - a.shape[0],) + a.shape[1:], fill, a.dtype)
+    return jnp.concatenate([a, pad])
 
 
 def run_jobs(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
-             limits):
+             limits, algo):
     import jax
     import jax.numpy as jnp
 
@@ -158,7 +161,7 @@ def run_jobs(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
 
     t = common.now()
     c0 = clock.seconds
-    _one_job(x, cfg, mix, mesh)
+    _one_job(x, cfg, mix, mesh, algo.transaction(cfg, seed))
     parts["warmup_s"] = common.now() - t
     parts["compile_s"] = clock.seconds - c0
 
@@ -171,7 +174,8 @@ def run_jobs(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
         trace_at = mix.get("trace_call", -1) if trace and not job_secs \
             else None
         tj = common.now()
-        answer, jc = _one_job(x, cfg, mix, mesh, trace_at)
+        answer, jc = _one_job(x, cfg, mix, mesh,
+                              algo.transaction(cfg, seed), trace_at)
         job_secs.append(common.now() - tj)
         if jc["traced"] is not None:
             traced = jc["traced"]
@@ -197,7 +201,8 @@ def run_jobs(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
     peak = common.memory_peak(devs)
 
     # -- correctness, after the window: the job the seed picks is judged
-    # by the reference; every other job must give the same answer exactly.
+    # by the algorithm's check; every other job must give the same answer
+    # exactly.
     rng = np.random.default_rng(seed % (1 << 63))
     pick = int(rng.integers(n_jobs))
     n = cfg["n_points"]
@@ -205,7 +210,8 @@ def run_jobs(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
                                                     False), p)
             for a, s, p in answers]
     del answers
-    a_ref, s_ref, p_ref = flat[pick]
+    ref = flat[pick]
+    a_ref, s_ref, p_ref = ref
     differ = sum(1 for a, s, p in flat
                  if not (bool(jnp.array_equal(a, a_ref))
                          and bool(jnp.array_equal(s, s_ref))
@@ -214,9 +220,7 @@ def run_jobs(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
                                                   p_ref.centers))))
     del flat
     t = common.now()
-    readings = reference.check_job(x, p_ref.centers, p_ref.count, a_ref,
-                                   s_ref, pb=cfg["pb"], lam=cfg["lam"],
-                                   block=mix.get("check_block", 1024))
+    readings = algo.check(x, ref, cfg, mix)
     readings["jobs_differing"] = differ
     common.log(f"reference check of job {pick} of {n_jobs}: "
                f"{common.now() - t:.3f} s; K={int(p_ref.count)}")
@@ -334,7 +338,9 @@ def _warm_service(svc, rows_np, mix, cfg):
 
 
 def run_open_loop(cfg, mix, cell, seed, seconds, trace, devs, clock, t_start,
-                  limits):
+                  limits, algo=None):
+    """Serving answers from an index the benchmark builds (`_index`): it
+    takes no transaction, and `algo` goes unread."""
     import jax
     import jax.numpy as jnp
     from repro.serving import ClusterService, Query, ServeConfig, SnapshotStore
