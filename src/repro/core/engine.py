@@ -373,6 +373,15 @@ _finish_epoch_jit = jax.jit(
     static_argnames=("validate_cap", "scan_mode"))
 
 
+@jax.jit
+def _join_state(carried: jnp.ndarray, state: jnp.ndarray) -> jnp.ndarray:
+    """One leaf of the partial-epoch carry's state ahead of the batch's, in
+    one compiled call under the named scope `occ.state` (a stateless
+    transaction has no leaf, so nothing runs)."""
+    with jax.named_scope("occ.state"):
+        return jnp.concatenate([carried, state], 0)
+
+
 class OCCEngine:
     """Driver for OCC transactions: batch passes and streaming epochs.
 
@@ -917,22 +926,26 @@ class OCCEngine:
         model restored from a snapshot.
 
         The call is the host span `engine.partial_fit`; inside it
-        `engine.dispatch`, `engine.stats_wait`, `engine.retry` and
-        `engine.publish` (`_dispatch`, `_commit_stream_pass`) mark what the
-        host does while the device runs or idles.
+        `engine.state` (the per-point state: `make_state` and the carry's
+        state joined ahead of it), `engine.dispatch`, `engine.stats_wait`,
+        `engine.retry` and `engine.publish` (`_dispatch`,
+        `_commit_stream_pass`) mark what the host does while the device
+        runs or idles.
         """
         with span("engine.partial_fit", self.obs, cat="engine"):
             if pool is not None:
                 if self._pool is not None:
                     raise ValueError("pool= only seeds the FIRST partial_fit")
                 self._pool = pool
-            if state is None:
-                state = self.txn.make_state(xb, self._n_seen)
+            with span("engine.state", self.obs, cat="engine"):
+                if state is None:
+                    state = self.txn.make_state(xb, self._n_seen)
+                if self._carry_x is not None:
+                    state = jax.tree.map(_join_state, self._carry_state,
+                                         state)
             self._n_seen += xb.shape[0]
             if self._carry_x is not None:
                 xb = jnp.concatenate([self._carry_x, xb], 0)
-                state = jax.tree.map(lambda c, s: jnp.concatenate([c, s], 0),
-                                     self._carry_state, state)
             n = xb.shape[0]
             n_full = (n // self.pb) * self.pb
             if n_full < n:

@@ -63,6 +63,15 @@ def point_uniforms(key: jax.Array, n: int, offset: int = 0) -> jnp.ndarray:
     return jax.vmap(lambda k: jax.random.uniform(k))(keys)
 
 
+@partial(jax.jit, static_argnames=("n",))
+def _draw_uniforms(key: jax.Array, offset, *, n: int) -> jnp.ndarray:
+    """`point_uniforms` as one compiled call, its ops under the named scope
+    `occ.state`.  The offset is traced, so each span length compiles once
+    however far into the stream it starts; the bits are `point_uniforms`'."""
+    with jax.named_scope("occ.state"):
+        return point_uniforms(key, n, offset)
+
+
 def _ofl_accept(lam2):
     def accept_fn(pool: CenterPool, x_j, u_j):
         d2, ref = nearest_center(pool, x_j)
@@ -96,7 +105,7 @@ class OFLTransaction:
         return make_pool(self.k_max, x.shape[-1], x.dtype)
 
     def make_state(self, x, offset: int = 0):
-        return point_uniforms(self.key, x.shape[0], offset)
+        return _draw_uniforms(self.key, offset, n=x.shape[0])
 
     def propose(self, pool, x_e, u_e):
         d2, idx = nearest_center(pool, x_e)

@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import DPMeansTransaction, OCCEngine
-from repro.core.engine import _engine_pass_jit
+from repro.core import DPMeansTransaction, OCCEngine, OFLTransaction
+from repro.core.engine import _engine_pass_jit, _join_state
+from repro.core.ofl import _draw_uniforms
 from repro.core.occ import make_pool
 from repro.obs import Obs, Tracer, span, validate_trace
 from repro.serving import SnapshotStore
@@ -117,6 +118,80 @@ def test_partial_fit_host_spans_in_a_profiler_trace(tmp_path):
         assert set(HOST_SPANS) <= inside, inside
     retries = [(s, e) for s, e, n in spans if n == "engine.retry"]
     assert len(retries) == eng.n_cap_retries == 1
+
+
+def _op_names(compiled_text, params):
+    """The named ops of a compiled program, its parameters left out."""
+    return [n for n in re.findall(r'op_name="([^"]*)"', compiled_text)
+            if n not in params]
+
+
+def test_ofl_state_draw_is_one_program_under_its_scope():
+    """A call's uniforms are one compiled program, and the carry's state
+    joined ahead of them another: every op of both lies under
+    `occ.state`."""
+    text = _draw_uniforms.lower(jax.random.key(3), 0,
+                                n=512).compile().as_text()
+    names = _op_names(text, ("key", "offset"))
+    assert names and all(n.startswith("jit(_draw_uniforms)/occ.state/")
+                         for n in names), names
+    text = _join_state.lower(jnp.zeros(3), jnp.zeros(5)).compile().as_text()
+    names = _op_names(text, ("carried", "state"))
+    assert names and all(n.startswith("jit(_join_state)/occ.state/")
+                         for n in names), names
+
+
+def _profiled_spans(tmp_path, fit):
+    """(start, end, name) of every `engine.*` host span `fit()` makes under
+    the JAX profiler."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fit()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for p in jax.profiler.ProfileData.from_file(path).planes
+            for line in p.lines for e in line.events
+            if e.name.startswith("engine.")]
+
+
+def test_ofl_partial_fit_draws_its_state_under_engine_state(tmp_path):
+    """Each OFL `partial_fit` holds one `engine.state` span, before its
+    dispatch: the uniforms, and the carry's state joined ahead of them
+    (chunks of 96, 96, 96 and 32 points against epochs of 64: the second
+    and the fourth call join a carry of 32)."""
+    eng = OCCEngine(OFLTransaction(1.0, 256, jax.random.key(1)), pb=64,
+                    validate_cap="adaptive")
+    x = _stream()
+    _fit(eng, x[:192], chunk=96)                # compile outside the trace
+    spans = _profiled_spans(tmp_path, lambda: _fit(eng, x[192:], chunk=96))
+    calls = [(s, e) for s, e, n in spans if n == "engine.partial_fit"]
+    assert len(calls) == 4
+    for s0, e0 in calls:
+        inside = sorted((s, n) for s, e, n in spans
+                        if s0 <= s and e <= e0 and n != "engine.partial_fit")
+        names = [n for _, n in inside]
+        assert names.count("engine.state") == 1, names
+        assert names.index("engine.state") < names.index("engine.dispatch")
+
+
+def test_dp_means_state_stays_free(monkeypatch):
+    """DP-means has no per-point state: its pass carries no `occ.state`
+    op, and a carry joins no state, so no state program runs."""
+    lowered = _engine_pass_jit.lower(
+        DPMeansTransaction(1.0, k_max=64), make_pool(64, 4),
+        jnp.zeros((256, 4)), (), pb=64, cap_warm=None, cap_rest=8, n_warm=1,
+        n_bootstrap=0, mesh=None, data_axis="data", scan_mode="serial")
+    assert "occ.state" not in lowered.as_text(debug_info=True)
+    joined = []
+    monkeypatch.setattr("repro.core.engine._join_state",
+                        lambda *a: joined.append(a))
+    eng = _engine()
+    _fit(eng, _stream(), chunk=96)
+    assert eng.n_pending == 0 and joined == []
 
 
 def test_pass_seconds_are_labelled_by_validator_width():

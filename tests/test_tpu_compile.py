@@ -29,6 +29,7 @@ D = 96                       # DEEP width (big-ann-benchmarks)
 PB = 256                     # the engine's propose width in chip_smoke.py
 K_POOL = 32768               # chip_smoke.py's center capacity
 K_INDEX = 131072             # a million-center-class flat index
+K_OFL = 1 << 20              # deep96-ofl's facility slots
 SERVE_BUCKETS = [1 << i for i in range(3, 13)]   # 8 .. ServeConfig max_bucket
 
 
@@ -105,6 +106,15 @@ def test_dpmeans_assign_compiles_at_cell_shapes(chip, rows, d):
         chip((), jnp.int32))
 
 
+def test_dpmeans_assign_compiles_at_ofl_pool(chip):
+    """deep96-ofl.train's propose: one 256-row epoch against the 2^20-slot
+    facility pool OFL needs for its ~477k facilities."""
+    _kernel_compiled(
+        lambda x, c, m, n: dpmeans_assign(x, c, m, count=n),
+        chip((PB, D)), chip((K_OFL, D)), chip((K_OFL,), jnp.bool_),
+        chip((), jnp.int32))
+
+
 @pytest.mark.parametrize("rows", SERVE_BUCKETS)
 def test_topk_stream_compiles(chip, rows):
     _kernel_compiled(
@@ -174,3 +184,27 @@ def test_occ_engine_pass_compiles(topo, monkeypatch, chips):
     if mesh is not None:
         # the kernel runs on each chip's quarter of the epoch
         assert f"f32[{PB // 4},1]" in text
+
+
+def test_ofl_pass_and_state_compile(chip, monkeypatch):
+    """deep96-ofl.train's programs on one chip: a call's uniform state
+    draw (`occ.state`) and the whole OCC OFL pass over a 2^17-point call,
+    the uniforms as its per-point state, against a 2^20-slot pool."""
+    from repro.core import OFLTransaction
+    from repro.core.engine import _engine_pass_jit
+    from repro.core.ofl import _draw_uniforms
+    from repro.core.occ import make_pool
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    n = 1 << 17
+    shaped = lambda a: chip(a.shape, a.dtype)
+    key = chip((), jax.random.key(0).dtype)
+    state = _draw_uniforms.lower(key, chip((), jnp.int32), n=n).compile()
+    assert "occ.state" in state.as_text()
+    txn = OFLTransaction(chip((), jnp.float32), K_OFL, key)
+    pool = jax.tree.map(shaped, jax.eval_shape(lambda: make_pool(K_OFL, D)))
+    text = _engine_pass_jit.lower(
+        txn, pool, chip((n, D)), chip((n,)), pb=PB, cap_warm=None,
+        cap_rest=None, n_warm=0, n_bootstrap=0, mesh=None, data_axis="data",
+        scan_mode="serial").compile().as_text()
+    assert "tpu_custom_call" in text
