@@ -9,8 +9,12 @@ into one MXU precompute and leaves a D-free serializing resolution.
 
 Variants timed here, all on the SAME problem sizes:
 
-  dp_reference / dp_precomputed  — the PR-2 pair (payload scalar scan)
-  dp_logdepth                    — the §11 fixed-point resolution
+  dp_reference / dp_precomputed  — the legacy pass vs the engine pass
+                                   (payload accept chain by the §11
+                                   log-depth fixed point)
+  dp_serial / dp_logdepth        — the payload resolution in isolation:
+                                   the serial scan vs the fixed point, on
+                                   the same compacted epoch
   dp_adaptive                    — Thm-3.3 adaptive cap, post-burn-in pass
                                    (vs the same warm pass at full cap)
   bp_reference / bp_gram         — BP-means legacy refit vs Gram-carry scan
@@ -33,8 +37,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import (
-    BPMeansTransaction, DPMeansTransaction, OCCEngine,
-    precomputed_gather_validate,
+    BPMeansTransaction, DPMeansTransaction, OCCEngine, logdepth_validate,
+    precomputed_gather_validate, precomputed_validate,
 )
 from repro.core._reference import _reference_validate, reference_pass
 from repro.core.occ import block_epochs
@@ -63,23 +67,34 @@ def run(n: int = 2048, d: int = 256, k_max: int = 512, pb: int = 512,
     txn = DPMeansTransaction(lam, k_max=k_max)
     pool0 = txn.init_pool(x[:pb])
     eng_fast = OCCEngine(txn, pb, validate_cap=cap)
-    eng_logd = OCCEngine(txn, pb, validate_cap=cap, scan_mode="logdepth")
-
     rf = jax.block_until_ready(eng_fast.run(x))
-    rl = jax.block_until_ready(eng_logd.run(x))
     rp, ra, _, rst = reference_pass(txn, pool0, x, pb=pb, cap=cap)
     assert np.array_equal(np.asarray(rf.assign), np.asarray(ra))
     assert np.array_equal(np.asarray(rf.pool.centers), np.asarray(rp.centers))
-    assert np.array_equal(np.asarray(rf.assign), np.asarray(rl.assign))
-    assert np.array_equal(np.asarray(rf.pool.centers),
-                          np.asarray(rl.pool.centers))
     assert np.array_equal(np.asarray(rf.stats.proposed),
                           np.asarray(rst.proposed))
 
     ref_s = _time(lambda: reference_pass(txn, pool0, x, pb=pb, cap=cap)[0]
                   .centers, repeats)
     fast_s = _time(lambda: eng_fast.run(x).pool.centers, repeats)
-    logd_s = _time(lambda: eng_logd.run(x).pool.centers, repeats)
+
+    # The payload resolution in isolation, on epoch-1 inputs compacted to
+    # the first `cap` sent proposals: the serial scan against the fixed
+    # point the engine runs — the same four outputs, bit for bit.
+    send, payload, aux, _ = jax.jit(txn.propose)(pool0, x[:pb], ())
+    order = jnp.argsort(~send, stable=True)[:cap]
+    send_c = send[order]
+    pre = jax.jit(txn.precompute_accept)(
+        pool0, payload[order], jax.tree.map(lambda a: a[order], aux),
+        pool0.count)
+    serial_step = jax.jit(lambda s, pre: precomputed_validate(
+        pool0, s, pre, txn.accept_pre))
+    logd_step = jax.jit(lambda s, pre: logdepth_validate(
+        pool0, s, pre, txn.accept_pre))
+    for got, want in zip(logd_step(send_c, pre), serial_step(send_c, pre)):
+        assert np.array_equal(np.asarray(got), np.asarray(want))
+    serial_s = _time(lambda: serial_step(send_c, pre)[0], repeats)
+    logd_s = _time(lambda: logd_step(send_c, pre)[0], repeats)
 
     # Adaptive cap: time a WARM pass (the Thm-3.3 regime the cap targets —
     # epoch 1 of a cold pool always runs full-width by design).
@@ -139,7 +154,8 @@ def run(n: int = 2048, d: int = 256, k_max: int = 512, pb: int = 512,
         "t_epochs": t_epochs, "repeats": repeats,
         "dp_reference_wall_s": ref_s,
         "dp_precomputed_wall_s": fast_s,
-        "dp_logdepth_wall_s": logd_s,
+        "dp_serial_validator_epoch_s": serial_s,
+        "dp_logdepth_validator_epoch_s": logd_s,
         "dp_speedup": ref_s / fast_s,
         "dp_adaptive_wall_s": adapt_s,
         "dp_fullcap_warm_wall_s": full_warm_s,
@@ -190,8 +206,10 @@ def run(n: int = 2048, d: int = 256, k_max: int = 512, pb: int = 512,
          "per_step=O(K_max*D)"),
         (f"validator_dp_precomputed_{tag}", fast_s * 1e6,
          f"per_step=O(cap);speedup={ref_s / fast_s:.2f}x"),
+        (f"validator_dp_serial_{tag}", serial_s * 1e6,
+         "serial_scan;epoch1_validator_only"),
         (f"validator_dp_logdepth_{tag}", logd_s * 1e6,
-         f"fixed_point;vs_serial={fast_s / logd_s:.2f}x"),
+         f"fixed_point;vs_serial={serial_s / logd_s:.2f}x"),
         (f"validator_dp_adaptive_{tag}", adapt_s * 1e6,
          f"cap={cap_ad};warm_speedup={full_warm_s / adapt_s:.2f}x"),
         (f"validator_bp_reference_{tag}", bp_ref_s * 1e6,

@@ -130,8 +130,7 @@ def engine_pass_has_kernel(eng, x_batch, mesh=None) -> bool:
     pool = eng.txn.init_pool(x_batch[:eng.pb])
     return kernel_in(_engine_pass_jit, eng.txn, pool, x_batch, (), pb=eng.pb,
                      cap_warm=None, cap_rest=None, n_warm=0, n_bootstrap=0,
-                     mesh=mesh, data_axis=eng.data_axis,
-                     scan_mode=eng.scan_mode)
+                     mesh=mesh, data_axis=eng.data_axis)
 
 
 def reference_check(x, *, lam=LAM, k_max=K_MAX, pb=PB) -> None:

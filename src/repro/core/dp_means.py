@@ -187,7 +187,6 @@ def occ_dp_means(
     validate_cap: int | None | str = None,
     mesh: jax.sharding.Mesh | None = None,
     data_axis: str = "data",
-    scan_mode: str = "serial",
 ) -> DPMeansResult:
     """OCC DP-means (Alg. 3) — convenience wrapper running
     `DPMeansTransaction` under `OCCEngine`.
@@ -199,7 +198,6 @@ def occ_dp_means(
       bootstrap: serially pre-process the first pb/16 points (paper §4.2).
       validate_cap: bounded-master compaction — an int, None, or "adaptive"
       for the Thm-3.3-sized window (see OCCEngine; bit-identical results).
-      scan_mode: "serial" | "logdepth" accept resolution (bit-identical).
       mesh: optional device mesh; epoch inputs are sharded over `data_axis`
       and the optimistic phase parallelizes under GSPMD while the validation
       scan executes replicated (SPMD re-execution of the master).
@@ -207,7 +205,7 @@ def occ_dp_means(
     n = x.shape[0]
     txn = DPMeansTransaction(lam, k_max)
     eng = OCCEngine(txn, pb, validate_cap=validate_cap, mesh=mesh,
-                    data_axis=data_axis, scan_mode=scan_mode)
+                    data_axis=data_axis)
     nb = min(n, max(1, pb // 16)) if bootstrap else 0
 
     z = jnp.full((n,), -1, jnp.int32)

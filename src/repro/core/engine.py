@@ -132,8 +132,9 @@ class OCCTransaction(Protocol):
     def accept_pre(self, d2_cur: jnp.ndarray, aux_j: Any) -> jnp.ndarray:
         """The D-free accept rule (REQUIRED): given the min squared distance
         to the current pool (payload scan) or the refit residual norm²
-        (Gram scan), decide acceptance.  Must be an elementwise monotone
-        threshold rule for `scan_mode="logdepth"` to apply (§11)."""
+        (Gram scan), decide acceptance.  For a payload-append transaction
+        it must be an elementwise monotone threshold rule: its accept chain
+        is resolved as the log-depth fixed point (§11)."""
         ...
 
     def accept(self, pool: CenterPool, payload_j: jnp.ndarray, aux_j: Any,
@@ -200,7 +201,7 @@ ADAPTIVE_CAP_MARGIN = 2
 
 
 def _finish_epoch(txn, pool, send, payload, aux, safe, valid_e, validate_cap,
-                  scan_mode, replicate=None):
+                  replicate=None):
     """Serialize one epoch's proposals: the master half of an OCC epoch.
 
     Everything after `propose` — valid-masking, the one true precomputed
@@ -218,7 +219,7 @@ def _finish_epoch(txn, pool, send, payload, aux, safe, valid_e, validate_cap,
         send = jnp.logical_and(send, valid_e)
     pool, slots, outs, sent_ovf = precomputed_gather_validate(
         pool, send, payload, aux, txn.precompute_accept, txn.accept_pre,
-        cap=validate_cap, replicate=replicate, scan_mode=scan_mode)
+        cap=validate_cap, replicate=replicate)
     with jax.named_scope("occ.commit"):
         assign_e = txn.writeback(send, slots, outs, safe, valid_e)
         pool = pool._replace(overflow=jnp.logical_or(pool.overflow, sent_ovf))
@@ -228,7 +229,7 @@ def _finish_epoch(txn, pool, send, payload, aux, safe, valid_e, validate_cap,
     return pool, (assign_e, send, n_sent, n_acc, cap)
 
 
-def _epoch_body(txn, pool, x_e, valid_e, state_e, validate_cap, scan_mode,
+def _epoch_body(txn, pool, x_e, valid_e, state_e, validate_cap,
                 replicate=None, propose=None):
     """One bulk-synchronous OCC epoch (any width, incl. the width-1 epochs
     of the serial bootstrap prefix) — always on the precomputed validator.
@@ -237,11 +238,11 @@ def _epoch_body(txn, pool, x_e, valid_e, state_e, validate_cap, scan_mode,
     with jax.named_scope("occ.propose"):
         send, payload, aux, safe = propose(pool, x_e, state_e)
     return _finish_epoch(txn, pool, send, payload, aux, safe, valid_e,
-                         validate_cap, scan_mode, replicate)
+                         validate_cap, replicate)
 
 
 def _engine_pass(txn, pool, x, state, *, pb, cap_warm, cap_rest, n_warm,
-                 n_bootstrap, mesh, data_axis, scan_mode="serial"):
+                 n_bootstrap, mesh, data_axis):
     """The whole pass: bootstrap prefix + T epochs, one `lax.scan` each,
     inside one jit.  All sizes static; no host round-trips.
 
@@ -278,8 +279,7 @@ def _engine_pass(txn, pool, x, state, *, pb, cap_warm, cap_rest, n_warm,
 
         def epoch_at(cap, propose=None):
             def epoch(pool, inp):
-                return _epoch_body(txn, pool, *inp, cap, scan_mode, replicate,
-                                   propose)
+                return _epoch_body(txn, pool, *inp, cap, replicate, propose)
             return epoch
 
         # Serial bootstrap prefix (paper §4.2): width-1 epochs are exactly
@@ -354,7 +354,7 @@ def _engine_pass(txn, pool, x, state, *, pb, cap_warm, cap_rest, n_warm,
 _engine_pass_jit = jax.jit(
     _engine_pass,
     static_argnames=("pb", "cap_warm", "cap_rest", "n_warm", "n_bootstrap",
-                     "mesh", "data_axis", "scan_mode"))
+                     "mesh", "data_axis"))
 
 
 # Per-epoch jits for the host-driven proposal-source path
@@ -367,10 +367,9 @@ _propose_epoch_jit = jax.jit(
     lambda txn, pool, x_e, state_e: txn.propose(pool, x_e, state_e))
 
 _finish_epoch_jit = jax.jit(
-    lambda txn, pool, send, payload, aux, safe, valid_e, validate_cap,
-    scan_mode: _finish_epoch(txn, pool, send, payload, aux, safe, valid_e,
-                             validate_cap, scan_mode),
-    static_argnames=("validate_cap", "scan_mode"))
+    lambda txn, pool, send, payload, aux, safe, valid_e, validate_cap:
+    _finish_epoch(txn, pool, send, payload, aux, safe, valid_e, validate_cap),
+    static_argnames=("validate_cap",))
 
 
 @jax.jit
@@ -397,11 +396,6 @@ class OCCEngine:
         retry whenever a pass overflows its window — adaptive results are
         bit-identical to full-cap results by construction.  Overflow of an
         int cap is surfaced on `pool.overflow`.
-      scan_mode: "serial" (default) runs the payload accept chain as the
-        sequential scalar scan; "logdepth" resolves it as the parallel
-        fixed point over the precomputed conflict matrix
-        (occ.logdepth_validate) — bit-identical, lower depth.  Gram-append
-        transactions (BP-means) always use the Gram-carry scan.
       mesh / data_axis: optional device mesh; each epoch's points are
         sharded over `data_axis` while the validation scan is replicated.
       publish: optional hook `publish(result, n_seen=..., epochs=...,
@@ -416,7 +410,6 @@ class OCCEngine:
                  validate_cap: int | None | str = None,
                  mesh: jax.sharding.Mesh | None = None,
                  data_axis: str = "data",
-                 scan_mode: str = "serial",
                  publish: Callable[..., Any] | None = None,
                  obs: Any = None):
         self.txn = transaction
@@ -427,13 +420,10 @@ class OCCEngine:
         self.pb = int(pb)
         if isinstance(validate_cap, str) and validate_cap != "adaptive":
             raise ValueError(f"unknown validate_cap {validate_cap!r}")
-        if scan_mode not in ("serial", "logdepth"):
-            raise ValueError(f"unknown scan_mode {scan_mode!r}")
         self.adaptive = validate_cap == "adaptive"
         self.validate_cap = None if self.adaptive else validate_cap
         self.mesh = mesh
         self.data_axis = data_axis
-        self.scan_mode = scan_mode
         self.publish = publish
         self.n_dispatches = 0       # compiled-pass invocations (1 per pass)
         # adaptive-cap observability
@@ -514,7 +504,7 @@ class OCCEngine:
         res = _engine_pass_jit(
             self.txn, pool, x, state, pb=self.pb, cap_warm=cap_warm,
             cap_rest=cap_rest, n_warm=n_warm, n_bootstrap=n_bootstrap,
-            mesh=mesh, data_axis=self.data_axis, scan_mode=self.scan_mode)
+            mesh=mesh, data_axis=self.data_axis)
         self.n_dispatches += 1
         return res
 
@@ -654,7 +644,7 @@ class OCCEngine:
                              "for mesh-sharded passes")
         if propose_fn is None:
             propose_fn = self.local_proposer()
-        cap, sm = self.validate_cap, self.scan_mode
+        cap = self.validate_cap
         n, d = x.shape
         nb = min(int(n_bootstrap), n)
         if pool is None:
@@ -674,8 +664,7 @@ class OCCEngine:
             s_, p_, a_, sf_, ve = propose_fn(pool, xe, se, ve,
                                              epoch=0, offset=i)
             pool, (ae, _, _, _, _) = _finish_epoch_jit(
-                self.txn, pool, s_, p_, a_, sf_, ve,
-                validate_cap=cap, scan_mode=sm)
+                self.txn, pool, s_, p_, a_, sf_, ve, validate_cap=cap)
             self.n_dispatches += 1
             assign_parts.append(ae)
         assign_b = None if not nb else jax.tree.map(
@@ -702,8 +691,7 @@ class OCCEngine:
                     valid[cut], epoch=ge, offset=nb + e * self.pb)
             with span("engine.validate", obs, cat="engine", epoch=ge):
                 pool, (ae, sde, ns, na, ce) = _finish_epoch_jit(
-                    self.txn, pool, s_, p_, a_, sf_, ve,
-                    validate_cap=cap, scan_mode=sm)
+                    self.txn, pool, s_, p_, a_, sf_, ve, validate_cap=cap)
             self.n_dispatches += 1
             am_parts.append(ae)
             sm_parts.append(sde)
@@ -854,7 +842,7 @@ class OCCEngine:
                     self.txn, p, x, s, pb=self.pb,
                     cap_warm=self.validate_cap, cap_rest=self.validate_cap,
                     n_warm=0, n_bootstrap=0, mesh=None,
-                    data_axis=self.data_axis, scan_mode=self.scan_mode),
+                    data_axis=self.data_axis),
                 zero_pool, x1, s1)
         finally:
             _PASS_TRACES = traces

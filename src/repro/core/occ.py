@@ -15,10 +15,11 @@ order, executed replicated on every device (SPMD re-execution of the
 
 The precomputed fast path is the ONLY engine validator (DESIGN.md §9/§11):
 `precomputed_gather_validate` batches every D-dimensional quantity into one
-MXU precompute (`ValidatePre`) and then runs a D-free serializing scan —
-the payload scan (`precomputed_validate`, DP-means/OFL), its log-depth
-formulation (`logdepth_validate`, `scan_mode="logdepth"`), or the
-Gram-carry scan (`precomputed_validate_gram`, BP-means).  The legacy
+MXU precompute (`ValidatePre`) and then runs a D-free serializing
+resolution — for DP-means/OFL the log-depth fixed point
+(`logdepth_validate`, with the serial payload scan `precomputed_validate`
+as its pool-overflow fallback); for BP-means the Gram-carry scan
+(`precomputed_validate_gram`).  The legacy
 per-step D-dimensional recompute survives only as a reference
 implementation in `core/_reference.py` (tests + benchmark baselines);
 `serial_validate` below remains as the vehicle for the paper's *serial*
@@ -267,27 +268,26 @@ class ValidatePre(NamedTuple):
 def precomputed_validate(
     pool: CenterPool,
     send_c: jnp.ndarray,            # (cap,) bool — compacted proposal flags
-    payload_c: jnp.ndarray,         # (cap, D) — compacted payloads
     pre: ValidatePre,
     decide_fn: Callable[[jnp.ndarray, Any], jnp.ndarray],
-) -> tuple[CenterPool, jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """The serializing scan with ZERO D-dimensional work per step.
 
     Same serial semantics as `serial_validate` (deterministic, compaction
     order == global index order), but each step is O(cap) scalar mask/min/
     compare logic over precomputed distances: the carry is (count, overflow,
-    per-proposal slots), never the (K_max, D) center buffer.  Accepted
-    payloads are written back to the pool in ONE batched scatter afterwards
-    — O(cap·D) total instead of O(cap·K_max·D) sequential.
+    per-proposal slots), never the (K_max, D) center buffer.  The caller
+    writes the accepted payloads to the pool in ONE batched scatter
+    afterwards — O(cap·D) total instead of O(cap·K_max·D) sequential.
 
     `decide_fn(d2_cur, aux_j) -> bool` is the transaction's accept rule given
     the min squared distance to the *current* pool (epoch-start ∪ this
-    epoch's appends).  Returns (pool', slots_c (cap,) int32, refs_c (cap,)
-    int32 — nearest-center reference for rejected proposals).
+    epoch's appends).  Returns (slots_c (cap,) int32 — accepted slot or -1,
+    refs_c (cap,) int32 — nearest-center reference for rejected proposals,
+    the pool's new count, its new overflow flag).
     """
     cap = send_c.shape[0]
     k_max = pool.centers.shape[0]
-    count0 = pool.count
 
     def step(carry, inp):
         count, overflow, slots_c = carry
@@ -313,29 +313,22 @@ def precomputed_validate(
     aux = pre.aux
     if aux is None:
         aux = jnp.zeros((cap,), jnp.int32)
-    init = (count0, pool.overflow, jnp.full((cap,), -1, jnp.int32))
+    init = (pool.count, pool.overflow, jnp.full((cap,), -1, jnp.int32))
     (count, overflow, slots_c), refs_c = jax.lax.scan(
         step, init, (jnp.arange(cap), send_c, pre.d2_start, pre.idx_start,
                      pre.pair_d2, aux))
-
-    # One batched pool write: appended slots are unique by construction.
-    with jax.named_scope("occ.commit"):
-        widx = jnp.where(slots_c >= 0, slots_c, k_max)   # out-of-range drop
-        centers = pool.centers.at[widx].set(
-            payload_c.astype(pool.centers.dtype), mode="drop")
-        mask = pool.mask.at[widx].set(True, mode="drop")
-    return CenterPool(centers, mask, count, overflow), slots_c, refs_c
+    return slots_c, refs_c, count, overflow
 
 
 def logdepth_validate(
     pool: CenterPool,
     send_c: jnp.ndarray,
-    payload_c: jnp.ndarray,
     pre: ValidatePre,
     decide_fn: Callable[[jnp.ndarray, Any], jnp.ndarray],
-) -> tuple[CenterPool, jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """`precomputed_validate` with the sequential accept chain replaced by a
-    log-depth parallel resolution (DESIGN.md §11) — bit-identical verdicts.
+    log-depth parallel resolution (DESIGN.md §11) — bit-identical verdicts,
+    the same four outputs.
 
     Key algebra: for a monotone threshold rule, accepting is intersective —
     decide(min(a, b), aux) == decide(a, aux) AND decide(b, aux) holds
@@ -353,13 +346,14 @@ def logdepth_validate(
     count is the conflict graph's greedy chain depth — O(log cap) in the
     paper's low-conflict regime (Thm 3.3), never more than cap — while
     every round is parallel O(cap²) bit work on the precomputed matrix.
-    Slots then come from one `associative_scan` prefix sum and refs from
-    one masked column-min, both exact.
+    Slots then come from one int32 prefix sum and refs from one masked
+    column-min, both exact.
 
     Pool-capacity overflow makes acceptance rank-dependent (an accepted
     proposal that does not fit is appended nowhere and kills nobody), so
     that rare epoch falls back to the serial scan under `lax.cond` —
-    verdicts stay bit-identical there too.
+    verdicts stay bit-identical there too.  Neither branch touches the
+    (K_max, D) pool: the caller's one batched write follows.
     """
     cap = send_c.shape[0]
     k_max = pool.centers.shape[0]
@@ -389,7 +383,7 @@ def logdepth_validate(
         (base, jnp.zeros((cap,), bool)))
 
     def finish():
-        rank = jax.lax.associative_scan(jnp.add, accepted.astype(jnp.int32))
+        rank = jnp.cumsum(accepted.astype(jnp.int32))
         slots_c = jnp.where(accepted, count0 + rank - 1, -1)
         # refs: min over the FINAL accepted prefix — same value set (and the
         # same lowest-index tie-break) the serial chain of minimums sees.
@@ -399,18 +393,12 @@ def logdepth_validate(
         arg_new = jnp.argmin(d2_new, axis=0)
         use_new = best_new < pre.d2_start
         refs_c = jnp.where(use_new, slots_c[arg_new], pre.idx_start)
-        with jax.named_scope("occ.commit"):
-            widx = jnp.where(slots_c >= 0, slots_c, k_max)
-            centers = pool.centers.at[widx].set(
-                payload_c.astype(pool.centers.dtype), mode="drop")
-            mask = pool.mask.at[widx].set(True, mode="drop")
-        new_pool = CenterPool(centers, mask, count0 + rank[-1], pool.overflow)
-        return new_pool, slots_c, refs_c
+        return slots_c, refs_c, count0 + rank[-1], pool.overflow
 
     n_acc = jnp.sum(accepted.astype(jnp.int32))
     return jax.lax.cond(
         count0 + n_acc > k_max,
-        lambda: precomputed_validate(pool, send_c, payload_c, pre, decide_fn),
+        lambda: precomputed_validate(pool, send_c, pre, decide_fn),
         finish)
 
 
@@ -523,17 +511,15 @@ def precomputed_gather_validate(
     decide_fn: Callable[[jnp.ndarray, Any], jnp.ndarray],
     cap: int | None = None,
     replicate: Callable[[jnp.ndarray], jnp.ndarray] | None = None,
-    scan_mode: str = "serial",
 ):
     """Bounded-master validation — THE engine validator (DESIGN.md §9/§11).
 
     Compacts the sent proposals (stable order == global index order), runs
     `precompute_fn(pool, payload_c, aux_c, count0)` ONCE on the MXU, then a
     D-free serializing resolution, then scatters verdicts back to the full
-    index space.  The resolution is picked from the ValidatePre contents
-    and `scan_mode`: `pre.gram` set → the BP-means Gram-carry scan;
-    otherwise the payload scalar scan (`scan_mode="serial"`) or its
-    log-depth fixed-point formulation (`scan_mode="logdepth"`).
+    index space.  `pre.gram` set → the BP-means Gram-carry scan; otherwise
+    the payload accept chain, resolved as the log-depth fixed point
+    (`logdepth_validate`) and written to the pool in one batched scatter.
 
     `replicate` (optional) constrains the compacted buffers — inputs AND
     every precomputed (cap, …) ValidatePre leaf — to the master's
@@ -542,7 +528,7 @@ def precomputed_gather_validate(
     runs with (see shardings.occ_validate_sharding).
 
     The steps run under the named scopes `occ.compact`, `occ.precompute`,
-    `occ.scan` and `occ.commit` (the scans' batched pool write and the
+    `occ.scan` and `occ.commit` (the batched pool write and the
     scatter-back), which tag their ops in a profile.
     """
     b = send.shape[0]
@@ -562,16 +548,21 @@ def precomputed_gather_validate(
         if replicate is not None:
             pre = jax.tree.map(replicate, pre)
     if pre.gram is not None:
-        validate = precomputed_validate_gram
-    elif scan_mode == "logdepth":
-        validate = logdepth_validate
-    elif scan_mode == "serial":
-        validate = precomputed_validate
+        with jax.named_scope("occ.scan"):
+            pool, slots_c, refs_c = precomputed_validate_gram(
+                pool, send_c, payload_c, pre, decide_fn)
     else:
-        raise ValueError(f"unknown scan_mode {scan_mode!r}")
-    with jax.named_scope("occ.scan"):
-        pool, slots_c, refs_c = validate(pool, send_c, payload_c, pre,
-                                         decide_fn)
+        with jax.named_scope("occ.scan"):
+            slots_c, refs_c, count, overflow = logdepth_validate(
+                pool, send_c, pre, decide_fn)
+        # One batched pool write: appended slots are unique by
+        # construction; the rest go out of range and drop.
+        with jax.named_scope("occ.commit"):
+            widx = jnp.where(slots_c >= 0, slots_c, pool.centers.shape[0])
+            pool = CenterPool(
+                pool.centers.at[widx].set(
+                    payload_c.astype(pool.centers.dtype), mode="drop"),
+                pool.mask.at[widx].set(True, mode="drop"), count, overflow)
     with jax.named_scope("occ.commit"):
         slots, outs = _scatter_back(order, b, slots_c, refs_c)
     return pool, slots, outs, sent_overflow

@@ -167,13 +167,12 @@ def occ_ofl(
     validate_cap: int | None | str = None,
     mesh: jax.sharding.Mesh | None = None,
     data_axis: str = "data",
-    scan_mode: str = "serial",
 ) -> OFLResult:
     """OCC Online Facility Location (Alg. 4) — convenience wrapper running
     `OFLTransaction` under `OCCEngine`.  Single pass by construction."""
     txn = OFLTransaction(lam, k_max, key)
     eng = OCCEngine(txn, pb, validate_cap=validate_cap, mesh=mesh,
-                    data_axis=data_axis, scan_mode=scan_mode)
+                    data_axis=data_axis)
     res = eng.run(x)
     obj = txn.objective(x, res.assign, res.pool)
     return OFLResult(res.pool, res.assign, res.stats, res.send,

@@ -47,15 +47,23 @@ def _fit(eng, x, chunk=128):
             range(0, x.shape[0], chunk)]
 
 
-@pytest.mark.parametrize("scan_mode", ["serial", "logdepth"])
-def test_compiled_pass_carries_every_occ_scope(scan_mode):
+# The loop of each payload resolution, as its op names show it: the
+# fixed point's rounds, and the serial scan's steps in the pool-overflow
+# branch of the fixed point's `conditional`.
+RESOLUTION_LOOPS = {"logdepth": r"/occ\.scan/while/body/",
+                    "serial": r"/occ\.scan/cond/[^/]+/while/body/"}
+
+
+@pytest.mark.parametrize("resolution", ["serial", "logdepth"])
+def test_compiled_pass_carries_every_occ_scope(resolution):
     """Every step of the pass is tagged, and the stage scopes nest inside
-    `occ.pass` through the epoch loop."""
+    `occ.pass` through the epoch loop, the loop of each payload resolution
+    under `occ.scan`."""
     txn = DPMeansTransaction(1.0, k_max=64)
     lowered = _engine_pass_jit.lower(
         txn, make_pool(64, 4), jnp.zeros((256, 4)), (), pb=64,
         cap_warm=None, cap_rest=8, n_warm=1, n_bootstrap=0, mesh=None,
-        data_axis="data", scan_mode=scan_mode)
+        data_axis="data")
     text = lowered.as_text(debug_info=True)
     for scope in SCOPES:
         assert scope in text, scope
@@ -63,6 +71,9 @@ def test_compiled_pass_carries_every_occ_scope(scan_mode):
     for scope in SCOPES[1:]:
         assert any(n.startswith("jit(_engine_pass)/occ.pass/")
                    and f"/{scope}/" in n for n in names), scope
+    assert any(n.startswith("jit(_engine_pass)/occ.pass/")
+               and re.search(RESOLUTION_LOOPS[resolution], n)
+               for n in names), resolution
 
 
 def test_sharded_pass_carries_every_occ_scope():
@@ -78,8 +89,7 @@ mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
 text = _engine_pass_jit.lower(
     DPMeansTransaction(1.0, k_max=64), make_pool(64, 4),
     jnp.zeros((256, 4)), (), pb=64, cap_warm=None, cap_rest=8, n_warm=1,
-    n_bootstrap=0, mesh=mesh, data_axis="data",
-    scan_mode="serial").as_text(debug_info=True)
+    n_bootstrap=0, mesh=mesh, data_axis="data").as_text(debug_info=True)
 print(jax.device_count(), [s in text for s in {SCOPES!r}])
 """
     env = dict(os.environ,
@@ -184,7 +194,7 @@ def test_dp_means_state_stays_free(monkeypatch):
     lowered = _engine_pass_jit.lower(
         DPMeansTransaction(1.0, k_max=64), make_pool(64, 4),
         jnp.zeros((256, 4)), (), pb=64, cap_warm=None, cap_rest=8, n_warm=1,
-        n_bootstrap=0, mesh=None, data_axis="data", scan_mode="serial")
+        n_bootstrap=0, mesh=None, data_axis="data")
     assert "occ.state" not in lowered.as_text(debug_info=True)
     joined = []
     monkeypatch.setattr("repro.core.engine._join_state",

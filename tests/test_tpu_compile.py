@@ -12,6 +12,7 @@ only one process may hold the TPU library at once, and under pytest-xdist
 every worker imports this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -178,8 +179,7 @@ def test_occ_engine_pass_compiles(topo, monkeypatch, chips):
     x = jax.ShapeDtypeStruct((1 << 18, D), jnp.float32, sharding=place)
     text = _engine_pass_jit.lower(
         txn, pool, x, (), pb=PB, cap_warm=None, cap_rest=None, n_warm=0,
-        n_bootstrap=0, mesh=mesh, data_axis="data",
-        scan_mode="serial").compile().as_text()
+        n_bootstrap=0, mesh=mesh, data_axis="data").compile().as_text()
     assert "tpu_custom_call" in text
     if mesh is not None:
         # the kernel runs on each chip's quarter of the epoch
@@ -189,7 +189,10 @@ def test_occ_engine_pass_compiles(topo, monkeypatch, chips):
 def test_ofl_pass_and_state_compile(chip, monkeypatch):
     """deep96-ofl.train's programs on one chip: a call's uniform state
     draw (`occ.state`) and the whole OCC OFL pass over a 2^17-point call,
-    the uniforms as its per-point state, against a 2^20-slot pool."""
+    the uniforms as its per-point state, against a 2^20-slot pool.  Its
+    width-256 epochs resolve by the log-depth fixed point, whose overflow
+    `cond` carries no pool, and the program copies the pool no more than
+    the two layout copies at the pass's boundary."""
     from repro.core import OFLTransaction
     from repro.core.engine import _engine_pass_jit
     from repro.core.ofl import _draw_uniforms
@@ -205,6 +208,10 @@ def test_ofl_pass_and_state_compile(chip, monkeypatch):
     pool = jax.tree.map(shaped, jax.eval_shape(lambda: make_pool(K_OFL, D)))
     text = _engine_pass_jit.lower(
         txn, pool, chip((n, D)), chip((n,)), pb=PB, cap_warm=None,
-        cap_rest=None, n_warm=0, n_bootstrap=0, mesh=None, data_axis="data",
-        scan_mode="serial").compile().as_text()
+        cap_rest=None, n_warm=0, n_bootstrap=0, mesh=None,
+        data_axis="data").compile().as_text()
     assert "tpu_custom_call" in text
+    assert "/occ.scan/cond" in text
+    pool = rf"f32\[{K_OFL},{D}\]"
+    assert not re.search(pool + r"[^\n]* conditional\(", text)
+    assert len(re.findall(pool + r"\{[^}]*\} copy\(", text)) <= 2

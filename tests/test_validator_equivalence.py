@@ -5,9 +5,10 @@ caps, pool occupancies, and the sent_overflow / pool-overflow paths.
 Contracts enforced here:
   * DP-means / OFL payload scan is bit-identical to the legacy per-step
     D-dimensional recompute (`core/_reference.py`), including pool bits.
-  * `scan_mode="logdepth"` is bit-identical to `scan_mode="serial"` for
-    DP-means / OFL — everything, centers included (min/compare algebra
-    never rounds).
+  * The log-depth fixed point the engine resolves DP-means / OFL epochs
+    by is bit-identical to the serial payload scan — slots, refs, count
+    and overflow of every epoch (min/compare algebra never rounds); the
+    two functions are called directly on each epoch of the engine's pass.
   * BP-means Gram-carry validation is decision-identical to the
     D-dimensional refit reference — every discrete output (assignments,
     sends, slots, counts, stats, overflow) bit-equal — with appended
@@ -21,6 +22,9 @@ Two layers: a deterministic seeded sweep that always runs, and hypothesis
 property variants (skipped when hypothesis is absent) exploring the same
 space adversarially.
 """
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -28,9 +32,11 @@ import pytest
 
 from repro.core import (
     BPMeansTransaction, DPMeansTransaction, OCCEngine, OFLTransaction,
-    make_pool, nearest_center, precomputed_gather_validate,
+    logdepth_validate, make_pool, nearest_center, precomputed_gather_validate,
+    precomputed_validate,
 )
 from repro.core._reference import _reference_validate, reference_pass
+from repro.core.occ import _compact_sent, effective_cap
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -57,11 +63,10 @@ def _problem(n, d, k_max, k0, seed):
     return x, _seeded_pool(k_max, d, min(k0, k_max), rng)
 
 
-def _assert_matches_reference(txn, x, pool, pb, cap, state=None,
-                              scan_mode="serial"):
+def _assert_matches_reference(txn, x, pool, pb, cap, state=None):
     """Engine (fast path) == legacy per-step reference, bit for bit."""
-    fast = OCCEngine(txn, pb, validate_cap=cap,
-                     scan_mode=scan_mode).run(x, pool=pool, state=state)
+    fast = OCCEngine(txn, pb, validate_cap=cap).run(x, pool=pool,
+                                                    state=state)
     rp, ra, rs, rst = reference_pass(txn, pool, x, state=state, pb=pb,
                                      cap=cap)
     np.testing.assert_array_equal(np.asarray(fast.assign), np.asarray(ra))
@@ -79,20 +84,40 @@ def _assert_matches_reference(txn, x, pool, pb, cap, state=None,
     return fast
 
 
-def _assert_scan_modes_identical(txn, x, pool, pb, cap):
-    """logdepth == serial, bit for bit, everything."""
-    serial = OCCEngine(txn, pb, validate_cap=cap).run(x, pool=pool)
-    logd = OCCEngine(txn, pb, validate_cap=cap,
-                     scan_mode="logdepth").run(x, pool=pool)
-    for got, want in [(logd.assign, serial.assign), (logd.send, serial.send),
-                      (logd.pool.centers, serial.pool.centers),
-                      (logd.pool.mask, serial.pool.mask),
-                      (logd.stats.proposed, serial.stats.proposed),
-                      (logd.stats.accepted, serial.stats.accepted)]:
+@functools.partial(jax.jit, static_argnames="cap")
+def _resolve_both(txn, pool, send, payload, aux, cap):
+    """One epoch's compacted accept chain, resolved by the serial payload
+    scan and by the log-depth fixed point: two (slots, refs, count,
+    overflow) tuples."""
+    order, _ = _compact_sent(send, effective_cap(cap, send.shape[0]))
+    pre = txn.precompute_accept(pool, payload[order],
+                                jax.tree.map(lambda a: a[order], aux),
+                                pool.count)
+    return (precomputed_validate(pool, send[order], pre, txn.accept_pre),
+            logdepth_validate(pool, send[order], pre, txn.accept_pre))
+
+
+def _assert_epoch_resolutions_identical(txn, pool, send, payload, aux, cap):
+    serial, logd = _resolve_both(txn, pool, send, payload, aux, cap)
+    for got, want in zip(logd, serial):
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    assert int(logd.pool.count) == int(serial.pool.count)
-    assert bool(logd.pool.overflow) == bool(serial.pool.overflow)
-    return serial
+
+
+def _assert_resolutions_identical(txn, x, pool, pb, cap, state=None):
+    """logdepth == serial, bit for bit, on every epoch of the engine's own
+    pass (its host-driven dual, which hands each epoch's starting pool to
+    the proposal source)."""
+    eng = OCCEngine(txn, pb, validate_cap=cap)
+    propose = eng.local_proposer()
+
+    def checked(pool, x_e, state_e, valid_e, **at):
+        send, payload, aux, safe, valid_e = propose(pool, x_e, state_e,
+                                                    valid_e, **at)
+        _assert_epoch_resolutions_identical(
+            txn, pool, jnp.logical_and(send, valid_e), payload, aux, cap)
+        return send, payload, aux, safe, valid_e
+
+    return eng.run_from_proposals(x, checked, pool=pool, state=state)
 
 
 def _assert_bp_decision_identical(txn, x, pool, pb, cap):
@@ -145,15 +170,61 @@ def test_ofl_fast_equals_reference_sweep(n, d, k_max, k0, pb, lam, cap):
 @pytest.mark.parametrize("n,d,k_max,k0,pb,lam,cap", SWEEP)
 def test_dpmeans_logdepth_equals_serial_sweep(n, d, k_max, k0, pb, lam, cap):
     x, pool = _problem(n, d, k_max, k0, seed=n + k0)
-    _assert_scan_modes_identical(DPMeansTransaction(lam, k_max), x, pool,
-                                 pb, cap)
+    _assert_resolutions_identical(DPMeansTransaction(lam, k_max), x, pool,
+                                  pb, cap)
 
 
 @pytest.mark.parametrize("n,d,k_max,k0,pb,lam,cap", SWEEP)
 def test_ofl_logdepth_equals_serial_sweep(n, d, k_max, k0, pb, lam, cap):
     x, pool = _problem(n, d, k_max, k0, seed=n + k0)
     txn = OFLTransaction(lam, k_max, jax.random.key(n))
-    _assert_scan_modes_identical(txn, x, pool, pb, cap)
+    _assert_resolutions_identical(txn, x, pool, pb, cap)
+
+
+def test_ofl_logdepth_equals_serial_at_the_cell_shape():
+    """deep96-ofl.train's validator shape: width-256 epochs at D = 96,
+    λ = 1.6, per-point uniforms, against a pool of 2^12 live facilities in
+    2^14 slots.  A quarter of the points come from 64 components the pool
+    lacks, so each epoch sends about a fifth of its points and some sends
+    kill later ones: the fixed point takes more than one round."""
+    d, k_max, k0, n, pb = 96, 1 << 14, 1 << 12, 1024, 256
+    rng = np.random.default_rng(16)
+    unit = lambda a: a / np.linalg.norm(a, axis=1, keepdims=True)
+    means = unit(rng.normal(size=(k0 + 64, d)).astype(np.float32))
+    z = np.where(rng.random(n) < 0.25, k0 + rng.integers(0, 64, n),
+                 rng.integers(0, k0, n))
+    x = jnp.asarray(unit(means[z] + np.sqrt(0.5 / d) * rng.normal(
+        size=(n, d)).astype(np.float32)))
+    pool = make_pool(k_max, d)
+    pool = pool._replace(centers=pool.centers.at[:k0].set(means[:k0]),
+                         mask=pool.mask.at[:k0].set(True),
+                         count=jnp.asarray(k0, jnp.int32))
+    txn = OFLTransaction(1.6, k_max, jax.random.key(7))
+    res = _assert_resolutions_identical(txn, x, pool, pb, None,
+                                        state=txn.make_state(x))
+    sent, acc = np.asarray(res.stats.proposed), np.asarray(res.stats.accepted)
+    assert (np.asarray(res.stats.cap) == pb).all()
+    assert (sent > pb // 8).all() and (sent < pb // 2).all(), sent
+    assert (sent > acc).any(), (sent, acc)
+
+
+@pytest.mark.parametrize("width", [1, 8, 64])
+def test_payload_epochs_resolve_by_the_fixed_point(width):
+    """At every compaction width, the width-1 epochs of a bootstrap prefix
+    included, a payload epoch resolves by the log-depth fixed point (a
+    `while` of no fixed trip count), with the serial scan only as the
+    pool-overflow branch of its `conditional`."""
+    txn = DPMeansTransaction(1.0, 64)
+    x = jnp.asarray(np.random.default_rng(0).normal(
+        size=(64, 4)).astype(np.float32))
+    pool = make_pool(64, 4)
+    send, payload, aux, _ = txn.propose(pool, x, ())
+    fn = jax.jit(lambda p, s, pl, a: precomputed_gather_validate(
+        p, s, pl, a, txn.precompute_accept, txn.accept_pre, cap=width))
+    text = fn.lower(pool, send, payload, aux).compile().as_text()
+    loops = [ln for ln in text.splitlines() if re.search(r" while\(", ln)]
+    assert "conditional" in text
+    assert any("known_trip_count" not in ln for ln in loops), loops
 
 
 @pytest.mark.parametrize("n,d,k_max,k0,pb,lam,cap", SWEEP)
@@ -223,8 +294,6 @@ def test_adaptive_cap_overflow_retry_is_lossless():
 
 def test_unknown_knobs_raise():
     with pytest.raises(ValueError):
-        OCCEngine(DPMeansTransaction(1.0, 8), 8, scan_mode="nope")
-    with pytest.raises(ValueError):
         OCCEngine(DPMeansTransaction(1.0, 8), 8, validate_cap="nope")
 
 
@@ -242,18 +311,18 @@ def test_sent_overflow_bitidentical_slots():
     accept = lambda p, v_j, a_j: txn.accept(p, v_j, a_j, count0)
     pl_, sl_, ol_, ovf_l = _reference_validate(pool, send, payload, accept,
                                                aux, cap=cap)
-    for mode in ("serial", "logdepth"):
-        pf_, sf_, of_, ovf_f = precomputed_gather_validate(
-            pool, send, payload, aux, txn.precompute_accept, txn.accept_pre,
-            cap=cap, scan_mode=mode)
-        assert bool(ovf_l) and bool(ovf_f)
-        np.testing.assert_array_equal(np.asarray(sl_), np.asarray(sf_))
-        # outs only carry meaning for sent proposals (writeback masks them)
-        s = np.asarray(send)
-        np.testing.assert_array_equal(np.asarray(ol_)[s], np.asarray(of_)[s])
-        np.testing.assert_array_equal(np.asarray(pl_.centers),
-                                      np.asarray(pf_.centers))
-        assert int(pl_.count) == int(pf_.count)
+    pf_, sf_, of_, ovf_f = precomputed_gather_validate(
+        pool, send, payload, aux, txn.precompute_accept, txn.accept_pre,
+        cap=cap)
+    assert bool(ovf_l) and bool(ovf_f)
+    np.testing.assert_array_equal(np.asarray(sl_), np.asarray(sf_))
+    # outs only carry meaning for sent proposals (writeback masks them)
+    s = np.asarray(send)
+    np.testing.assert_array_equal(np.asarray(ol_)[s], np.asarray(of_)[s])
+    np.testing.assert_array_equal(np.asarray(pl_.centers),
+                                  np.asarray(pf_.centers))
+    assert int(pl_.count) == int(pf_.count)
+    _assert_epoch_resolutions_identical(txn, pool, send, payload, aux, cap)
 
 
 def test_fast_path_equals_full_recompute_reference():
@@ -321,10 +390,10 @@ if HAVE_HYPOTHESIS:
     @settings(**SET)
     def test_logdepth_equals_serial_property(prob):
         x, pool, pb, lam, k_max, cap, seed = prob
-        _assert_scan_modes_identical(DPMeansTransaction(lam, k_max), x, pool,
-                                     pb, cap)
+        _assert_resolutions_identical(DPMeansTransaction(lam, k_max), x,
+                                      pool, pb, cap)
         txn = OFLTransaction(lam, k_max, jax.random.key(seed))
-        _assert_scan_modes_identical(txn, x, pool, pb, cap)
+        _assert_resolutions_identical(txn, x, pool, pb, cap)
 
     @given(validator_problem())
     @settings(max_examples=8, deadline=None)
