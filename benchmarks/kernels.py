@@ -25,12 +25,13 @@ def _time(fn, *args, iters: int = 20) -> float:
     return (time.time() - t0) / iters * 1e6
 
 
-def _topk_rows(rng, quick: bool):
+def _topk_rows(rng, quick: bool, backend: str):
     """serve_topk at retrieval-serving scale (DESIGN.md §16): flat oracle
-    (full (B, K) matrix) vs the streaming schedule (emulate — static-count
-    prefix slice + tile skip) vs hierarchical multi-probe over the same
-    buffers.  K sweeps 2^12..2^17; counts are ragged (~K/4 active) so the
-    active-prefix machinery actually earns its rows."""
+    (full (B, K) matrix) vs the streaming path (the Pallas kernel on a TPU;
+    off the chip the oracle on the static-count prefix slice) vs
+    hierarchical multi-probe over the same buffers.  K sweeps 2^12..2^17;
+    counts are ragged (~K/4 active) so the active-prefix machinery
+    actually earns its rows."""
     from repro.kernels.topk_stream import topk_tile_loads
     from repro.serving.snapshot import build_hier
 
@@ -49,12 +50,12 @@ def _topk_rows(rng, quick: bool):
         rows.append((f"kern_serve_topk_flat_K{kc}", us,
                      f"count={count};k={k};backend=ref"))
 
-        # streaming schedule: host count -> pow2 prefix slice + tile skip
+        # streaming: host count -> tile skip (pow2 prefix slice off-TPU)
         us = _time(lambda: ops.serve_topk(x, c, k, mask=m, count=count,
-                                          backend="emulate"))
+                                          backend=backend))
         loads = topk_tile_loads(count, kc)
         rows.append((f"kern_serve_topk_stream_K{kc}", us,
-                     f"count={count};k={k};backend=emulate;"
+                     f"count={count};k={k};backend={backend};"
                      f"tile_loads={loads}of{-(-kc // 128)}"))
 
         # multi-probe: p=4 of the hier layout built from the same prefix
@@ -74,10 +75,10 @@ def _topk_rows(rng, quick: bool):
         ucnt = jnp.asarray(u, jnp.int32)
         us = _time(lambda: ops.serve_topk_multiprobe(
             x, h.fine, h.fine_ids, h.fine_mask, cells_j, member_j, k,
-            u_count=ucnt, backend="emulate"))
+            u_count=ucnt, backend=backend))
         rows.append((f"kern_serve_topk_multiprobe_K{kc}", us,
                      f"count={count};k={k};p={p};probed={u}of{h.n_cells};"
-                     f"shard_cap={h.shard_cap};backend=emulate"))
+                     f"shard_cap={h.shard_cap};backend={backend}"))
     return rows
 
 
@@ -117,7 +118,7 @@ def run(quiet: bool = False, quick: bool = False):
     ok = bool(jnp.allclose(d2p, d2r, atol=1e-4))
     rows.append(("kern_pallas_interpret_parity", 0.0, f"allclose={ok}"))
 
-    rows += _topk_rows(rng, quick)
+    rows += _topk_rows(rng, quick, backend)
 
     if not quiet:
         for r in rows:

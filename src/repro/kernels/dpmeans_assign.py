@@ -42,11 +42,8 @@ The grid stays static (K_max tiles, JAX needs static shapes) but both the
 compute AND the HBM transfer per epoch track the *occupied* pool size
 rather than the K_max capacity; `assign_tile_steps` counts both.
 
-`dpmeans_assign_emulate` is a vmapped jnp re-implementation of the exact
-kernel schedule (same tiles, same f32 accumulation, same lane-wide running
-state and final reduction, same prefix skipping) — the fast stand-in for
-interpret mode, whose per-grid-step Python loop is too slow to parity-check
-production shapes (serving buckets) in CI.
+Off the chip the kernel runs in interpret mode (`ops.assign(...,
+backend="pallas")`), and the tests hold it to `kernels/ref.py`.
 """
 from __future__ import annotations
 
@@ -59,8 +56,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import MATMUL_PRECISION
 
-__all__ = ["dpmeans_assign", "dpmeans_assign_emulate", "assign_tiles",
-           "assign_tile_steps", "VMEM_BUDGET"]
+__all__ = ["dpmeans_assign", "assign_tiles", "assign_tile_steps",
+           "VMEM_BUDGET"]
 
 LANES = 128
 MAX_ROWS = 1024
@@ -292,62 +289,3 @@ def dpmeans_assign(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
         interpret=interpret,
     )(k_active, x, centers, mask.astype(jnp.int32).reshape(-1, 1, bg))
     return d2[:n, 0], idx[:n, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("block_n", "block_k"))
-def dpmeans_assign_emulate(x: jnp.ndarray, centers: jnp.ndarray,
-                           mask: jnp.ndarray,
-                           count: jnp.ndarray | None = None,
-                           block_n: int | None = None,
-                           block_k: int | None = None):
-    """Vmapped emulation of the Pallas kernel's exact schedule.
-
-    Same contract as `dpmeans_assign`, computed as vmap-over-n-blocks of a
-    scan-over-k-tiles that mirrors the kernel body op for op: the same tile
-    choice, padding and clamping, ||x||^2 once per row block, the same f32
-    distance algebra, the same lane-wide running fold (`_fold`, in the same
-    column order) and final cross-lane reduction (`_finish`), and
-    count-based tile skipping.  It computes a tile's distances in one
-    `dot_general` where the kernel walks column groups: each distance is
-    one row-column contraction either way.
-    Runs as ONE compiled XLA computation — no per-grid-step Python — so
-    production shapes (serving buckets, large K_max) can be parity-checked
-    in CI where interpret mode would take minutes.
-    """
-    n, d = x.shape
-    k = centers.shape[0]
-    bn, bk = _blocks(n, d, k, block_n, block_k)
-    x, centers, mask = _pad(x, centers, mask, bn, bk)
-    k_active = jnp.asarray(k if count is None else count, jnp.int32)
-    lanes = _lanes(bk)
-
-    xb = x.reshape(-1, bn, d)
-    cb = centers.reshape(-1, bk, d)
-    mb = mask.reshape(-1, bk)
-    kbs = jnp.arange(cb.shape[0], dtype=jnp.int32)
-
-    def one_block(xblk):
-        xf = xblk.astype(jnp.float32)
-        x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
-
-        def tile(carry, inp):
-            kb, c, m = inp
-            cf = c.astype(jnp.float32)
-            c2 = jnp.sum(cf * cf, axis=-1)[None, :]
-            d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-                xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
-                preferred_element_type=jnp.float32), 0.0)
-            d2 = jnp.where(m[None, :], d2, jnp.inf)
-            folded = _fold(d2, *carry, kb * bk)
-            live = kb * bk < k_active
-            return jax.tree.map(lambda new, old: jnp.where(live, new, old),
-                                folded, carry), None
-
-        init = (jnp.full((bn, lanes), jnp.inf, jnp.float32),
-                jnp.full((bn, lanes), -1, jnp.int32))
-        (run_min, run_idx), _ = jax.lax.scan(tile, init, (kbs, cb, mb))
-        d2m, idxm = _finish(run_min, run_idx)
-        return d2m[:, 0], idxm[:, 0]
-
-    d2, idx = jax.vmap(one_block)(xb)
-    return d2.reshape(-1)[:n], idx.reshape(-1)[:n]

@@ -1,17 +1,13 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-On the TPU target the Pallas path runs natively; on this CPU container the
-kernels execute under interpret=True (kernel body in Python) for
-correctness, and callers default to the jnp reference for speed.  The
-`backend` knob makes the choice explicit and testable:
+Each kernel has one implementation, the Pallas one, and one oracle in
+`kernels/ref.py`.  On a TPU the kernels run compiled; off the chip they
+run in Pallas interpret mode, which the tests hold to the oracle.  The
+`backend` knob (one of `BACKENDS`) makes the choice explicit:
 
   backend="auto"      -> pallas on TPU, ref elsewhere (production default)
   backend="pallas"    -> pallas, interpret=True off-TPU (kernel validation)
   backend="ref"       -> pure-jnp oracle
-  backend="emulate"   -> vmapped emulation of the kernel's exact schedule
-                         (assign/pairwise_argmin only) — interpret-mode
-                         semantics at compiled speed, for parity-checking
-                         production shapes (serving buckets) in CI
 """
 from __future__ import annotations
 
@@ -19,23 +15,20 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
-from repro.kernels.dpmeans_assign import (
-    dpmeans_assign as _dpmeans_assign,
-    dpmeans_assign_emulate as _dpmeans_assign_emulate,
-)
+from repro.kernels.dpmeans_assign import dpmeans_assign as _dpmeans_assign
 from repro.kernels.flash_attention import flash_attention as _flash_attention
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 from repro.kernels.swiglu import swiglu as _swiglu
 from repro.kernels.topk_stream import (
     topk_stream as _topk_stream,
-    topk_stream_emulate as _topk_stream_emulate,
     topk_multiprobe_stream as _topk_mp_stream,
-    topk_multiprobe_emulate as _topk_mp_emulate,
 )
 
 __all__ = ["assign", "pairwise_argmin", "serve_assign", "serve_topk",
            "serve_topk_multiprobe", "flash_attention", "rmsnorm", "swiglu",
-           "on_tpu"]
+           "on_tpu", "BACKENDS"]
+
+BACKENDS = ("auto", "pallas", "ref")
 
 
 def on_tpu() -> bool:
@@ -50,7 +43,8 @@ def _resolve(backend: str) -> tuple[bool, bool]:
         return True, not on_tpu()
     if backend == "ref":
         return False, False
-    raise ValueError(f"unknown backend {backend!r}")
+    raise ValueError(f"unknown backend {backend!r}, expected one of "
+                     f"{BACKENDS}")
 
 
 def assign(x, centers, mask=None, count=None, backend: str = "auto",
@@ -73,8 +67,6 @@ def assign(x, centers, mask=None, count=None, backend: str = "auto",
         mask = jnp.ones((centers.shape[0],), bool)
     if count is not None:
         mask = jnp.logical_and(mask, jnp.arange(centers.shape[0]) < count)
-    if backend == "emulate":
-        return _dpmeans_assign_emulate(x, centers, mask, count=count, **blocks)
     use_pallas, interp = _resolve(backend)
     if use_pallas:
         return _dpmeans_assign(x, centers, mask, count=count,
@@ -90,8 +82,6 @@ def pairwise_argmin(x, centers, mask=None, backend: str = "auto", **blocks):
     body against a like-for-like oracle across input dtypes."""
     if mask is None:
         mask = jnp.ones((centers.shape[0],), bool)
-    if backend == "emulate":
-        return _dpmeans_assign_emulate(x, centers, mask, **blocks)
     use_pallas, interp = _resolve(backend)
     if use_pallas:
         return _dpmeans_assign(x, centers, mask, interpret=interp, **blocks)
@@ -152,10 +142,10 @@ def serve_topk(x, centers, k: int, mask=None, count=None, n_valid=None,
     (inf, -1); distance ties break by lower index on EVERY backend.  Full
     backend dispatch (DESIGN.md §16): pallas streams center tiles through
     VMEM carrying k running candidates and skips HBM DMA beyond the active
-    prefix (`kernels/topk_stream.py`); "emulate" replays that exact tile
-    schedule as vmapped jnp; "ref" runs the one-matmul + `lax.top_k`
-    oracle.  For f32 inputs all three agree bit-exactly — the streamed
-    merge is tiling-invariant and the D-contraction is never split.
+    prefix (`kernels/topk_stream.py`); "ref" runs the one-matmul +
+    `lax.top_k` oracle.  For f32 inputs the two agree bit-exactly — the
+    streamed merge is tiling-invariant and the D-contraction is never
+    split.
     `topk[..., :1]` equals `serve_assign` bit-exactly on each backend
     (same algebra, same tie-breaking).
 
@@ -164,34 +154,29 @@ def serve_topk(x, centers, k: int, mask=None, count=None, n_valid=None,
     the kernel, so NaN/inf-laden payloads in padded slots can never
     surface in — or reorder — the top-k (tests/test_serving.py pins
     this).  When `count` is a HOST int (benchmarks, the retrieval example
-    — not the service's traced per-version scalar), the ref/emulate paths
-    additionally slice the center buffer to the pow2-rounded active prefix
-    before any compute, so CPU backends pay O(pow2(count)) instead of
-    O(K_max) at count << K_max; a prefix slice changes no surviving
-    distance bitwise.  k may exceed the (sliced) capacity — the overflow
-    columns come back (inf, -1).
+    — not the service's traced per-version scalar), the reference path
+    off the chip additionally slices the center buffer to the
+    pow2-rounded active prefix before any compute, so it pays
+    O(pow2(count)) instead of O(K_max) at count << K_max; a prefix slice
+    changes no surviving distance bitwise.  k may exceed the (sliced)
+    capacity — the overflow columns come back (inf, -1).
     """
     if mask is None:
         mask = jnp.ones((centers.shape[0],), bool)
     static_c = _static_count(count)
     if count is not None:
         mask = jnp.logical_and(mask, jnp.arange(centers.shape[0]) < count)
-    if static_c is not None and backend in ("ref", "emulate", "auto") \
-            and not on_tpu():
+    if static_c is not None and backend in ("ref", "auto") and not on_tpu():
         kp = min(centers.shape[0], max(_next_pow2(max(static_c, 1)), 8))
         if kp < centers.shape[0]:
             centers, mask = centers[:kp], mask[:kp]
     kk = min(k, centers.shape[0])
-    if backend == "emulate":
-        d2, idx = _topk_stream_emulate(x, centers, mask, kk, count=count,
-                                       **blocks)
+    use_pallas, interp = _resolve(backend)
+    if use_pallas:
+        d2, idx = _topk_stream(x, centers, mask, kk, count=count,
+                               interpret=interp, **blocks)
     else:
-        use_pallas, interp = _resolve(backend)
-        if use_pallas:
-            d2, idx = _topk_stream(x, centers, mask, kk, count=count,
-                                   interpret=interp, **blocks)
-        else:
-            d2, idx = _ref.topk_ref(x, centers, kk, mask)
+        d2, idx = _ref.topk_ref(x, centers, kk, mask)
     if kk < k:
         pad = k - kk
         d2 = jnp.concatenate(
@@ -219,18 +204,14 @@ def serve_topk_multiprobe(x, fine, fine_ids, fine_mask, cells, member,
     batched-distance formulation XLA reproduces bitwise against the flat
     matmul.
     """
-    if backend == "emulate":
-        d2, idx = _topk_mp_emulate(x, fine, fine_ids, fine_mask, cells,
-                                   member, k, u_count=u_count, **blocks)
+    use_pallas, interp = _resolve(backend)
+    if use_pallas:
+        d2, idx = _topk_mp_stream(x, fine, fine_ids, fine_mask, cells,
+                                  member, k, u_count=u_count,
+                                  interpret=interp, **blocks)
     else:
-        use_pallas, interp = _resolve(backend)
-        if use_pallas:
-            d2, idx = _topk_mp_stream(x, fine, fine_ids, fine_mask, cells,
-                                      member, k, u_count=u_count,
-                                      interpret=interp, **blocks)
-        else:
-            d2, idx = _ref.topk_multiprobe_ref(x, fine, fine_ids, fine_mask,
-                                               cells, member, k)
+        d2, idx = _ref.topk_multiprobe_ref(x, fine, fine_ids, fine_mask,
+                                           cells, member, k)
     return _mask_queries(d2, idx, n_valid)
 
 

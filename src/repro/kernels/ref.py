@@ -23,9 +23,9 @@ __all__ = ["assign_ref", "pairwise_argmin_ref", "topk_ref",
            "MATMUL_PRECISION", "D2_RTOL", "D2_ATOL", "topk_disagreements",
            "flash_attention_ref", "rmsnorm_ref", "swiglu_ref"]
 
-# Every distance matmul of the OCC path -- the Pallas kernels, their
-# emulations, these oracles and the validator's precompute -- contracts at
-# full f32 precision.  On TPU an f32 matmul at the default precision is one
+# Every distance matmul of the OCC path -- the Pallas kernels, these
+# oracles and the validator's precompute -- contracts at full f32
+# precision.  On TPU an f32 matmul at the default precision is one
 # bf16 pass, so the propose and the validator would see different
 # distances for the same pair, which breaks the serial equivalence of the
 # OCC pass (paper Thm 3.1).  The CPU computes f32 either way.
@@ -142,8 +142,8 @@ def topk_merge_ref(run_d: jnp.ndarray, run_i: jnp.ndarray,
     the candidates' original positions.  Because selection depends only on
     the candidate (value, id) MULTISET, the result is invariant to how
     callers tile or reorder candidates — the property that makes the
-    streaming kernel (kernels/topk_stream.py), its vmapped emulation, and
-    the gathered multi-probe path all bit-identical to `topk_ref` for f32
+    streaming kernel (kernels/topk_stream.py) and the gathered
+    multi-probe path both bit-identical to `topk_ref` for f32
     inputs, whatever their block sizes.  (inf, TOPK_SENTINEL) pads are a
     fixed point of the extraction (consuming one re-creates it), so ragged
     candidate sets need no special casing.  Used as the merge body INSIDE

@@ -20,9 +20,9 @@ top-k candidate buffer:
     maps clamp the tile index at the last active tile so the pipeline
     re-addresses a block already resident in VMEM — Pallas elides the copy
     when consecutive grid steps map to the same block, so tiles beyond the
-    active prefix issue ZERO HBM loads.  `topk_tile_loads` is the exact
-    accounting of that index-map sequence; the emulate paths return it so
-    CI can assert the elision arithmetic at production shapes.
+    active prefix issue ZERO HBM loads.  `center_tile` is that clamp, the
+    one function both the index maps and the host-side count
+    `topk_tile_loads` evaluate.
 
 `topk_multiprobe_stream` is the two-level variant serving hierarchical
 snapshots (serving/snapshot.build_hier): the scalar-prefetch operands are
@@ -38,9 +38,9 @@ Selection is by lexicographic (d2, original id), which equals
 `lax.top_k`'s lower-index-first tie order and is invariant to candidate
 tiling/ordering — so for f32 inputs flat kernel == multiprobe kernel ==
 `ref.topk_ref` bit-exactly (the D-contraction is never split, so even the
-distances are bitwise equal), across every block size.  The `*_emulate`
-twins replay the exact kernel schedule as vmapped jnp at compiled speed
-(interpret mode cannot sweep production shapes in CI time).
+distances are bitwise equal), across every block size.  Off the chip both
+kernels run in interpret mode (`ops.serve_topk(..., backend="pallas")`),
+and the tests hold them to `kernels/ref.py`.
 """
 from __future__ import annotations
 
@@ -48,36 +48,35 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.ref import MATMUL_PRECISION, TOPK_SENTINEL, topk_merge_ref
 
-__all__ = ["topk_stream", "topk_stream_emulate", "topk_multiprobe_stream",
-           "topk_multiprobe_emulate", "topk_tile_loads"]
+__all__ = ["topk_stream", "topk_multiprobe_stream", "topk_tile_loads"]
+
+
+def center_tile(j, count, bk: int, xp=jnp):
+    """The center tile grid step j reads: j, clamped at the last tile that
+    starts below `count` (tile 0 for an empty prefix).  `xp` is jnp in the
+    kernel's index map, where `count` is the prefetched scalar, and numpy
+    in `topk_tile_loads`, which walks the same map on the host."""
+    return xp.minimum(j, xp.maximum((count + bk - 1) // bk, 1) - 1)
 
 
 def topk_tile_loads(count: int, k_total: int, block_k: int = 128) -> int:
     """Center-tile HBM loads one row-block sweep of the flat kernel issues.
 
-    Walks the clamped index-map sequence literally: the pipeline DMAs a
-    block only when the mapped index changes between consecutive grid
-    steps, so loads == the number of distinct consecutive mapped indices
-    == max(1, ceil(count/bk)) — and tiles beyond the active prefix
-    contribute zero.  Tests assert the emulate paths' on-device accounting
-    against this host-side walk.
+    Walks the kernel's own index map (`center_tile`) over the grid: the
+    pipeline DMAs a block only when the mapped index changes between
+    consecutive grid steps, so loads == the number of distinct consecutive
+    mapped indices == max(1, ceil(count/bk)) — tiles beyond the active
+    prefix contribute zero.
     """
     bk = min(block_k, max(8, k_total))
-    k_pad = (-k_total) % bk
-    k_tiles = (k_total + k_pad) // bk
-    last = max((count + bk - 1) // bk, 1) - 1
-    loads, prev = 0, None
-    for j in range(k_tiles):
-        mapped = min(j, last)
-        if mapped != prev:
-            loads += 1
-        prev = mapped
-    return loads
+    mapped = center_tile(np.arange(-(-k_total // bk)), count, bk, np)
+    return 1 + int(np.count_nonzero(np.diff(mapped)))
 
 
 def _finalize(d2, idx):
@@ -148,11 +147,10 @@ def topk_stream(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
     k_active = jnp.full((1,), kc if count is None else count, jnp.int32)
 
     def _center_tile(i, j, k_ref):
-        last = jnp.maximum((k_ref[0] + bk - 1) // bk, 1) - 1
-        return jnp.minimum(j, last), 0
+        return center_tile(j, k_ref[0], bk), 0
 
     def _mask_tile(i, j, k_ref):
-        return 0, _center_tile(i, j, k_ref)[0]
+        return 0, center_tile(j, k_ref[0], bk)
 
     grid = (np_ // bn, kp // bk)
     d2, idx = pl.pallas_call(
@@ -178,82 +176,6 @@ def topk_stream(x: jnp.ndarray, centers: jnp.ndarray, mask: jnp.ndarray,
     )(k_active, x, centers, mask.astype(jnp.int32)[None, :])
     d2, idx = _finalize(d2, idx)
     return d2[:n], idx[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "block_k",
-                                             "with_loads"))
-def topk_stream_emulate(x: jnp.ndarray, centers: jnp.ndarray,
-                        mask: jnp.ndarray, k: int,
-                        count: jnp.ndarray | None = None,
-                        block_n: int = 256, block_k: int = 128,
-                        with_loads: bool = False):
-    """Vmapped emulation of `topk_stream`'s exact schedule (bitwise-equal).
-
-    vmap-over-n-blocks of a scan-over-center-tiles mirroring the kernel
-    body op for op: same padding, same f32 tile matmul, same
-    `topk_merge_ref` fold, same count-gated tile skipping.  ONE compiled
-    XLA computation — parity-checks production buckets in CI where
-    interpret mode would take minutes.  `with_loads=True` additionally
-    returns the center-tile HBM load count implied by the kernel's clamped
-    index map (== `topk_tile_loads`): the on-device side of the
-    DMA-elision accounting.
-    """
-    n, d = x.shape
-    kc = centers.shape[0]
-    bn = min(block_n, max(8, n))
-    bk = min(block_k, max(8, kc))
-    n_pad = (-n) % bn
-    k_pad = (-kc) % bk
-    if n_pad:
-        x = jnp.concatenate([x, jnp.zeros((n_pad, d), x.dtype)], 0)
-    if k_pad:
-        centers = jnp.concatenate(
-            [centers, jnp.zeros((k_pad, d), centers.dtype)], 0)
-        mask = jnp.concatenate([mask, jnp.zeros((k_pad,), bool)], 0)
-    k_active = jnp.asarray(kc if count is None else count, jnp.int32)
-
-    xb = x.reshape(-1, bn, d)
-    cb = centers.reshape(-1, bk, d)
-    mb = mask.reshape(-1, bk)
-    kbs = jnp.arange(cb.shape[0], dtype=jnp.int32)
-
-    def one_block(xblk):
-        xf = xblk.astype(jnp.float32)
-        x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
-
-        def tile(carry, inp):
-            run_d, run_i = carry
-            kb, c, m = inp
-            cf = c.astype(jnp.float32)
-            c2 = jnp.sum(cf * cf, axis=-1)[None, :]
-            d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-                xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
-                preferred_element_type=jnp.float32), 0.0)
-            d2 = jnp.where(m[None, :], d2, jnp.inf)
-            ids = (jax.lax.broadcasted_iota(jnp.int32, (bn, bk), 1)
-                   + kb * bk)
-            nd, ni = topk_merge_ref(run_d, run_i, d2, ids, k)
-            active = kb * bk < k_active
-            return (jnp.where(active, nd, run_d),
-                    jnp.where(active, ni, run_i)), None
-
-        init = (jnp.full((bn, k), jnp.inf, jnp.float32),
-                jnp.full((bn, k), TOPK_SENTINEL, jnp.int32))
-        (d2k, idk), _ = jax.lax.scan(tile, init, (kbs, cb, mb))
-        return d2k, idk
-
-    d2, idx = jax.vmap(one_block)(xb)
-    d2, idx = _finalize(d2.reshape(-1, k), idx.reshape(-1, k))
-    d2, idx = d2[:n], idx[:n]
-    if not with_loads:
-        return d2, idx
-    # The kernel's index-map sequence, evaluated on-device: block j maps to
-    # min(j, last); a load happens iff the mapped index changed vs step
-    # j-1.  Equals max(1, ceil(count/bk)) — zero loads past the prefix.
-    last = jnp.maximum((k_active + bk - 1) // bk, 1) - 1
-    mapped = jnp.minimum(kbs, last)
-    loads = 1 + jnp.sum(mapped[1:] != mapped[:-1]).astype(jnp.int32)
-    return d2, idx, loads
 
 
 # ---------------------------------------------------------------------------
@@ -356,72 +278,3 @@ def topk_multiprobe_stream(x: jnp.ndarray, fine: jnp.ndarray,
       fine_mask.astype(jnp.int32)[:, None, :], member.astype(jnp.int32))
     d2, idx = _finalize(d2, idx)
     return d2[:b], idx[:b]
-
-
-@functools.partial(jax.jit, static_argnames=("k", "block_n", "with_loads"))
-def topk_multiprobe_emulate(x: jnp.ndarray, fine: jnp.ndarray,
-                            fine_ids: jnp.ndarray, fine_mask: jnp.ndarray,
-                            cells: jnp.ndarray, member: jnp.ndarray, k: int,
-                            u_count: jnp.ndarray | None = None,
-                            block_n: int = 256, with_loads: bool = False):
-    """Vmapped emulation of `topk_multiprobe_stream`'s exact schedule.
-
-    Same contract; scan-over-union-ranks with the shard gathered per step
-    (`fine[cells[j]]` — the index-map gather, replayed as dynamic
-    indexing), merge gated on rank < u_count.  `with_loads=True` also
-    returns the shard HBM loads the clamped index map implies:
-    max(1, u_count) — independent of n_cells, the multi-probe DMA-skip
-    claim in one number.
-    """
-    b, d = x.shape
-    s = fine.shape[1]
-    u = cells.shape[0]
-    bn = min(block_n, max(8, b))
-    b_pad = (-b) % bn
-    if b_pad:
-        x = jnp.concatenate([x, jnp.zeros((b_pad, d), x.dtype)], 0)
-        member = jnp.concatenate(
-            [member, jnp.zeros((b_pad, u), bool)], 0)
-    u_active = jnp.asarray(u if u_count is None else u_count, jnp.int32)
-    cells_cl = jnp.maximum(cells, 0).astype(jnp.int32)
-
-    xb = x.reshape(-1, bn, d)
-    memb = member.reshape(-1, bn, u)
-    ranks = jnp.arange(u, dtype=jnp.int32)
-
-    def one_block(xblk, mblk):
-        xf = xblk.astype(jnp.float32)
-        x2 = jnp.sum(xf * xf, axis=-1, keepdims=True)
-
-        def tile(carry, inp):
-            run_d, run_i = carry
-            j, cell, mem = inp
-            cf = fine[cell].astype(jnp.float32)
-            c2 = jnp.sum(cf * cf, axis=-1)[None, :]
-            d2 = jnp.maximum(x2 + c2 - 2.0 * jax.lax.dot_general(
-                xf, cf, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
-                preferred_element_type=jnp.float32), 0.0)
-            d2 = jnp.where(fine_mask[cell][None, :] & mem[:, None],
-                           d2, jnp.inf)
-            nd, ni = topk_merge_ref(
-                run_d, run_i, d2,
-                jnp.broadcast_to(fine_ids[cell][None, :], d2.shape), k)
-            active = j < u_active
-            return (jnp.where(active, nd, run_d),
-                    jnp.where(active, ni, run_i)), None
-
-        init = (jnp.full((bn, k), jnp.inf, jnp.float32),
-                jnp.full((bn, k), TOPK_SENTINEL, jnp.int32))
-        (d2k, idk), _ = jax.lax.scan(
-            tile, init, (ranks, cells_cl, jnp.moveaxis(mblk, 1, 0)))
-        return d2k, idk
-
-    d2, idx = jax.vmap(one_block)(xb, memb)
-    d2, idx = _finalize(d2.reshape(-1, k), idx.reshape(-1, k))
-    d2, idx = d2[:b], idx[:b]
-    if not with_loads:
-        return d2, idx
-    last = jnp.maximum(u_active, 1) - 1
-    mapped = jnp.minimum(ranks, last)
-    loads = 1 + jnp.sum(mapped[1:] != mapped[:-1]).astype(jnp.int32)
-    return d2, idx, loads

@@ -34,6 +34,8 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, NamedTuple
 
+from repro.kernels.ops import BACKENDS
+
 __all__ = [
     "LANES", "LANE_RANK", "Query", "ServeConfig", "LaneState",
     "FlushDecision", "select_flush", "select_flush_fifo", "next_deadline",
@@ -148,6 +150,9 @@ class ServeConfig:
     miss_grace_ms: float | None = None
 
     def __post_init__(self):
+        if self.backend not in BACKENDS:
+            raise ValueError(f"ServeConfig.backend must be one of "
+                             f"{BACKENDS}, got {self.backend!r}")
         for f in ("min_bucket", "max_bucket", "coalesce_bucket"):
             v = getattr(self, f)
             if v < 1 or v & (v - 1):

@@ -4,6 +4,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.serving import ServeConfig
 
 
 @pytest.mark.parametrize("n,k,d", [(17, 5, 3), (64, 32, 16), (100, 37, 16),
@@ -30,22 +31,45 @@ def test_dpmeans_assign_empty_mask(rng):
     assert np.all(np.asarray(idx) == -1)    # kernel contract: -1 when empty
 
 
-@pytest.mark.parametrize("n,k,d", [
-    (5, 3, 2),        # n and k both below the minimum tile
-    (9, 5, 4),        # K < 8: bk clamps up, k-padding fills the tile
-    (7, 130, 8),      # ragged K across many tiles, ragged n
-    (130, 7, 16),     # ragged N across tiles, K < 8
-    (31, 33, 5),      # both non-multiples of the block sizes
+_SMALL_BLOCKS = {"block_n": 16, "block_k": 8}
+
+
+@pytest.mark.parametrize("n,k,d,count,blocks", [
+    pytest.param(*case, None, _SMALL_BLOCKS, id="-".join(map(str, case)))
+    for case in [
+        (5, 3, 2),        # n and k both below the minimum tile
+        (9, 5, 4),        # K < 8: bk clamps up, k-padding fills the tile
+        (7, 130, 8),      # ragged K across many tiles, ragged n
+        (130, 7, 16),     # ragged N across tiles, K < 8
+        (31, 33, 5),      # both non-multiples of the block sizes
+    ]
+] + [
+    pytest.param(*case, _SMALL_BLOCKS, id="-".join(map(str, case)))
+    for case in [(17, 5, 3, None), (33, 130, 8, 37), (20, 37, 6, 0),
+                 (20, 37, 6, 8), (7, 130, 8, 100)]
+] + [
+    # 128-lane running state: two lane slices per 256-wide tile, three
+    # tiles, the third beyond the count
+    pytest.param(40, 600, 12, 300, {"block_n": 16, "block_k": 256},
+                 id="lane-slices"),
+    # the default tiles: one row block, 512-wide center tiles
+    pytest.param(24, 1000, 8, None, {}, id="default-tiles"),
+    # one 1024-wide tile computed in four 256-wide column groups
+    pytest.param(512, 1024, 8, 700, {}, id="column-groups"),
 ])
-def test_dpmeans_assign_interpret_ragged_parity(rng, n, k, d):
-    """Interpret-mode Pallas vs sq_dists reference on ragged N/K shapes
+def test_dpmeans_assign_interpret_ragged_parity(rng, n, k, d, count, blocks):
+    """Interpret-mode Pallas vs the reference on ragged N/K shapes
     (non-multiples of block sizes, K < 8) — exactly the awkward pool sizes
-    the OCC engine produces."""
+    the OCC engine produces — with and without an active-prefix count, and
+    on the schedule's own shapes: lane slices, default tiles, column
+    groups."""
     x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
-    m = jnp.asarray(rng.uniform(size=k) > 0.3)
-    d2p, ip = ops.assign(x, c, m, backend="pallas", block_n=16, block_k=8)
-    d2r, ir = ops.assign(x, c, m, backend="ref")
+    m = (jnp.asarray(np.arange(k) < count) if count is not None
+         else jnp.asarray(rng.uniform(size=k) > 0.3))
+    cnt = None if count is None else jnp.asarray(count, jnp.int32)
+    d2p, ip = ops.assign(x, c, m, count=cnt, backend="pallas", **blocks)
+    d2r, ir = ops.assign(x, c, m, count=cnt, backend="ref")
     np.testing.assert_allclose(np.asarray(d2p), np.asarray(d2r), atol=1e-4)
     np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
 
@@ -178,47 +202,25 @@ def test_swiglu_sweep(rng, shape):
 
 def test_backend_resolution():
     assert not ops.on_tpu()
-    with pytest.raises(ValueError):
-        ops._resolve("nope")
+    for bad in ("nope", "emulate"):
+        with pytest.raises(ValueError):
+            ops._resolve(bad)
 
 
-# ------------------------------------------------- emulation harness (CI)
-
-_SMALL_BLOCKS = {"block_n": 16, "block_k": 8}
-
-
-@pytest.mark.parametrize("n,k,d,count,blocks", [
-    pytest.param(*case, _SMALL_BLOCKS, id="-".join(map(str, case)))
-    for case in [(17, 5, 3, None), (33, 130, 8, 37), (20, 37, 6, 0),
-                 (20, 37, 6, 8), (7, 130, 8, 100)]
-] + [
-    # 128-lane running state: two lane slices per 256-wide tile, three
-    # tiles, the third beyond the count
-    pytest.param(40, 600, 12, 300, {"block_n": 16, "block_k": 256},
-                 id="lane-slices"),
-    # the default tiles: one row block, 512-wide center tiles
-    pytest.param(24, 1000, 8, None, {}, id="default-tiles"),
-    # one 1024-wide tile computed in four 256-wide column groups
-    pytest.param(512, 1024, 8, 700, {}, id="column-groups"),
+@pytest.mark.parametrize("construct", [
+    pytest.param(lambda: ServeConfig(backend="emulate"), id="serve-config"),
+    pytest.param(lambda: ops.assign(jnp.zeros((8, 4)), jnp.zeros((8, 4)),
+                                    backend="emulate"), id="ops-assign"),
 ])
-def test_emulate_bitwise_matches_interpret(rng, n, k, d, count, blocks):
-    """`dpmeans_assign_emulate` mirrors the kernel schedule op for op, so
-    on shapes interpret mode CAN sweep the two are BIT-identical (same
-    tiles, same f32 dot_general, same lane-wide running fold and final
-    reduction) — which is what licenses the emulation as the large-shape
-    parity oracle."""
-    x = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
-    c = jnp.asarray(rng.normal(size=(k, d)).astype(np.float32))
-    m = (jnp.asarray(np.arange(k) < count) if count is not None
-         else jnp.asarray(rng.uniform(size=k) > 0.25))
-    cnt = None if count is None else jnp.asarray(count, jnp.int32)
-    d2p, ip = ops.assign(x, c, m, count=cnt, backend="pallas", **blocks)
-    d2e, ie = ops.assign(x, c, m, count=cnt, backend="emulate", **blocks)
-    np.testing.assert_array_equal(np.asarray(d2p), np.asarray(d2e))
-    np.testing.assert_array_equal(np.asarray(ip), np.asarray(ie))
+def test_backend_outside_the_three_rejected(construct):
+    """`auto`, `pallas` and `ref` are the only backends: the serving
+    config refuses any other at construction, the kernel entry points at
+    the call."""
+    with pytest.raises(ValueError, match="backend"):
+        construct()
 
 
-@pytest.mark.parametrize("backend", ["ref", "emulate", "pallas"])
+@pytest.mark.parametrize("backend", ["ref", "pallas"])
 def test_assign_duplicate_centers_lowest_index_wins(rng, backend):
     """Exact duplicate centers tie exactly; the lowest index wins on every
     backend: a pair in two lanes of one tile (3, 70), a pair in one lane
@@ -239,30 +241,19 @@ def test_assign_duplicate_centers_lowest_index_wins(rng, backend):
     np.testing.assert_array_equal(np.asarray(idx), lows + lows)
 
 
-def test_emulate_production_shape_parity(rng):
-    """The point of the harness: a serving-bucket-sized shape (interpret
-    mode would loop 8x16 grid steps in Python per call — minutes) checked
-    against the jnp oracle in one compiled call."""
+def test_assign_production_shape_parity(rng):
+    """A serving-bucket-sized shape with the default tiles (two 1024-row
+    blocks against one 1024-wide center tile) in interpret mode, against
+    the oracle."""
     x = jnp.asarray(rng.normal(size=(2048, 48)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(1024, 48)).astype(np.float32))
     count = 517
     m = jnp.asarray(np.arange(1024) < count)
     cnt = jnp.asarray(count, jnp.int32)
-    d2e, ie = ops.assign(x, c, m, count=cnt, backend="emulate")
+    d2p, ip = ops.assign(x, c, m, count=cnt, backend="pallas")
     d2r, ir = ops.assign(x, c, m, count=cnt, backend="ref")
-    np.testing.assert_allclose(np.asarray(d2e), np.asarray(d2r), atol=1e-3)
-    np.testing.assert_array_equal(np.asarray(ie), np.asarray(ir))
-
-
-def test_emulate_pairwise_argmin_entry(rng):
-    x = jnp.asarray(rng.normal(size=(40, 12)).astype(np.float32))
-    c = jnp.asarray(rng.normal(size=(24, 12)).astype(np.float32))
-    d2e, ie = ops.pairwise_argmin(x, c, backend="emulate",
-                                  block_n=16, block_k=8)
-    d2p, ip = ops.pairwise_argmin(x, c, backend="pallas",
-                                  block_n=16, block_k=8)
-    np.testing.assert_array_equal(np.asarray(d2e), np.asarray(d2p))
-    np.testing.assert_array_equal(np.asarray(ie), np.asarray(ip))
+    np.testing.assert_allclose(np.asarray(d2p), np.asarray(d2r), atol=1e-3)
+    np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
 
 
 # --------------------------------------------------- serving-plane entries
@@ -275,7 +266,7 @@ def test_serve_assign_query_prefix_masking(rng):
     m = jnp.asarray(np.arange(16) < 9)
     cnt = jnp.asarray(9, jnp.int32)
     nv = jnp.asarray(20, jnp.int32)
-    for backend in ("ref", "emulate", "pallas"):
+    for backend in ("ref", "pallas"):
         kw = {} if backend == "ref" else {"block_n": 16, "block_k": 8}
         d2, idx = ops.serve_assign(x, c, m, count=cnt, n_valid=nv,
                                    backend=backend, **kw)
@@ -339,14 +330,11 @@ def test_serve_topk_active_prefix_immune_to_garbage_slots(rng):
 # merge is candidate-multiset-invariant, and at MXU-aligned shapes (D a
 # lane multiple, K a block multiple) XLA CPU reproduces the tile matmuls
 # bitwise against the flat one — so aligned shapes assert BITWISE equality
-# of (d2, idx) across ref/emulate/interpret.  At deliberately awkward
-# shapes (D=19, K=300) the last-ulp of the d2 reduction may differ between
-# tilings, so ragged sweeps assert idx exactly + d2 to 1e-5 — while
-# emulate vs interpret stays bitwise EVERYWHERE (identical op sequence).
+# of (d2, idx) between ref and interpret.  At deliberately awkward shapes
+# (D=19, K=300) the last-ulp of the d2 reduction may differ between
+# tilings, so ragged sweeps assert idx exactly + d2 to 1e-5.
 
-from repro.kernels.topk_stream import (
-    topk_stream_emulate, topk_tile_loads, topk_multiprobe_emulate,
-)
+from repro.kernels.topk_stream import center_tile, topk_tile_loads
 from repro.serving.snapshot import build_hier
 
 
@@ -365,17 +353,12 @@ def test_topk_stream_ragged_parity(rng, n, kc, d, count, k):
     d2r, ir = ops.serve_topk(x, c, k, mask=m, count=cnt, backend="ref")
     d2p, ip = ops.serve_topk(x, c, k, mask=m, count=cnt, backend="pallas",
                              block_n=16, block_k=8)
-    d2e, ie = ops.serve_topk(x, c, k, mask=m, count=cnt, backend="emulate",
-                             block_n=16, block_k=8)
     np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
     np.testing.assert_allclose(np.asarray(d2p), np.asarray(d2r), atol=1e-5)
-    # emulate replays the kernel schedule op for op: bitwise vs interpret
-    np.testing.assert_array_equal(np.asarray(d2e), np.asarray(d2p))
-    np.testing.assert_array_equal(np.asarray(ie), np.asarray(ip))
 
 
 def test_topk_stream_bitwise_at_aligned_shapes(rng):
-    """MXU-aligned serving shapes: all three backends bit-identical in
+    """MXU-aligned serving shapes: kernel and oracle bit-identical in
     BOTH distances and indices, ragged active prefix included."""
     x = jnp.asarray(rng.normal(size=(64, 64)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(512, 64)).astype(np.float32))
@@ -383,11 +366,8 @@ def test_topk_stream_bitwise_at_aligned_shapes(rng):
     m = jnp.asarray(np.arange(512) < count)
     cnt = jnp.asarray(count, jnp.int32)
     d2r, ir = ops.serve_topk(x, c, 8, mask=m, count=cnt, backend="ref")
-    d2e, ie = ops.serve_topk(x, c, 8, mask=m, count=cnt, backend="emulate")
     d2p, ip = ops.serve_topk(x, c, 8, mask=m, count=cnt, backend="pallas",
                              block_n=32, block_k=128)
-    np.testing.assert_array_equal(np.asarray(d2e), np.asarray(d2r))
-    np.testing.assert_array_equal(np.asarray(ie), np.asarray(ir))
     np.testing.assert_array_equal(np.asarray(d2p), np.asarray(d2r))
     np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
 
@@ -399,7 +379,7 @@ def test_topk_top1_column_equals_serve_assign(rng):
     c = jnp.asarray(rng.normal(size=(64, 16)).astype(np.float32))
     cnt = jnp.asarray(41, jnp.int32)
     m = jnp.asarray(np.arange(64) < 41)
-    for backend in ("ref", "emulate", "pallas"):
+    for backend in ("ref", "pallas"):
         kw = {} if backend == "ref" else {"block_n": 16, "block_k": 8}
         d2k, ik = ops.serve_topk(x, c, 3, mask=m, count=cnt,
                                  backend=backend, **kw)
@@ -409,14 +389,14 @@ def test_topk_top1_column_equals_serve_assign(rng):
 
 
 def test_topk_static_count_slicing_bitwise(rng):
-    """A HOST-int count lets CPU backends slice to the pow2 active prefix
-    pre-matmul; the result must be bitwise what the traced-count full-
+    """A HOST-int count lets the CPU reference slice to the pow2 active
+    prefix pre-matmul (named as "ref" or resolved from "auto"); the result must be bitwise what the traced-count full-
     width dispatch produces (a prefix slice changes no surviving lane)."""
     x = jnp.asarray(rng.normal(size=(16, 16)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(1024, 16)).astype(np.float32))
     count = 53                                # pow2 pad -> 64 of 1024
     m = jnp.asarray(np.arange(1024) < count)
-    for backend in ("ref", "emulate"):
+    for backend in ("ref", "auto"):
         d2s, is_ = ops.serve_topk(x, c, 6, mask=m, count=count,
                                   backend=backend)
         d2t, it = ops.serve_topk(x, c, 6, mask=m,
@@ -428,20 +408,29 @@ def test_topk_static_count_slicing_bitwise(rng):
 
 @pytest.mark.parametrize("count", [0, 1, 5, 64, 130, 300, 512])
 def test_topk_tile_loads_accounting(rng, count):
-    """Emulate-mode DMA accounting == the host-side index-map walk, and
+    """The host-side walk of the kernel's own index map (`center_tile`):
     tiles beyond the active prefix issue ZERO loads (the dpmeans_assign
-    assertion style, applied to the top-k schedule)."""
+    assertion style, applied to the top-k schedule), the map traced as
+    the kernel traces it agrees, and the kernel at that count answers as
+    the oracle does."""
     kc, bk = 512, 128
+    walk = topk_tile_loads(count, kc, block_k=bk)
+    assert walk == max(1, -(-count // bk))    # active tiles only
+    assert walk <= kc // bk                   # never the full-K sweep
+    tiles = np.arange(kc // bk)
+    np.testing.assert_array_equal(
+        np.asarray(center_tile(jnp.asarray(tiles),
+                               jnp.asarray(count, jnp.int32), bk)),
+        center_tile(tiles, count, bk, np))
     x = jnp.asarray(rng.normal(size=(8, 16)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(kc, 16)).astype(np.float32))
     m = jnp.asarray(np.arange(kc) < count)
-    d2, idx, loads = topk_stream_emulate(
-        x, c, m, 4, count=jnp.asarray(count, jnp.int32), block_k=bk,
-        with_loads=True)
-    walk = topk_tile_loads(count, kc, block_k=bk)
-    assert int(loads) == walk
-    assert walk == max(1, -(-count // bk))    # active tiles only
-    assert walk <= kc // bk                   # never the full-K sweep
+    cnt = jnp.asarray(count, jnp.int32)
+    d2p, ip = ops.serve_topk(x, c, 4, mask=m, count=cnt, backend="pallas",
+                             block_k=bk)
+    d2r, ir = ops.serve_topk(x, c, 4, mask=m, count=cnt, backend="ref")
+    np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
+    np.testing.assert_allclose(np.asarray(d2p), np.asarray(d2r), atol=1e-5)
 
 
 def test_topk_k_exceeds_capacity_padded_columns(rng):
@@ -450,7 +439,7 @@ def test_topk_k_exceeds_capacity_padded_columns(rng):
     x = jnp.asarray(rng.normal(size=(7, 5)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(12, 5)).astype(np.float32))
     cnt = jnp.asarray(12, jnp.int32)
-    for backend in ("ref", "emulate", "pallas"):
+    for backend in ("ref", "pallas"):
         kw = {} if backend == "ref" else {"block_n": 8, "block_k": 8}
         d2, idx = ops.serve_topk(x, c, 20, count=cnt, backend=backend, **kw)
         assert d2.shape == (7, 20)
@@ -468,14 +457,13 @@ def test_topk_duplicate_distance_tiebreak_determinism(rng):
     x = jnp.asarray(rng.normal(size=(11, 6)).astype(np.float32))
     cnt = jnp.asarray(24, jnp.int32)
     outs = {}
-    for backend in ("ref", "emulate", "pallas"):
+    for backend in ("ref", "pallas"):
         kw = {} if backend == "ref" else {"block_n": 8, "block_k": 8}
         d2, idx = ops.serve_topk(x, c, 6, count=cnt, backend=backend, **kw)
         outs[backend] = (np.asarray(d2), np.asarray(idx))
-    for b in ("emulate", "pallas"):
-        np.testing.assert_array_equal(outs[b][1], outs["ref"][1])
-        np.testing.assert_allclose(outs[b][0], outs["ref"][0],
-                                   rtol=ref.D2_RTOL, atol=ref.D2_ATOL)
+    np.testing.assert_array_equal(outs["pallas"][1], outs["ref"][1])
+    np.testing.assert_allclose(outs["pallas"][0], outs["ref"][0],
+                               rtol=ref.D2_RTOL, atol=ref.D2_ATOL)
     d2, idx = outs["ref"]
     for r in range(11):
         for j in range(1, 6):
@@ -501,7 +489,7 @@ def test_topk_multiprobe_full_union_bitwise_flat(rng):
     d2f, if_ = ops.serve_topk(x, jnp.asarray(np.nan_to_num(cn)), 9, mask=m,
                               count=jnp.asarray(count, jnp.int32),
                               backend="ref")
-    for backend in ("ref", "emulate", "pallas"):
+    for backend in ("ref", "pallas"):
         d2m, im = ops.serve_topk_multiprobe(
             x, h.fine, h.fine_ids, h.fine_mask, cells, member, 9,
             u_count=jnp.asarray(h.n_cells, jnp.int32), backend=backend)
@@ -547,16 +535,15 @@ def test_topk_multiprobe_partial_union_matches_candidate_oracle(rng):
     member = np.zeros((b, h.n_cells), bool)
     member[:, :3] = rng.uniform(size=(b, 3)) > 0.3
     outs = {}
-    for backend in ("ref", "emulate", "pallas"):
+    for backend in ("ref", "pallas"):
         outs[backend] = ops.serve_topk_multiprobe(
             x, h.fine, h.fine_ids, h.fine_mask, jnp.asarray(cells),
             jnp.asarray(member), k, u_count=jnp.asarray(3, jnp.int32),
             backend=backend)
-    for bk_ in ("emulate", "pallas"):
-        np.testing.assert_array_equal(np.asarray(outs[bk_][1]),
-                                      np.asarray(outs["ref"][1]))
-        np.testing.assert_allclose(np.asarray(outs[bk_][0]),
-                                   np.asarray(outs["ref"][0]), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(outs["pallas"][1]),
+                                  np.asarray(outs["ref"][1]))
+    np.testing.assert_allclose(np.asarray(outs["pallas"][0]),
+                               np.asarray(outs["ref"][0]), atol=1e-5)
     # brute force over the candidate multiset
     ids = np.asarray(h.fine_ids)
     msk = np.asarray(h.fine_mask)
@@ -584,7 +571,7 @@ if HAVE_HYPOTHESIS:
     @settings(max_examples=25, deadline=None)
     @given(data=st.data())
     def test_hypothesis_topk_stream_parity(data):
-        """Any (n, K, count, k, duplicate run): ref and emulate agree on
+        """Any (n, K, count, k, duplicate run): ref and interpret agree on
         indices exactly, distances to f32 tolerance, tails are (inf, -1),
         and rows are lexicographically (d2, idx) ascending."""
         n = data.draw(st.integers(1, 40), label="n")
@@ -602,10 +589,10 @@ if HAVE_HYPOTHESIS:
         cnt = jnp.asarray(count, jnp.int32)
         d2r, ir = ops.serve_topk(x, jnp.asarray(c), k, mask=m, count=cnt,
                                  backend="ref")
-        d2e, ie = ops.serve_topk(x, jnp.asarray(c), k, mask=m, count=cnt,
-                                 backend="emulate", block_n=16, block_k=8)
-        np.testing.assert_array_equal(np.asarray(ie), np.asarray(ir))
-        np.testing.assert_allclose(np.asarray(d2e), np.asarray(d2r),
+        d2p, ip = ops.serve_topk(x, jnp.asarray(c), k, mask=m, count=cnt,
+                                 backend="pallas", block_n=16, block_k=8)
+        np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
+        np.testing.assert_allclose(np.asarray(d2p), np.asarray(d2r),
                                    atol=1e-5)
         d2, idx = np.asarray(d2r), np.asarray(ir)
         valid = idx >= 0

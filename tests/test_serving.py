@@ -250,25 +250,25 @@ def test_bucket_rounding_and_padding_mask():
 
 
 def test_bucketed_emulation_parity_on_serving_shapes():
-    """The vmapped emulation harness parity-checks a production serving
-    bucket (4096 queries x 512-capacity snapshot) against the jnp oracle —
-    the shape interpret mode cannot sweep in CI time."""
+    """The kernel, emulated by Pallas interpret mode, parity-checks a
+    production serving bucket (4096 queries x 512-capacity snapshot)
+    against the jnp oracle."""
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(4096, 32)).astype(np.float32))
     c = jnp.asarray(rng.normal(size=(512, 32)).astype(np.float32))
     count = 301
     m = jnp.asarray(np.arange(512) < count)
-    d2e, ie = ops.serve_assign(x, c, m, count=jnp.asarray(count, jnp.int32),
+    d2p, ip = ops.serve_assign(x, c, m, count=jnp.asarray(count, jnp.int32),
                                n_valid=jnp.asarray(4000, jnp.int32),
-                               backend="emulate")
+                               backend="pallas")
     d2r, ir = ops.serve_assign(x, c, m, count=jnp.asarray(count, jnp.int32),
                                n_valid=jnp.asarray(4000, jnp.int32),
                                backend="ref")
-    assert np.array_equal(np.asarray(ie), np.asarray(ir))
-    np.testing.assert_allclose(np.asarray(d2e[:4000]), np.asarray(d2r[:4000]),
+    assert np.array_equal(np.asarray(ip), np.asarray(ir))
+    np.testing.assert_allclose(np.asarray(d2p[:4000]), np.asarray(d2r[:4000]),
                                atol=1e-3)
-    assert (np.asarray(ie[4000:]) == -1).all()
-    assert np.isinf(np.asarray(d2e[4000:])).all()
+    assert (np.asarray(ip[4000:]) == -1).all()
+    assert np.isinf(np.asarray(d2p[4000:])).all()
 
 
 # -------------------------------------------------------- hypothesis layer
@@ -418,17 +418,17 @@ def test_service_multiprobe_counters_recall_and_audit_record():
 
 
 def test_service_multiprobe_backend_parity_and_no_retrace():
-    """ref and emulate services agree through the full multi-probe path
+    """ref and pallas services agree through the full multi-probe path
     (indices exactly, distances to f32 tolerance), and a version hot-swap
     does not retrace the warm multi-probe step."""
     x = _stream()
     store, eng = _hier_store(x)
     q = np.asarray(x[200:232])
     svc_r = ClusterService(store, backend="ref", probes=2)
-    svc_e = ClusterService(store, backend="emulate", probes=2)
-    r_r, r_e = svc_r.topk(q, k=6), svc_e.topk(q, k=6)
-    np.testing.assert_array_equal(r_r.labels, r_e.labels)
-    np.testing.assert_allclose(r_r.scores, r_e.scores, atol=1e-5)
+    svc_p = ClusterService(store, backend="pallas", probes=2)
+    r_r, r_p = svc_r.topk(q, k=6), svc_p.topk(q, k=6)
+    np.testing.assert_array_equal(r_r.labels, r_p.labels)
+    np.testing.assert_allclose(r_r.scores, r_p.scores, atol=1e-5)
     traces0 = cs_mod._QUERY_TRACES
     store.publish_pool(eng.pool)             # new version, same buckets
     r2 = svc_r.topk(q, k=6)
