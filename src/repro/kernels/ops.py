@@ -15,7 +15,11 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref as _ref
-from repro.kernels.dpmeans_assign import dpmeans_assign as _dpmeans_assign
+from repro.kernels.dpmeans_assign import (
+    dpmeans_assign as _dpmeans_assign,
+    screened_assign as _screened_assign,
+    takes_screen as _takes_screen,
+)
 from repro.kernels.flash_attention import flash_attention as _flash_attention
 from repro.kernels.rmsnorm import rmsnorm as _rmsnorm
 from repro.kernels.swiglu import swiglu as _swiglu
@@ -61,7 +65,10 @@ def assign(x, centers, mask=None, count=None, backend: str = "auto",
     active prefix — tiles beyond the pool count are skipped), pallas
     interpret=True off-TPU for kernel validation, jnp reference elsewhere
     (the reference cannot skip work — static shapes — so count folds into
-    the mask, which the pool invariant makes a no-op).
+    the mask, which the pool invariant makes a no-op).  On the Pallas path
+    a call that `takes_screen` (at least SCREEN_MIN_ROWS rows, more than
+    one center tile, default tiles) runs `screened_assign`: the same
+    contract, its distances screened in three bf16 MXU passes.
     """
     if mask is None:
         mask = jnp.ones((centers.shape[0],), bool)
@@ -69,6 +76,10 @@ def assign(x, centers, mask=None, count=None, backend: str = "auto",
         mask = jnp.logical_and(mask, jnp.arange(centers.shape[0]) < count)
     use_pallas, interp = _resolve(backend)
     if use_pallas:
+        if not blocks and _takes_screen(x.shape[0], x.shape[1],
+                                        centers.shape[0]):
+            return _screened_assign(x, centers, mask, count=count,
+                                    interpret=interp)
         return _dpmeans_assign(x, centers, mask, count=count,
                                interpret=interp, **blocks)
     return _ref.assign_ref(x, centers, mask)

@@ -256,6 +256,171 @@ def test_assign_production_shape_parity(rng):
     np.testing.assert_array_equal(np.asarray(ip), np.asarray(ir))
 
 
+# ------------------------------------------------- the screened assign path
+
+def _unit(a):
+    return (a / np.linalg.norm(a, axis=-1, keepdims=True)).astype(np.float32)
+
+
+def _screen_case(rng, case, n, d, k):
+    """(x, centers, count, certified): rows and a pool of unit vectors with
+    the case planted.  `certified` says whether every row must pass the
+    screen's band test (None: either)."""
+    x = _unit(rng.normal(size=(n, d)))
+    c = _unit(rng.normal(size=(k, d)))
+    count, certified = k - 37, None
+
+    def plant(row, col, d2):
+        # a center at squared distance d2 from the row, off a random axis
+        v = _unit(rng.normal(size=d))
+        v -= (v @ x[row]) * x[row]
+        c[col] = x[row] + np.sqrt(d2) * _unit(v)
+
+    if case == "random":
+        certified = None
+    elif case in ("near-tie-lanes", "near-tie-lane"):
+        # the nearer of two centers 2e-5 apart (inside the band) at the
+        # higher index: in other lanes, or in one lane 128 columns apart
+        gap = 1 if case == "near-tie-lanes" else 128
+        for r in range(0, n, 3):
+            lo = (7 * r) % (count - 2 * 128)
+            plant(r, lo, 0.5 + 2e-5)
+            plant(r, lo + gap, 0.5)
+        certified = case == "near-tie-lanes"
+    elif case in ("exact-tie-lanes", "exact-tie-lane"):
+        gap = 1 if case == "exact-tie-lanes" else 128
+        for r in range(0, n, 5):
+            lo = (11 * r) % (count - 2 * 128)
+            plant(r, lo, 0.4)
+            c[lo + gap] = c[lo]
+        certified = case == "exact-tie-lanes"
+    elif case == "empty":
+        count, certified = 0, True
+    elif case == "norms":
+        x *= 10.0 ** rng.uniform(-2, 2, size=(n, 1)).astype(np.float32)
+        c *= 10.0 ** rng.uniform(-2, 2, size=(k, 1)).astype(np.float32)
+    return jnp.asarray(x), jnp.asarray(c), count, certified
+
+
+_SCREEN_SHAPES = [pytest.param(128, 96, 1024, id="d96"),
+                  pytest.param(128, 512, 1024, id="d512")]
+
+
+@pytest.mark.parametrize("n,d,k", _SCREEN_SHAPES)
+@pytest.mark.parametrize("case", ["random", "near-tie-lanes",
+                                  "near-tie-lane", "exact-tie-lanes",
+                                  "exact-tie-lane", "empty", "norms"])
+def test_screened_assign_is_the_highest_answer(rng, case, n, d, k):
+    """The screen, the re-score and the fallback together give
+    `ref.assign_ref`'s answer: the same ids, d2 within the distance
+    contract.  Near-ties across lanes stay certified and the re-score
+    orders them; near-ties and exact ties inside one lane (columns i and
+    i + 128) fail the band test, and the call falls back to the exact
+    kernel."""
+    from repro.kernels.dpmeans_assign import (SCREEN_MIN_ROWS,
+                                              dpmeans_assign_screen,
+                                              screened_assign)
+    assert n >= SCREEN_MIN_ROWS
+    x, c, count, certified = _screen_case(rng, case, n, d, k)
+    m = jnp.arange(k) < count
+    cnt = jnp.asarray(count, jnp.int32)
+    blocks = {"block_k": 256, "interpret": True}
+    _, cand, cert = dpmeans_assign_screen(x, c, m, count=cnt, **blocks)
+    if certified is not None:
+        assert bool(jnp.all(cert)) == certified
+    d2, idx = screened_assign(x, c, m, count=cnt, **blocks)
+    d2r, ir = ref.assign_ref(x, c, m)
+    np.testing.assert_array_equal(np.asarray(idx), np.asarray(ir))
+    np.testing.assert_allclose(np.asarray(d2), np.asarray(d2r),
+                               rtol=ref.D2_RTOL, atol=ref.D2_ATOL)
+    # a certified row's HIGHEST nearest center is one of its candidates
+    hit = np.any(np.asarray(cand) == np.asarray(ir)[:, None], axis=1)
+    assert np.all(hit | ~np.asarray(cert) | (np.asarray(ir) < 0))
+    if case == "empty":
+        assert np.all(np.asarray(idx) == -1) and np.all(np.isinf(d2))
+
+
+@pytest.mark.parametrize("seed", range(3))
+@pytest.mark.parametrize("kind", ["random", "near-duplicates", "norms",
+                                  "offset"])
+def test_screened_minimum_within_the_bound(kind, seed):
+    """|screened - HIGHEST| <= E for every pair, so each row's screened
+    minimum lies within E of the HIGHEST minimum: E = kappa_D ||x|| c_max
+    + rho_D (||x||^2 + c_max^2) (`screen_bound`).  Adversarial rows:
+    centers within 1e-3 of the rows (cancellation in x^2 + c^2 - 2 x.c),
+    norms over four decades, and rows far off the origin."""
+    from repro.kernels.dpmeans_assign import (dpmeans_assign_screen,
+                                              screen_bound)
+    rng = np.random.default_rng(seed)
+    n, d, k = 64, 96 if seed % 2 else 512, 512
+    x = _unit(rng.normal(size=(n, d)))
+    c = _unit(rng.normal(size=(k, d)))
+    if kind == "near-duplicates":
+        c[:n] = x + 1e-3 * rng.normal(size=(n, d)).astype(np.float32)
+    elif kind == "norms":
+        x *= 10.0 ** rng.uniform(-2, 2, size=(n, 1)).astype(np.float32)
+        c *= 10.0 ** rng.uniform(-2, 2, size=(k, 1)).astype(np.float32)
+    elif kind == "offset":
+        shift = 30.0 * _unit(rng.normal(size=(1, d)))
+        x, c = x + shift, c + shift
+    m = jnp.ones((k,), bool)
+    d2s, _, _ = dpmeans_assign_screen(jnp.asarray(x), jnp.asarray(c), m,
+                                      block_k=256, interpret=True)
+    d2r, _ = ref.assign_ref(jnp.asarray(x), jnp.asarray(c), m)
+    kappa, rho = screen_bound(d)
+    x2 = (x.astype(np.float64) ** 2).sum(1)
+    c2max = (c.astype(np.float64) ** 2).sum(1).max()
+    bound = kappa * np.sqrt(x2 * c2max) + rho * (x2 + c2max)
+    err = np.abs(np.asarray(d2s, np.float64) - np.asarray(d2r, np.float64))
+    assert np.all(err <= bound), (err / bound).max()
+
+
+def test_screen_bound_values():
+    """kappa_D and rho_D at the cells' widths: for unit rows E is 1.4e-4
+    at D = 96 and 3.5e-4 at D = 512."""
+    from repro.kernels.dpmeans_assign import screen_bound
+    k96, r96 = screen_bound(96)
+    k512, r512 = screen_bound(512)
+    assert k96 + 2 * r96 == pytest.approx(1.42e-4, rel=0.01)
+    assert k512 + 2 * r512 == pytest.approx(3.45e-4, rel=0.01)
+    assert 3 * 2.0 ** -16 * 2 < k96 < k512
+
+
+@pytest.mark.parametrize("n,d,k,screen", [
+    (256, 96, 65536, True),       # deep96.train, deep96-ofl.train
+    (1024, 512, 65536, True),     # laion512.train
+    (256, 512, 65536, True),      # laion512.train.4chip, a chip's rows
+    (64, 96, 32768, False),       # a serving bucket below R
+    (256, 96, 4096, False),       # one center tile
+])
+def test_takes_screen(n, d, k, screen):
+    from repro.kernels.dpmeans_assign import takes_screen
+    assert takes_screen(n, d, k) == screen
+
+
+def test_assign_routes_by_shape(rng):
+    """`ops.assign` on the Pallas path takes the screen at >= R rows and
+    more than one center tile, and the exact kernel alone otherwise, or
+    where the caller sets the tiles."""
+    import re
+
+    import jax
+    from repro.kernels.dpmeans_assign import SCREEN_MIN_ROWS
+    k, d = 8192, 96
+
+    def kernels(n, **blocks):
+        x = jnp.zeros((n, d))
+        c = jnp.zeros((k, d))
+        names = set(re.findall(r"name=(\w+)", str(jax.make_jaxpr(
+            lambda x, c: ops.assign(x, c, backend="pallas", **blocks))(
+                x, c))))
+        return "dpmeans_assign_screen" in names, "dpmeans_assign" in names
+
+    assert kernels(SCREEN_MIN_ROWS) == (True, True)
+    assert kernels(SCREEN_MIN_ROWS - 8) == (False, True)
+    assert kernels(SCREEN_MIN_ROWS, block_k=512) == (False, True)
+
+
 # --------------------------------------------------- serving-plane entries
 
 def test_serve_assign_query_prefix_masking(rng):

@@ -116,6 +116,51 @@ def test_dpmeans_assign_compiles_at_ofl_pool(chip):
         chip((), jnp.int32))
 
 
+def _custom_calls(text: str) -> set:
+    """Names of the Pallas calls in a compiled program, numbering dropped."""
+    return {re.sub(r"\.\d+$", "", n) for n in re.findall(
+        r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"",
+        text)}
+
+
+@pytest.mark.parametrize("rows,d,k", [
+    (1024, 512, 65536),   # laion512.train
+    (256, 512, 65536),    # laion512.train.4chip: one chip's quarter
+    (256, D, 65536),      # deep96.train
+    (PB, D, K_OFL),       # deep96-ofl.train
+])
+def test_screened_assign_compiles_at_cell_shapes(chip, rows, d, k):
+    """The propose through the screen at the cells' shapes: the screen,
+    the re-score and, under a `cond`, the exact kernel, with the screen's
+    extra scratch inside the scoped VMEM it asks for."""
+    from repro.kernels.dpmeans_assign import screened_assign, takes_screen
+    assert takes_screen(rows, d, k)
+    text = _kernel_compiled(
+        lambda x, c, m, n: screened_assign(x, c, m, count=n),
+        chip((rows, d)), chip((k, d)), chip((k,), jnp.bool_),
+        chip((), jnp.int32))
+    assert _custom_calls(text) == {"dpmeans_assign_screen", "dpmeans_assign"}
+    assert re.search(r" conditional\(", text)
+
+
+@pytest.mark.parametrize("rows", SERVE_BUCKETS)
+def test_serve_assign_takes_the_screen_from_r_rows(chip, monkeypatch, rows):
+    """Every assign bucket the service dispatches: those below R rows
+    compile to the exact kernel alone, the rest to the screen with the
+    exact kernel as its fallback."""
+    from repro.kernels import ops
+    from repro.kernels.dpmeans_assign import SCREEN_MIN_ROWS
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    text = _kernel_compiled(
+        lambda x, c, m, n, v: ops.serve_assign(x, c, m, count=n, n_valid=v),
+        chip((rows, D)), chip((K_POOL, D)), chip((K_POOL,), jnp.bool_),
+        chip((), jnp.int32), chip((), jnp.int32))
+    want = {"dpmeans_assign"}
+    if rows >= SCREEN_MIN_ROWS:
+        want.add("dpmeans_assign_screen")
+    assert _custom_calls(text) == want
+
+
 @pytest.mark.parametrize("rows", SERVE_BUCKETS)
 def test_topk_stream_compiles(chip, rows):
     _kernel_compiled(
@@ -154,15 +199,22 @@ def test_flash_attention_compiles(chip):
         chip((1, 8, 2048, 128), jnp.bfloat16))
 
 
-@pytest.mark.parametrize("chips", [1, 4])
-def test_occ_engine_pass_compiles(topo, monkeypatch, chips):
+@pytest.mark.parametrize("chips,d,pb,k_max", [
+    pytest.param(1, D, PB, K_POOL, id="1"),
+    pytest.param(4, D, PB, K_POOL, id="4"),
+    pytest.param(4, 512, 1024, 65536, id="4-laion512"),
+])
+def test_occ_engine_pass_compiles(topo, monkeypatch, chips, d, pb, k_max):
     """A whole DP-means pass program on the Pallas path — on one chip, and
     sharded over the 2x2 host's data axis with the propose under
-    shard_map (GSPMD cannot partition the kernel itself)."""
+    shard_map (GSPMD cannot partition the kernel itself).  Each chip's
+    rows take the screen from R rows on: laion512's 256 a chip do,
+    chip_smoke.py's 64 do not."""
     from repro.core import DPMeansTransaction
     from repro.core.engine import _engine_pass_jit
     from repro.core.occ import make_pool
     from repro.kernels import ops
+    from repro.kernels.dpmeans_assign import SCREEN_MIN_ROWS
     # Code that asks for the backend sees this CPU; steer it to the kernel.
     monkeypatch.setattr(ops, "on_tpu", lambda: True)
     if chips == 1:
@@ -172,18 +224,22 @@ def test_occ_engine_pass_compiles(topo, monkeypatch, chips):
         mesh = Mesh(np.array(topo.devices).reshape(4), ("data",),
                     axis_types=(AxisType.Auto,))
         place = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
-    txn = DPMeansTransaction(1.0, k_max=K_POOL)
+    txn = DPMeansTransaction(1.0, k_max=k_max)
     pool = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=place),
-        jax.eval_shape(lambda: make_pool(K_POOL, D)))
-    x = jax.ShapeDtypeStruct((1 << 18, D), jnp.float32, sharding=place)
+        jax.eval_shape(lambda: make_pool(k_max, d)))
+    x = jax.ShapeDtypeStruct((1 << 18, d), jnp.float32, sharding=place)
     text = _engine_pass_jit.lower(
-        txn, pool, x, (), pb=PB, cap_warm=None, cap_rest=None, n_warm=0,
+        txn, pool, x, (), pb=pb, cap_warm=None, cap_rest=None, n_warm=0,
         n_bootstrap=0, mesh=mesh, data_axis="data").compile().as_text()
-    assert "tpu_custom_call" in text
+    rows = pb // chips
+    want = {"dpmeans_assign"}
+    if rows >= SCREEN_MIN_ROWS:
+        want.add("dpmeans_assign_screen")
+    assert _custom_calls(text) == want
     if mesh is not None:
         # the kernel runs on each chip's quarter of the epoch
-        assert f"f32[{PB // 4},1]" in text
+        assert f"f32[{rows},1]" in text
 
 
 def test_ofl_pass_and_state_compile(chip, monkeypatch):
